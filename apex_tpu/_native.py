@@ -8,11 +8,14 @@ back to a numpy implementation with identical semantics, so the framework
 never hard-requires a compiler at runtime.
 
 The library is compiled on demand with g++ (baked into the image) into
-``csrc/build/`` and cached; pybind11 is unavailable so the ABI is plain C
-consumed via ctypes.
+``csrc/build/`` and cached under a name that carries a hash of its source,
+so a build directory copied from another checkout can never supply a
+library built from other code; pybind11 is unavailable so the ABI is plain
+C consumed via ctypes.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,7 +30,6 @@ _TRIED = False
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_ROOT, "csrc", "apex_tpu_C.cpp")
 _BUILD_DIR = os.path.join(_ROOT, "csrc", "build")
-_SO = os.path.join(_BUILD_DIR, "libapex_tpu_C.so")
 
 
 def _installed_ext() -> Optional[str]:
@@ -42,26 +44,32 @@ def _installed_ext() -> Optional[str]:
 
 
 def _compile() -> Optional[str]:
-    if not os.path.exists(_SRC):
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
         return None
+    so = os.path.join(_BUILD_DIR, f"libapex_tpu_C.{digest}.so")
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
     # compile to a per-pid temp and rename atomically: an interrupted or
-    # concurrent build must never leave a half-written .so that the mtime
-    # cache then trusts forever
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    # concurrent build must never leave a half-written .so under the name
+    # the cache trusts
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-    except Exception:
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError):
+        # no compiler / failed build: callers fall back to the numpy twin,
+        # and available() says so
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return None
-    return _SO
+    return so
 
 
 def _load() -> Optional[ctypes.CDLL]:

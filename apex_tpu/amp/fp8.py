@@ -1,4 +1,4 @@
-"""Minimal FP8 delayed-scaling recipe (VERDICT r3 item 7).
+"""Minimal FP8 delayed-scaling recipe.
 
 Reference parity: the reference exposes the amax-reduction PROCESS GROUPS
 for FP8 training (apex/transformer/parallel_state.py:280-292) but no

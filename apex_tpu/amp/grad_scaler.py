@@ -44,7 +44,7 @@ class GradScaler(LossScaler):
             # the psum runs even when the axis has size 1: it moves no
             # bytes (XLA elides size-1 reduces; the xray ledger doesn't
             # record them) but it DOES establish replication over the
-            # axis, which checked shard_map (check_rep/check_vma=True)
+            # axis, which checked shard_map (check_vma=True)
             # needs to type a P() out_spec — skipping it on degenerate
             # tp=1/pp=1 meshes breaks out_specs inference (verified).
             # The analysis collective.dead-traffic warning for this site

@@ -199,7 +199,7 @@ _COLLECTIVE = [
             "found_inf psum over a possibly-size-1 model-parallel axis "
             "is replication-ESTABLISHING, not traffic: XLA elides the "
             "size-1 reduce (zero bytes) but checked shard_map "
-            "(check_rep/check_vma=True) relies on the psum to type the "
+            "(check_vma=True) relies on the psum to type the "
             "result replicated — gating it on axis size breaks "
             "out_specs inference on degenerate tp=1/pp=1 meshes "
             "(verified by repro)"

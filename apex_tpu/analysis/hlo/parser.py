@@ -320,6 +320,8 @@ _METADATA_RE = re.compile(r"metadata=\{")
 _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _SOURCE_FILE_RE = re.compile(r'source_file="((?:[^"\\]|\\.)*)"')
 _SOURCE_LINE_RE = re.compile(r"source_line=(\d+)")
+_STACK_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _SHARDING_RE = re.compile(r"sharding=\{")
 
 #: instruction opener: ``  %name = type opcode(``  (ROOT optional)
@@ -368,7 +370,44 @@ def _parse_source_target_pairs(attrs: str) -> Tuple[Tuple[int, int], ...]:
     return tuple(pairs)
 
 
-def _parse_metadata(attrs: str) -> Tuple[str, str, int]:
+def _parse_stack_frames(text: str) -> Dict[int, Tuple[str, int]]:
+    """``{stack_frame_id: (file, line)}`` from the module header. Current
+    XLA prints source locations once, as ``FileNames`` / ``FileLocations``
+    / ``StackFrames`` index tables ahead of the computations, and each
+    instruction's metadata carries only ``stack_frame_id=N``. Empty when
+    the text has inline ``source_file=``/``source_line=`` instead."""
+    tables: Dict[str, Dict[int, str]] = {}
+    section = None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            break  # header is over at the first computation
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            section = tables.setdefault(line, {})
+            continue
+        idx, _, body = line.partition(" ")
+        if section is not None and idx.isdigit():
+            section[int(idx)] = body
+    files = {
+        i: b.strip().strip('"') for i, b in tables.get("FileNames", {}).items()
+    }
+    locations = {}
+    for i, b in tables.get("FileLocations", {}).items():
+        fm = re.search(r"file_name_id=(\d+)", b)
+        lm = re.search(r"\bline=(\d+)", b)
+        if fm and lm:
+            locations[i] = (files.get(int(fm.group(1)), ""), int(lm.group(1)))
+    frames = {}
+    for i, b in tables.get("StackFrames", {}).items():
+        m = re.search(r"file_location_id=(\d+)", b)
+        if m and int(m.group(1)) in locations:
+            frames[i] = locations[int(m.group(1))]
+    return frames
+
+
+def _parse_metadata(
+    attrs: str, frames: Optional[Dict[int, Tuple[str, int]]] = None,
+) -> Tuple[str, str, int]:
     m = _METADATA_RE.search(attrs)
     if m is None:
         return "", "", 0
@@ -376,11 +415,11 @@ def _parse_metadata(attrs: str) -> Tuple[str, str, int]:
     op = _OP_NAME_RE.search(body)
     sf = _SOURCE_FILE_RE.search(body)
     sl = _SOURCE_LINE_RE.search(body)
-    return (
-        op.group(1) if op else "",
-        sf.group(1) if sf else "",
-        int(sl.group(1)) if sl else 0,
-    )
+    source = (sf.group(1) if sf else "", int(sl.group(1)) if sl else 0)
+    frame = _STACK_FRAME_RE.search(body)
+    if not sf and frame and frames:
+        source = frames.get(int(frame.group(1)), source)
+    return (op.group(1) if op else "",) + source
 
 
 def _parse_sharding_attr(attrs: str) -> Optional[HloSharding]:
@@ -520,6 +559,11 @@ def parse_hlo_module(compiled_or_text) -> HloModule:
         entry_root_shardings=None,
         input_output_alias=realized_aliases(text),
     )
+    frames = _parse_stack_frames(text)
+    # result shapes by instruction name: current XLA prints operands as
+    # bare ``%name`` references, so a collective's operand shapes are the
+    # result shapes of the instructions it names
+    results: Dict[str, List[HloShape]] = {}
     for comp, in_entry, lineno, instr in _iter_instructions(text):
         if in_entry:
             module.entry_name = comp
@@ -528,6 +572,8 @@ def parse_hlo_module(compiled_or_text) -> HloModule:
             continue
         rest = m.group("rest")
         opcode, paren = _find_opcode(rest)
+        result_shapes = _parse_shapes(rest[:paren]) if paren >= 0 else []
+        results[m.group("name")] = result_shapes
         if in_entry:
             pm = _PARAM_RE.match(instr)
             if pm:
@@ -555,16 +601,17 @@ def parse_hlo_module(compiled_or_text) -> HloModule:
             continue
         operand_text, end = balanced(rest, paren, "(", ")")
         attrs = rest[end + 1:]
-        op_name, source_file, source_line = _parse_metadata(attrs)
-        result_shapes = _parse_shapes(rest[:paren])
+        op_name, source_file, source_line = _parse_metadata(attrs, frames)
+        operand_shapes = _parse_shapes(operand_text) or [
+            s for ref in _OPERAND_NAME_RE.findall(operand_text)
+            for s in results.get(ref, ())
+        ]
         module.collectives.append(HloCollective(
             kind=kind,
             name=f"%{m.group('name')}",
             computation=comp,
             result=result_shapes[0] if result_shapes else HloShape("f32", ()),
-            operands=tuple(
-                HloOperand(s) for s in _parse_shapes(operand_text)
-            ),
+            operands=tuple(HloOperand(s) for s in operand_shapes),
             replica_groups=_parse_replica_groups(attrs),
             source_target_pairs=_parse_source_target_pairs(attrs),
             channel_id=(

@@ -6,13 +6,13 @@ serializes the device against Python. The offenders hide well because
 they are *correct*: ``jax.debug.print`` left over from a debugging
 session, a ``pure_callback`` smuggled in by a library, an
 ``io_callback`` logger — each one stalls the XLA pipeline for a host
-round-trip (~73 ms through the relay, utils/benchmarking.py) every
+round-trip every
 single step, which swamps small-step training without changing any
 output. This pass finds them in the traced jaxpr before a step runs:
 
 - ``host-sync.callback`` — ``pure_callback`` / ``io_callback`` /
-  ``debug_callback`` (what ``jax.debug.print`` lowers to) and the legacy
-  host_callback primitives.
+  ``debug_print`` / ``debug_callback`` (``jax.debug.print`` /
+  ``jax.debug.callback``) and the legacy host_callback primitives.
 - ``host-sync.transfer`` — explicit ``device_put`` equations whose
   destination is a host memory space (the memories API): an in-step
   device->host transfer.
@@ -33,7 +33,8 @@ __all__ = ["host_sync_pass"]
 _CALLBACK_PRIMS = {
     "pure_callback": "jax.pure_callback",
     "io_callback": "jax.experimental.io_callback",
-    "debug_callback": "jax.debug.print/jax.debug.callback",
+    "debug_print": "jax.debug.print",
+    "debug_callback": "jax.debug.callback",
     "outside_call": "jax.experimental.host_callback (legacy)",
     "host_callback": "jax.experimental.host_callback (legacy)",
 }

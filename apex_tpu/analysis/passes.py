@@ -57,10 +57,14 @@ _REPO_ROOT = os.path.dirname(
 
 #: wrapper modules whose frames are NOT the interesting call site: the
 #: instrumented collective wrappers and the p2p edge helpers — findings
-#: should name the schedule/layer that called them
+#: should name the schedule/layer that called them. Installed libraries
+#: (flax's dtype promotion helpers sit innermost under every nn.Dense)
+#: are wrappers in the same sense: the finding belongs to the repo line
+#: that called them, which is also what the allowlist matches on
 _WRAPPER_FRAGMENTS = (
     os.path.join("monitor", "xray", "ledger.py"),
     os.path.join("parallel", "pipeline", "p2p.py"),
+    os.sep + "site-packages" + os.sep,
 )
 
 
@@ -96,13 +100,11 @@ def eqn_site(eqn, skip_wrappers: bool = True) -> str:
     equation's source info, so a backward promotion points at the forward
     cast it transposes — the right line to look at anyway.
     """
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-
-        frames = list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
-        return "<unknown>"
+    # private API (jax exposes no public equation -> user frame lookup);
+    # it takes the equation's Traceback, not its SourceInfo
+    frames = list(source_info_util.user_frames(eqn.source_info.traceback))
     chosen = None
     for fr in frames:
         chosen = fr

@@ -1,52 +1,11 @@
-"""Cross-version jax shims.
+"""The one jax this tree runs on (``pyproject.toml``: ``jax>=0.9``).
 
-The codebase targets current jax, where ``shard_map`` is a top-level API
-(``jax.shard_map``) and checked mode tracks varying-manual-axes (vma)
-types via the ``check_vma`` flag. Older jax (<= 0.4.x, what some CI and
-dev images carry) only has ``jax.experimental.shard_map.shard_map`` with
-the predecessor ``check_rep`` flag and no vma tracking.
-
-Import ``shard_map`` from here instead of from jax so one tree runs on
-both:
-
-- ``check_vma=``/``check_rep=`` are translated to whatever the running
-  jax accepts (the semantics of *False* — tracking off — are identical;
-  ``True`` selects whichever checker the jax build has).
-- ``HAS_VMA`` gates code and tests that need real vma types (e.g.
-  ``jax.eval_shape(...).vma``); on pre-vma jax those must skip or fall
-  back (``apex_tpu.parallel.utils.vma_cond`` already falls back on its
-  own).
+``shard_map`` is ``jax.shard_map``: a top-level API whose checked mode
+tracks varying-manual-axes (vma) types, switched by ``check_vma``. The
+package imports it from here so there is a single place that names the
+supported surface.
 """
 
-import functools
-import inspect
+from jax import shard_map
 
-try:  # current jax
-    from jax import shard_map as _shard_map
-except ImportError:  # pre-0.5 jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-#: True when this jax tracks varying-manual-axes types under shard_map
-#: (the ``check_vma`` era); False on check_rep-only jax.
-HAS_VMA = "check_vma" in _PARAMS
-
-__all__ = ["shard_map", "HAS_VMA"]
-
-
-def shard_map(f=None, *args, **kwargs):
-    """``jax.shard_map`` portable across jax versions.
-
-    Accepts either ``check_vma`` (current jax) or ``check_rep`` (older
-    jax) and forwards the flag under the name the running jax expects.
-    Usable directly or as ``functools.partial(shard_map, mesh=..., ...)``
-    exactly like the real API.
-    """
-    if f is None:
-        return functools.partial(shard_map, *args, **kwargs)
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, *args, **kwargs)
+__all__ = ["shard_map"]

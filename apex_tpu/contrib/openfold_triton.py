@@ -9,7 +9,7 @@ TPU mapping:
   generic Pallas/XLA kernels; whether those need a small-shape-tuned path
   is a MEASURED question — ``benchmarks/bench_small_shapes.py`` runs the
   openfold evoformer shapes (LN hidden 64/128, MHA seq<=256 head_dim
-  8/16) and BENCH.md carries the decision row.
+  8/16); the decision is open until they have run on the chip.
 """
 
 from apex_tpu.normalization import FusedLayerNorm as LayerNormSmallShapeOptImpl

@@ -48,7 +48,6 @@ _EXPORTS = {
     "detect_divergence": "fleet",
     "LiveFleetMonitor": "live",
     # sentinel
-    "load_bench_history": "sentinel",
     "measurements_from_records": "sentinel",
     "noise_tolerance": "sentinel",
     "check_regression": "sentinel",

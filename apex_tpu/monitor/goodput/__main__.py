@@ -17,15 +17,13 @@ timeline CLI's grab-and-run contract):
   hosts and silent-corruption suspects. Exit 1 on any flag.
 
 - **--check** — the perf-regression sentinel (exit-nonzero gate, the
-  ``python -m apex_tpu.analysis`` discipline). With no streams, the
-  NEWEST recorded BENCH round is checked against the prior rounds'
-  noise-aware thresholds — the self-test that the recorded trajectory
-  itself passes its own gate. With streams, their ``kind="bench"`` /
-  ``"metrics"`` / ``"goodput"`` measurements are the fresh side, checked
-  against the full recorded history plus an optional ``--baseline``
-  recording of a comparable run. Intentional regressions go through the
-  reason-carrying allowlist (goodput/sentinel.py), never through
-  silence.
+  ``python -m apex_tpu.analysis`` discipline). The streams'
+  ``kind="bench"`` / ``"metrics"`` / ``"goodput"`` measurements are the
+  fresh side, checked with noise-aware thresholds against a
+  ``--baseline`` recording of a comparable run (a metric the baseline
+  does not carry is reported as un-gated, not failed). Intentional
+  regressions go through the reason-carrying allowlist
+  (goodput/sentinel.py), never through silence.
 """
 
 import argparse
@@ -46,9 +44,9 @@ def main(argv=None) -> int:
                         help="fleet-health divergence detection; exit 1 on "
                              "stragglers or corruption suspects")
     parser.add_argument("--check", action="store_true",
-                        help="perf-regression gate vs the recorded BENCH "
-                             "trajectory; exit 1 on unallowlisted "
-                             "regressions")
+                        help="perf-regression gate of the stream(s) vs a "
+                             "--baseline recording; exit 1 on "
+                             "unallowlisted regressions")
     parser.add_argument("--baseline", default=None,
                         help="--check: baseline record jsonl for run-kind "
                              "measurements (tokens/s, MFU, goodput)")
@@ -73,25 +71,16 @@ def main(argv=None) -> int:
     if args.check:
         from apex_tpu.monitor.goodput import sentinel
 
-        history = sentinel.load_bench_history()
-        if args.streams:
-            fresh = sentinel.measurements_from_records(
-                records, source=",".join(args.streams))
-            if args.baseline:
-                history = history + sentinel.measurements_from_records(
-                    accountant.read_records([args.baseline]),
-                    source=args.baseline,
-                )
-        else:
-            # self-test: the newest recorded round vs the prior rounds
-            if not history:
-                print("perf check: no recorded BENCH_r*.json history")
-                return 1
-            newest_source = history[-1]["source"]
-            fresh = [m for m in history if m["source"] == newest_source]
-            history = [m for m in history if m["source"] != newest_source]
-            print(f"perf check: newest recorded round {newest_source} vs "
-                  f"{len(history)} prior measurement(s)")
+        if not args.streams:
+            parser.error("--check needs at least one record stream")
+        fresh = sentinel.measurements_from_records(
+            records, source=",".join(args.streams))
+        history = (
+            sentinel.measurements_from_records(
+                accountant.read_records([args.baseline]),
+                source=args.baseline)
+            if args.baseline else []
+        )
         findings = sentinel.check_regression(
             fresh, history, floor=args.floor)
         # check_stale=False: whether a perf entry fires depends on which
@@ -115,7 +104,7 @@ def main(argv=None) -> int:
         rc = 0 if report.ok else 1
     else:
         if not args.streams:
-            parser.error("give at least one record stream (or --check)")
+            parser.error("give at least one record stream")
         report = accountant.account(records, run_id=args.run_id)
         if report.n_spans == 0:
             print("goodput: no span records found — is the producer wired "

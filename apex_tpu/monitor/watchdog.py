@@ -2,7 +2,7 @@
 
 The resilience subsystem reacts to signals the system DELIVERS — SIGTERM
 before preemption, NaN verdicts from the sentinel. A wedged collective, a
-deadlocked host thread, or a relay hang delivers nothing: the step simply
+deadlocked host thread, or a hung device delivers nothing: the step simply
 never finishes. :class:`StallWatchdog` is the complement — a daemon
 heartbeat thread that flags a step exceeding its deadline from OUTSIDE
 the (possibly stuck) training thread. Its ``escalations`` ladder carries

@@ -14,9 +14,8 @@ the standard flash recompute strategy, O(seq x block) memory in both
 directions.
 
 Single-chip long context: K/V residency caps the kernel at
-``_KV_RESIDENT_BYTES`` (below 16k bf16 / 8k fp32 keys at head_dim 128).
-Beyond
-it — or when the XLA fallback's full (sq, sk) score tensor would blow
+``_KV_RESIDENT_BYTES`` (below 14k bf16 / 7k fp32 keys at head_dim <= 128).
+Beyond it — or when the XLA fallback's full (sq, sk) score tensor would blow
 ``_SCORE_BYTES`` — dispatch switches to ``_attn_blockwise``: an XLA-level
 (cq, ck)-tiled online softmax with a custom lse-recompute VJP, the same
 math as the kernel one tile size up, supporting GQA, key-padding masks,
@@ -125,7 +124,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk,
     # accumulation via preferred_element_type — upcasting operands to fp32
     # before the dot forces the MXU's slow fp32 path and was the dominant
     # cost of this kernel; softmax math stays fp32 throughout
-    kpm_ref = refs[0] if has_kpm else None  # (1, SK) int32, 1 = padded key
+    kpm_ref = refs[0] if has_kpm else None  # (1, SK/BK, BK), 1 = padded
     o_ref, lse_ref = refs[-2:]
     q = q_ref[0]  # (BQ, D)
     seq_k = k_ref.shape[1]
@@ -147,7 +146,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk,
         if causal:
             s = jnp.where(_causal_keep(qi, j, bq, bk, window), s, _NEG_INF)
         if has_kpm:
-            s = jnp.where(kpm_ref[:, pl.ds(j * bk, bk)] == 0, s, _NEG_INF)
+            s = jnp.where(kpm_ref[0, pl.ds(j, 1), :] == 0, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -178,10 +177,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk,
     lse_ref[0, 0, :] = jnp.where(dead, -_NEG_INF, m + jnp.log(l))[:, 0]
 
 
-def _kpm_spec(heads, sk):
-    """Key-padding-mask block: the (b, sk) int32 mask row for this (b*h)
-    grid step — heads is static, so b = bh // heads is an index-map affine."""
-    return pl.BlockSpec((1, sk), lambda b_h, i, heads=heads: (b_h // heads, 0))
+def _kpm_spec(heads, num_kv, bk):
+    """Key-padding-mask block: this (b*h) grid step's mask out of the
+    (b, sk/bk, bk) int32 mask, one row per kv block — heads is static, so
+    b = bh // heads is an index-map affine. The block's last two dims EQUAL
+    the array's, which the TPU lowering accepts at any batch (a (1, sk)
+    block of a (b, sk) array is refused for every b > 1: 1 is neither b nor
+    a multiple of 8), and a kernel reads kv block j's keys as row j — a
+    dynamic sublane index, where slicing one long row at j*bk would need a
+    dynamic LANE offset that Mosaic only takes in multiples of 128."""
+    return pl.BlockSpec(
+        (1, num_kv, bk), lambda b_h, i, heads=heads: (b_h // heads, 0, 0)
+    )
 
 
 def _kv_spec(group, sk, d):
@@ -205,7 +212,7 @@ def _flash_fwd(q3, kv3, kpm, heads, group, scale, causal, interpret, bq, bk, win
     ]
     inputs = [q3, k3, v3]
     if has_kpm:
-        in_specs.append(_kpm_spec(heads, sk))
+        in_specs.append(_kpm_spec(heads, sk // bk, bk))
         inputs.append(kpm)
     o, lse = pl.pallas_call(
         functools.partial(
@@ -271,7 +278,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             p = jnp.where(_causal_keep(qi, j, bq, bk, window), p, 0.0)
         if has_kpm:
-            p = jnp.where(kpm_ref[:, pl.ds(j * bk, bk)] == 0, p, 0.0)
+            p = jnp.where(kpm_ref[0, pl.ds(j, 1), :] == 0, p, 0.0)
         dp = jax.lax.dot_general(
             do, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -313,7 +320,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p = jnp.where(_causal_keep(i, kj, bq, bk, window), p, 0.0)
         if has_kpm:
             # this kv block's slice of the padding row: keys of THIS block
-            p = jnp.where(kpm_ref[:, pl.ds(kj * bk, bk)] == 0, p, 0.0)
+            p = jnp.where(kpm_ref[0, pl.ds(kj, 1), :] == 0, p, 0.0)
         dv = dv + jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -363,7 +370,7 @@ def _flash_bwd(heads, group, scale, causal, interpret, bq, bk, window, res, do):
     ]
     inputs = [q3, k3, v3, do, lse3, delta3]
     if has_kpm:
-        in_specs.append(_kpm_spec(heads, sk))
+        in_specs.append(_kpm_spec(heads, sk // bk, bk))
         inputs.append(kpm)
     dq = pl.pallas_call(
         functools.partial(
@@ -388,7 +395,7 @@ def _flash_bwd(heads, group, scale, causal, interpret, bq, bk, window, res, do):
         row_q,                                             # delta full row
     ]
     if has_kpm:
-        in_specs_kv.append(_kpm_spec(heads, sk))
+        in_specs_kv.append(_kpm_spec(heads, sk // bk, bk))
     # per-Q-HEAD partials: grid still runs over all bh q-head rows, so two
     # q heads sharing a kv head never race on one output block
     dk_p, dv_p = pl.pallas_call(
@@ -426,15 +433,31 @@ _flash.defvjp(_flash_fwd_res, _flash_bwd)
 # Blockwise long-context path (single chip)
 # ---------------------------------------------------------------------------
 
-# The Pallas kernels keep K/V fully VMEM-resident per (batch, head) — the
-# fastest layout while K+V fit (8 MB leaves room for q/do blocks, fp32
-# accumulators, and double-buffering inside the 16 MB scoped-VMEM limit).
-# Past that, attention switches to the blockwise-XLA path below.
-_KV_RESIDENT_BYTES = 8 * 1024 * 1024
+# The Pallas kernels keep K/V (and, in the dk/dv kernel, Q/dO) fully
+# VMEM-resident per (batch, head) — the fastest layout while they fit. The
+# pipeline double-buffers every input, so the resident pair costs TWICE its
+# VMEM size out of the 16 MiB scoped limit, next to the q/do/o blocks and
+# fp32 accumulators; and VMEM pads the head dim to 128 lanes, so d=64 costs
+# what d=128 does (_kv_vmem_bytes). Past the budget, attention switches to
+# the blockwise-XLA path below.
+#
+# The budget is the edge of a compile sweep against the v5e (libtpu 0.0.34,
+# compile-only topology; fwd and fwd+bwd of causal, key-padding, GQA+window
+# and decode-shaped calls, bf16 and f32, d=64 and d=128, batch*heads 4..64,
+# K+V in 0.25 MiB steps): everything up to 7.5 MiB of padded K+V compiles;
+# 7.75 MiB is refused for VMEM in the dk/dv kernel (f32, batch*heads 64) and
+# 8 MiB in every kernel. 7 MiB keeps half a MiB of margin under the edge.
+_KV_RESIDENT_BYTES = 7 * 1024 * 1024
 # XLA fallback budget: the reference implementation materializes the full
 # (b, h, sq, sk) fp32 score tensor; beyond this it pages through HBM or
 # OOMs, so the blockwise path takes over.
 _SCORE_BYTES = 1 << 30
+
+
+def _kv_vmem_bytes(seq: int, d: int, esize: int) -> int:
+    """VMEM footprint of one (batch, head)'s resident pair (K+V, or Q+dO):
+    the head dim is padded to the 128-lane tile."""
+    return 2 * seq * (-(-d // 128) * 128) * esize
 
 
 def _bw_chunk(n: int, target: int) -> int:
@@ -728,7 +751,11 @@ def flash_attention(
     bq = min(block_q, sq)
     bk = min(block_k, sk)
     esize = jnp.dtype(q.dtype).itemsize
-    kv_resident = 2 * sk * d * esize < _KV_RESIDENT_BYTES
+    # the backward's dk/dv kernel holds Q/dO resident the way the others
+    # hold K/V, so the longer of the two sequences is what must fit
+    kv_resident = (
+        _kv_vmem_bytes(max(sq, sk), d, esize) <= _KV_RESIDENT_BYTES
+    )
     pallas_ok = (
         use_pallas
         and mask is None
@@ -756,7 +783,8 @@ def flash_attention(
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h_kv, sk, d)
     v3 = v.reshape(b * h_kv, sk, d)
+    kpm3 = None if kpm_i is None else kpm_i.reshape(b, sk // bk, bk)
     o = _flash(
-        q3, (k3, v3), kpm_i, h, group, scale, causal, interpret, bq, bk, window
+        q3, (k3, v3), kpm3, h, group, scale, causal, interpret, bq, bk, window
     )
     return o.reshape(b, h, sq, d)

@@ -11,9 +11,9 @@ reference's per-chunk remainder handling.
 The jnp twin (`_adam_flat_ref`) is bit-identical math used for the
 impl="xla" path and CPU tests; `fused_adam(fuse="flat")` in fused_adam.py
 plugs either into the optax interface. benchmarks/bench_optimizers.py
-measures flat-vs-tree; current numbers are in BENCH.md (CPU: tree Adam
-wins — flatten round-trip overhead; flat l2norm wins 1.7x on already-flat
-buffers, which is why the ZeRO optimizers use it).
+compares flat with tree; on a CPU tree Adam wins (flatten round-trip
+overhead) and flat l2norm wins 1.7x on already-flat buffers, which is why
+the ZeRO optimizers use it. Neither is measured on the chip.
 """
 
 import functools
@@ -170,8 +170,8 @@ def sumsq_flat(x_flat, impl: str = "auto"):
 def l2norm_flat(x_flat, impl: str = "auto"):
     """Global L2 norm of a flat buffer (padding zeros contribute 0).
 
-    Measured 1.7x faster than the tree-based ``multi_tensor_l2norm`` on
-    already-flat buffers even on CPU/XLA (BENCH.md) — the flat path is the
+    1.7x faster than the tree-based ``multi_tensor_l2norm`` on already-flat
+    buffers on CPU/XLA (bench_optimizers.py) — the flat path is the
     default wherever the data already lives in one buffer (ZeRO shards in
     distributed_fused_lamb; fused_adam's flat engine).
     """

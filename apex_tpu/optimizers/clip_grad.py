@@ -4,7 +4,8 @@ Reference parity: apex.contrib.clip_grad.clip_grad_norm_
 (contrib/clip_grad/clip_grad.py:16) — global-norm clip using
 multi_tensor_l2norm + multi_tensor_scale.
 
-Engine choice (measured, BENCH.md): the tree-based norm stays because the
+Engine choice (benchmarks/bench_optimizers.py, CPU runs only — not
+measured on the chip): the tree-based norm stays because the
 input here is a pytree — the flat reduction only wins when the data already
 lives in one buffer (flatten round-trips cost more than they save; see the
 adam tree-vs-flat row). ZeRO optimizers, whose shards ARE flat, use

@@ -126,7 +126,7 @@ def distributed_fused_lamb(
         # stage 1: GLOBAL grad norm (clip-after-allreduce, ref
         # distributed_fused_lamb.py _pipeline_step): local shard sum-of-
         # squares through the flat Pallas reduction (the shard is already
-        # one flat buffer — the case where flat wins, BENCH.md), then psum
+        # one flat buffer — the case where flat wins), then psum
         from apex_tpu.optimizers._fused_kernels import sumsq_flat
 
         sq = xlax.psum(sumsq_flat(gshard), axis_name)

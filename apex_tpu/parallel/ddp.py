@@ -28,14 +28,8 @@ def vma_tracking_live(axis_name: str) -> bool:
     (``check_vma=False`` turns ``pcast`` into a no-op, so the probe's
     type stays unvarying there.) Per-trace-context constant — hoist out
     of per-leaf loops."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:  # pre-vma jax: nothing is tracked
-        return False
-    probe = pcast(jnp.zeros(()), axis_name, to="varying")
-    try:
-        return axis_name in jax.typeof(probe).vma
-    except AttributeError:
-        return False
+    probe = jax.lax.pcast(jnp.zeros(()), axis_name, to="varying")
+    return axis_name in jax.typeof(probe).vma
 
 
 def grads_already_reduced(x, axis_name: str, tracking: bool = None) -> bool:
@@ -50,11 +44,7 @@ def grads_already_reduced(x, axis_name: str, tracking: bool = None) -> bool:
     tests/test_ddp.py's harness): the ``vma_tracking_live`` probe tells
     whether unvarying proves anything (pass it in when calling per leaf).
     """
-    try:
-        vma = jax.typeof(x).vma
-    except AttributeError:  # older tracer/no vma support: classic path
-        return False
-    if axis_name in vma:
+    if axis_name in jax.typeof(x).vma:
         return False  # genuinely per-rank varying
     if tracking is None:
         tracking = vma_tracking_live(axis_name)

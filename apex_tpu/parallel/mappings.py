@@ -54,12 +54,10 @@ def _all_gather_invariant_dim(x, axis_name: str, dim: int):
     axis-varying, failing the custom_vjp typecheck (caught by the GPT
     pp x tp x sp integration under default shard_map). Same collective,
     different type; identical under ``check_vma=False``."""
-    try:
-        # private import: jax exposes no public invariant gather yet —
-        # switch to the public API the release it appears
-        from jax._src.lax.parallel import all_gather_invariant
-    except ImportError:  # older jax: unchecked semantics, plain gather
-        return _all_gather_dim(x, axis_name, dim)
+    # private import: jax exposes no public invariant gather yet —
+    # switch to the public API the release it appears
+    from jax._src.lax.parallel import all_gather_invariant
+
     try:
         # no wrapper for the private invariant gather: record it under
         # the same op kind (identical bytes on the wire)
@@ -84,13 +82,9 @@ def _typed_gather(g, primal_probe, axis_name: str, dim: int):
     the usual replicated primal needs the invariant gather (checked
     shard_map owes an invarying cotangent), but a genuinely axis-varying
     primal — recorded as a zero-size residual slice carrying its vma —
-    needs the plain varying gather. Pre-vma jax / check_vma=False reads
-    everything unvarying AND accepts either, so plain gather is used."""
-    try:
-        varying = axis_name in jax.typeof(primal_probe).vma
-    except AttributeError:
-        varying = True
-    if varying:
+    needs the plain varying gather. check_vma=False reads everything
+    unvarying AND accepts either, so plain gather is used."""
+    if axis_name in jax.typeof(primal_probe).vma:
         return _all_gather_dim(g, axis_name, dim)
     from apex_tpu.parallel.ddp import vma_tracking_live
 
@@ -130,7 +124,7 @@ def _reduce_fwd(x, axis_name):
 def _reduce_bwd(axis_name, _, g):
     # the primal input was axis-VARYING (per-rank partial sums); the
     # cotangent of the psum'd output arrives invarying, so re-type it
-    # (identity under check_vma=False / pre-vma jax, and on numerics)
+    # (identity under check_vma=False, and on numerics)
     return (pcast_varying(g, axis_name),)
 
 

@@ -65,19 +65,7 @@ def initialize_distributed(
     so the call is a no-op returning ``(process_count, process_index)``
     (jax's auto-detection would otherwise raise on a dev box).
     """
-    # jax.distributed.is_initialized only exists on current jax; older
-    # builds expose the same fact through the global client handle
-    _is_init = getattr(jax.distributed, "is_initialized", None)
-    if _is_init is not None:
-        initialized = _is_init()
-    else:  # pre-0.5 jax: the global client handle is the same fact
-        try:
-            from jax._src.distributed import global_state
-
-            initialized = global_state.client is not None
-        except Exception:
-            initialized = False
-    if initialized:
+    if jax.distributed.is_initialized():
         return jax.process_count(), jax.process_index()
     cluster_env = any(
         v in os.environ
@@ -263,7 +251,7 @@ def _axis_rank(name: str):
     """Python 0 when the axis is trivial; traced ``lax.axis_index`` inside
     shard_map over that axis.  Outside shard_map with a >1 axis there IS no
     well-defined rank (the single-controller host sees all shards), so that
-    misuse raises instead of silently acting as rank 0 (VERDICT r3 weak #4);
+    misuse raises instead of silently acting as rank 0;
     non-axis errors (bad axis name, tracing bugs) always propagate."""
     if _MESH is None or int(get_mesh().shape[name]) == 1:
         return 0

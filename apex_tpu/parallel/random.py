@@ -114,9 +114,9 @@ def checkpoint_distributed(fn: Callable, axis_name: str = "tp"):
     must divide by the axis size (asserted — a silent floor-split would
     drop rows).
 
-    Measured (BENCH.md): wins when MANY segments stash boundaries (the
-    per-layer remat pattern — 3.7x less live memory at 16 segments,
-    tp=8); for a SINGLE segment the transient all-gather buffer outweighs
+    In compiled memory on the CPU mesh it wins when MANY segments stash
+    boundaries (the per-layer remat pattern — 3.7x less live memory at 16
+    segments, tp=8); for a SINGLE segment the transient all-gather buffer outweighs
     the one saved boundary (0.84x), so don't wrap a whole network in one
     call.
     """

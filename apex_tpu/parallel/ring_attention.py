@@ -216,7 +216,7 @@ def _ring(q, k, v, kbias, axis_name, causal, scale, block_size, window, zigzag):
 def _bias_placeholder(b: int, axis_name: str):
     """Rotatable stand-in for a None key-padding bias in the ring scan
     carry — typed varying so it survives the in-scan ppermute's vma under
-    checked shard_map (identity under check_vma=False / pre-vma jax)."""
+    checked shard_map (identity under check_vma=False)."""
     from apex_tpu.parallel.utils import pcast_varying
 
     return pcast_varying(jnp.zeros((b, 0)), axis_name)

@@ -49,20 +49,15 @@ def broadcast_data(keys, data, dtype=None):
 
 
 def pcast_varying(x, axis_names):
-    """``jax.lax.pcast(x, axis_names, to='varying')`` with an identity
-    fallback on jax versions predating the vma type system (pcast absent
-    there, and with no typing the cast is meaningless — exactly the
-    unchecked semantics every pre-vma path assumed)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis_names, to="varying")
-
-
-def _widen_leaf(x, want):
-    """pcast ``x`` to also vary over the axes in ``want`` it lacks."""
-    missing = tuple(sorted(set(want) - set(jax.typeof(x).vma)))
-    return pcast_varying(x, missing) if missing else x
+    """Type ``x`` varying over ``axis_names``, casting only the axes it
+    does not already vary over: ``jax.lax.pcast(..., to='varying')``
+    refuses a varying -> varying cast, and callers (error-feedback
+    residuals, scan carries) cannot know which axes a value picked up on
+    the way in."""
+    if isinstance(axis_names, str):
+        axis_names = (axis_names,)
+    missing = tuple(a for a in axis_names if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def promote_to_vma(tree, like):
@@ -70,16 +65,12 @@ def promote_to_vma(tree, like):
     axes — the scan-carry fixed-point helper: accumulators must start
     with the vma their loop bodies will produce (ring attention's block
     scans derive masks from rank positions, so outputs vary even when
-    inputs are replicated). No-op when already varying, under
-    ``check_vma=False``, or on pre-vma jax."""
-    try:
-        want = jax.typeof(like).vma
-    except AttributeError:
-        return tree
+    inputs are replicated). No-op when already varying or under
+    ``check_vma=False``."""
+    want = tuple(sorted(jax.typeof(like).vma))
     if not want:
         return tree
-
-    return jax.tree_util.tree_map(lambda x: _widen_leaf(x, want), tree)
+    return jax.tree_util.tree_map(lambda x: pcast_varying(x, want), tree)
 
 
 def pvary_params(tree, axis_name: str = "tp"):
@@ -106,15 +97,9 @@ def pvary_params(tree, axis_name: str = "tp"):
     a tree mixes both (tests/test_checked_vma.py shows the pattern).
     """
 
-    def one(x):
-        try:
-            if axis_name in jax.typeof(x).vma:
-                return x
-        except AttributeError:
-            return x
-        return pcast_varying(x, axis_name)
-
-    return jax.tree_util.tree_map(one, tree)
+    return jax.tree_util.tree_map(
+        lambda x: pcast_varying(x, axis_name), tree
+    )
 
 
 def vma_cond(pred, true_fn, false_fn, *operands):
@@ -134,8 +119,8 @@ def vma_cond(pred, true_fn, false_fn, *operands):
     outputs to that join INSIDE the branch.
 
     Falls back to plain ``lax.cond`` when nothing needs widening — in
-    particular on pre-vma jax, under ``check_vma=False``, and outside
-    ``shard_map``, where it is exactly ``jax.lax.cond``.
+    particular under ``check_vma=False`` and outside ``shard_map``, where
+    it is exactly ``jax.lax.cond``.
     """
     try:
         # muted: these shape probes re-trace branch Python (possibly
@@ -172,7 +157,7 @@ def vma_cond(pred, true_fn, false_fn, *operands):
         def g(*ops):
             out = fn(*ops)
             leaves, treedef = jax.tree_util.tree_flatten(out)
-            leaves = [l if w is None else _widen_leaf(l, w)
+            leaves = [l if w is None else pcast_varying(l, w)
                       for l, w in zip(leaves, wants)]
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
@@ -196,14 +181,8 @@ def scan_carry_fixed_point(body, carry, x0, max_iters: int = 3):
 
     ``x0``: one slice of the scan xs (e.g. ``tree_map(lambda a: a[0],
     xs)``); pass ``None`` for a None-xs scan. No-op under
-    ``check_vma=False`` / pre-vma jax. Returns the promoted carry.
+    ``check_vma=False``. Returns the promoted carry.
     """
-
-    def _vma(x):
-        try:
-            return jax.typeof(x).vma
-        except AttributeError:
-            return None
 
     # max_iters + 1 evals: a round whose widening REACHES the fixed point
     # must not raise — convergence means some eval produced no widening,
@@ -217,11 +196,11 @@ def scan_carry_fixed_point(body, carry, x0, max_iters: int = 3):
 
         def widen(c, o):
             nonlocal changed
-            have, want = _vma(c), getattr(o, "vma", None)
-            if have is None or not want or not (set(want) - set(have)):
+            have, want = jax.typeof(c).vma, getattr(o, "vma", None)
+            if not want or not (set(want) - set(have)):
                 return c
             changed = True
-            return _widen_leaf(c, want)
+            return pcast_varying(c, tuple(sorted(want)))
 
         carry = jax.tree_util.tree_map(widen, carry, out_carry)
         if not changed:

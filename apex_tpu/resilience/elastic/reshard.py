@@ -145,8 +145,11 @@ def _host_restore(directory: str, step: int, target: Any,
         node.pop(keys[-1])
         spliced.append((keys, arr))
     ckptr = ocp.PyTreeCheckpointer()
+    # the checkpoint's own structure comes from its metadata; orbax wraps
+    # that tree in a StepMetadata whose ``item_metadata.tree`` is the plain
+    # nested dict restore_args must mirror leaf for leaf
     args_tree = (
-        ckptr.metadata(_step_dir(directory, step))
+        ckptr.metadata(_step_dir(directory, step)).item_metadata.tree
         if drop_extra else plain_target
     )
     restore_args = jax.tree_util.tree_map(

@@ -148,7 +148,11 @@ class GPTTraining:
         cfg = self.cfg
         sample_tokens = jnp.zeros((cfg.micro_batch, cfg.seq_len), jnp.int32)
 
-        # tp-sharded init must run under the mesh like the step
+        # tp-sharded init must run under the mesh like the step — and
+        # jitted: an un-jitted shard_map executes primitive by primitive
+        # across the mesh (measured: a minute for a 2-layer model on 8
+        # virtual devices, against seconds as one program)
+        @jax.jit
         @functools.partial(
             shard_map, mesh=self.mesh, in_specs=P(), out_specs=P(),
             check_vma=False,
@@ -166,10 +170,10 @@ class GPTTraining:
             # ZeRO init needs the mesh axis (axis_index slices this
             # rank's shard); the state leaves come out dp-sharded
             # NamedShardings — the elastic restore's target layout
-            init_opt = functools.partial(
+            init_opt = jax.jit(functools.partial(
                 shard_map, mesh=self.mesh, in_specs=(P(),),
                 out_specs=self.opt_specs, check_vma=False,
-            )(self.opt.init)
+            )(self.opt.init))
             opt_state = init_opt(params)
         else:
             opt_state = jax.jit(

@@ -267,6 +267,62 @@ class ServingEngine:
 
     # -- compilation (all of it happens here) -------------------------------
 
+    def lower_programs(self, sharding=None) -> Dict[Any, Any]:
+        """Lower every program of the engine's life: one prefill per
+        bucket (keyed by bucket length) and the decode step (keyed
+        ``"decode"``). Also derives the cache layout (``self._spec``).
+
+        ``sharding`` places the abstract arguments; None means the default
+        device. The compile-only pre-flight (benchmarks/tpu_preflight.py)
+        passes a device of a TPU topology with no chip attached, to compile
+        exactly these programs before any chip time is spent."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.config
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        var_sds = jax.tree_util.tree_map(
+            lambda x: sds(x.shape, x.dtype), self.variables
+        )
+        b0 = cfg.prefill_buckets[0]
+
+        def _prefill_shape(variables, tokens):
+            return self.model.apply(
+                variables, tokens, cache_len=b0, mutable=["cache"]
+            )
+
+        _, shapes = jax.eval_shape(
+            _prefill_shape, var_sds, sds((1, b0), jnp.int32)
+        )
+        self._spec = CacheSpec.from_cache_shapes(shapes["cache"])
+        pool_sds = {
+            k: sds(shape, dtype)
+            for k, (shape, dtype) in self._spec.pool_shapes(
+                cfg.num_blocks, cfg.block_size).items()
+        }
+        i32, f32 = jnp.int32, jnp.float32
+        lowered = {}
+        for P in cfg.prefill_buckets:
+            lowered[P] = jax.jit(
+                self._make_prefill(P), donate_argnums=(0,)
+            ).lower(
+                pool_sds, var_sds, sds((P,), i32), sds((), i32),
+                sds((P // cfg.block_size,), i32), sds((), f32),
+                sds((2,), jnp.uint32),
+            )
+        B, MB = cfg.lanes, cfg.max_blocks_per_lane
+        lowered["decode"] = jax.jit(
+            self._make_decode(), donate_argnums=(0,)
+        ).lower(
+            pool_sds, var_sds, sds((B, MB), i32), sds((B,), i32),
+            sds((B,), i32), sds((B,), f32), sds((B, 2), jnp.uint32),
+            sds((B,), jnp.bool_),
+        )
+        return lowered
+
     def start(self) -> "ServingEngine":
         """Build the pool and AOT-compile every prefill bucket plus the
         decode step. Every compile of the engine's life happens inside
@@ -276,61 +332,28 @@ class ServingEngine:
         if self._started:
             return self
         import jax
-        import jax.numpy as jnp
 
         cfg = self.config
         with span("compile", router=self.router, step=-1):
-            b0 = cfg.prefill_buckets[0]
-
-            def _prefill_shape(tokens):
-                return self.model.apply(
-                    self.variables, tokens, cache_len=b0, mutable=["cache"]
-                )
-
-            _, shapes = jax.eval_shape(
-                _prefill_shape, jax.ShapeDtypeStruct((1, b0), jnp.int32)
-            )
-            self._spec = CacheSpec.from_cache_shapes(shapes["cache"])
-            pool_shapes = self._spec.pool_shapes(
-                cfg.num_blocks, cfg.block_size
-            )
+            # the weights live on the device ONCE and enter every program
+            # as an ARGUMENT: closed over, each of the bucket programs and
+            # the decode step would carry its own copy of the model as a
+            # constant (a 345M-parameter model is 1.4 GB per program, in
+            # HBM, in the compile and in the persistent cache)
+            self.variables = jax.device_put(self.variables)
+            for key, lowered in self.lower_programs().items():
+                if key == "decode":
+                    self._decode_c = lowered.compile()
+                else:
+                    self._prefill_c[key] = lowered.compile()
             self._pool = {
                 k: jax.device_put(np.zeros(shape, dtype))
-                for k, (shape, dtype) in pool_shapes.items()
+                for k, (shape, dtype) in self._spec.pool_shapes(
+                    cfg.num_blocks, cfg.block_size).items()
             }
-            pool_sds = {
-                k: jax.ShapeDtypeStruct(shape, dtype)
-                for k, (shape, dtype) in pool_shapes.items()
-            }
-            i32, f32 = jnp.int32, jnp.float32
-            key_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
-            for P in cfg.prefill_buckets:
-                lowered = jax.jit(
-                    self._make_prefill(P), donate_argnums=(0,)
-                ).lower(
-                    pool_sds,
-                    jax.ShapeDtypeStruct((P,), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((P // cfg.block_size,), i32),
-                    jax.ShapeDtypeStruct((), f32),
-                    key_sds,
-                )
-                self._prefill_c[P] = lowered.compile()
-            B, MB = cfg.lanes, cfg.max_blocks_per_lane
-            self._decode_c = jax.jit(
-                self._make_decode(), donate_argnums=(0,)
-            ).lower(
-                pool_sds,
-                jax.ShapeDtypeStruct((B, MB), i32),
-                jax.ShapeDtypeStruct((B,), i32),
-                jax.ShapeDtypeStruct((B,), i32),
-                jax.ShapeDtypeStruct((B,), f32),
-                jax.ShapeDtypeStruct((B, 2), jnp.uint32),
-                jax.ShapeDtypeStruct((B,), jnp.bool_),
-            ).compile()
             self._prefill_key = jax.random.PRNGKey(cfg.seed)
             self._keys = jax.random.split(
-                jax.random.PRNGKey(cfg.seed + 1), B
+                jax.random.PRNGKey(cfg.seed + 1), cfg.lanes
             )
         from apex_tpu.monitor.xray.compile_watch import CompileWatcher
 
@@ -349,11 +372,10 @@ class ServingEngine:
 
         from apex_tpu.models.generate import sample_next_token
 
-        cfg, spec = self.config, self._spec
-        model, variables = self.model, self.variables
+        cfg, spec, model = self.config, self._spec, self.model
         n_pb = P // cfg.block_size
 
-        def prefill(pool, tokens, true_len, block_ids, temp, key):
+        def prefill(pool, variables, tokens, true_len, block_ids, temp, key):
             logits, st = model.apply(
                 variables, tokens[None], cache_len=P, mutable=["cache"]
             )
@@ -394,12 +416,12 @@ class ServingEngine:
 
         from apex_tpu.models.generate import sample_next_token
 
-        cfg, spec = self.config, self._spec
-        model, variables = self.model, self.variables
+        cfg, spec, model = self.config, self._spec, self.model
         bs, nb, MB = cfg.block_size, cfg.num_blocks, cfg.max_blocks_per_lane
         kv_keys = [CacheSpec.key(l.path) for l in spec.kv_leaves]
 
-        def decode(pool, tables, positions, tokens, temps, keys, active):
+        def decode(pool, variables, tables, positions, tokens, temps, keys,
+                   active):
             def lane(table, pos, tok, temp, key):
                 safe = jnp.clip(table, 0, nb - 1)
                 kv = {}
@@ -727,7 +749,8 @@ class ServingEngine:
         try:
             with span("prefill", router=self.router, step=t):
                 out = self._prefill_c[P](
-                    self._pool, tokens, np.int32(L), block_ids,
+                    self._pool, self.variables, tokens, np.int32(L),
+                    block_ids,
                     np.float32(req.temperature), self._prefill_key,
                 )
                 self._pool, tok_dev, self._prefill_key = out[:3]
@@ -772,7 +795,8 @@ class ServingEngine:
                     # exactly the span the stall warn flags
                     self.fault_plan.maybe_slow_decode(t)
                 out = self._decode_c(
-                    self._pool, self._tables, self._positions,
+                    self._pool, self.variables, self._tables,
+                    self._positions,
                     self._last_tok, self._temps, self._keys,
                     self._lane_mask,
                 )

@@ -1,4 +1,4 @@
-"""Small-shape (openfold-tier) micro-benchmarks (VERDICT r3 item 9).
+"""Small-shape (openfold-tier) micro-benchmarks.
 
 Reference parity: apex/contrib/openfold_triton ships shape-specialized
 kernels (LayerNormSmallShapeOptImpl, small fused MHA) because at
@@ -8,8 +8,8 @@ dominate and the generic CUDA kernels lose.  The TPU question is
 different: do the generic Pallas kernels lose to plain XLA at these
 shapes (tile underfill on 8x128 lanes), and by how much?  This harness
 measures exactly that, with the same slope-timing method as the rest of
-the suite, so BENCH.md can carry a measured row instead of the r3 claim
-"subsumed by ops kernels" that VERDICT flagged as unmeasured.
+the suite. Only a run on the chip answers it; the claim "subsumed by the
+ops kernels" is not measured until one has been made.
 
 Shapes follow openfold's evoformer: LN hidden 64/128 (pair/msa channels)
 over many rows; MHA seq 128/256, head_dim 8/16 (!), few heads.

@@ -37,7 +37,7 @@ import sys
 import time
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     # model
     p.add_argument("--layers", type=int, default=2)
@@ -88,11 +88,58 @@ def parse_args():
     p.add_argument("--stall-terminate-after", type=float, default=None)
     # telemetry
     p.add_argument("--metrics-jsonl", default=None)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def build_model(args):
+    """The served model and its seeded random weights — ``main``'s own
+    construction, shared with ``chip_smoke.py``'s logits check so both
+    hold the same weights by construction."""
+    import jax
+    import numpy as np
+
+    from apex_tpu.models import GPTModel
+    from apex_tpu.transformer import TransformerConfig
+
+    tcfg = TransformerConfig(
+        num_layers=args.layers, hidden_size=args.hidden,
+        num_attention_heads=args.heads, vocab_size=args.vocab,
+        max_position_embeddings=args.max_seq_len,
+        hidden_dropout=0.0, attention_dropout=0.0,
+        position_embedding_type="rope",
+    )
+    model = GPTModel(config=tcfg)
+    # jitted: eager init dispatches every initializer as its own program
+    variables = jax.jit(model.init)(
+        jax.random.PRNGKey(args.seed), np.zeros((1, 4), np.int32)
+    )
+    return model, variables
+
+
+def serving_config(args):
+    """The engine geometry and admission policy these arguments ask for."""
+    from apex_tpu.serving import ServingConfig
+
+    return ServingConfig(
+        lanes=args.lanes, block_size=args.block_size,
+        num_blocks=args.blocks, max_seq_len=args.max_seq_len,
+        max_queue_depth=args.queue_depth,
+        ttft_budget_s=args.ttft_budget,
+        default_deadline_s=args.deadline,
+        max_prefills_per_tick=args.prefills_per_tick,
+        seed=args.seed,
+    )
+
+
+def main(argv=None):
+    """Serve; ``argv`` (default ``sys.argv[1:]``) lets a driver such as
+    ``chip_smoke.py`` run this very loop in-process."""
+    args = parse_args(argv)
+    # compiled programs persist across runs (apex_tpu/utils/compile_cache.py:
+    # JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache)
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # a drain needs SIGTERM OBSERVED (flag), not obeyed (die): the
     # notice supersedes the router module's die-by-signal flush hook in
     # either install order, and chains any flag-style handler
@@ -101,9 +148,7 @@ def main():
     notice = TerminationNotice(grace_s=args.grace_s)
 
     import jax
-    import numpy as np
 
-    from apex_tpu.models import GPTModel
     from apex_tpu.monitor import (
         JsonlSink, MemorySink, MetricRouter, StdoutSink,
     )
@@ -112,10 +157,7 @@ def main():
     )
     from apex_tpu.resilience.chaos import FaultPlan, parse_steps
     from apex_tpu.resilience.health import IncidentResponder
-    from apex_tpu.serving import (
-        PoissonLoadGenerator, ServingConfig, ServingEngine,
-    )
-    from apex_tpu.transformer import TransformerConfig
+    from apex_tpu.serving import PoissonLoadGenerator, ServingEngine
 
     sinks = [StdoutSink()]
     mem = MemorySink(kinds=("run", "span", "request"))
@@ -128,18 +170,7 @@ def main():
 
     with span("init"):
         jax.devices()  # backend up before anything records host indices
-        tcfg = TransformerConfig(
-            num_layers=args.layers, hidden_size=args.hidden,
-            num_attention_heads=args.heads, vocab_size=args.vocab,
-            max_position_embeddings=args.max_seq_len,
-            hidden_dropout=0.0, attention_dropout=0.0,
-            position_embedding_type="rope",
-        )
-        model = GPTModel(config=tcfg)
-        variables = model.init(
-            jax.random.PRNGKey(args.seed),
-            np.zeros((1, 4), np.int32),
-        )
+        model, variables = build_model(args)
         plan = FaultPlan(
             slow_decode_steps=parse_steps(args.chaos_slow_decode_steps),
             slow_decode_s=args.chaos_slow_decode_s,
@@ -158,16 +189,8 @@ def main():
                 dump_after=args.stall_dump_after,
                 terminate_after=args.stall_terminate_after,
             )
-        cfg = ServingConfig(
-            lanes=args.lanes, block_size=args.block_size,
-            num_blocks=args.blocks, max_seq_len=args.max_seq_len,
-            max_queue_depth=args.queue_depth,
-            ttft_budget_s=args.ttft_budget,
-            default_deadline_s=args.deadline,
-            max_prefills_per_tick=args.prefills_per_tick,
-            seed=args.seed,
-        )
-        eng = ServingEngine(model, variables, cfg, router=router,
+        eng = ServingEngine(model, variables, serving_config(args),
+                            router=router,
                             fault_plan=plan, watchdog=responder)
         gen = PoissonLoadGenerator(
             rate_rps=args.rate, vocab=args.vocab,

@@ -360,8 +360,8 @@ class TestUserRegistries:
 
 
 class TestCastThroughRNNScan:
-    """O1 cast behavior through the rnn/ scan cells (VERDICT r3 item 8;
-    ref: apex/amp/rnn_compat.py + SEQUENCE_CASTS in
+    """O1 cast behavior through the rnn/ scan cells (ref:
+    apex/amp/rnn_compat.py + SEQUENCE_CASTS in
     apex/amp/lists/torch_overrides.py — the reference needed special RNN
     handling because cuDNN RNNs bypass the functional overrides; here the
     cells are plain flax modules whose gate GEMMs go through the patched

@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp
-from apex_tpu.compat import HAS_VMA, shard_map
+from apex_tpu.compat import shard_map
 from apex_tpu.models.resnet import BasicBlock, ResNet, cross_entropy_loss
 from apex_tpu.optimizers import clip_grad_norm, fused_adam, fused_sgd
 
@@ -137,17 +137,6 @@ def _rel(a, b):
     return np.abs(a - b) / np.maximum(np.abs(b), 1e-3)
 
 
-@pytest.mark.skipif(
-    not HAS_VMA,
-    reason=(
-        "pre-vma jax (check_rep era) cannot infer replication for this "
-        "step's replicated out_specs: the amp step returns opt-state "
-        "leaves whose replication flows through fused-optimizer "
-        "internals check_rep's inference does not see through (vma "
-        "tracking handles it) — fails at HEAD since before PR 5, "
-        "jax-version skew, not a convergence regression"
-    ),
-)
 class TestDistributedMatchesSingle:
     """compare.py:36-47 — per-iteration loss equality, distributed vs not."""
 

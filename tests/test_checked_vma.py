@@ -22,14 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from apex_tpu.compat import HAS_VMA
 from apex_tpu.parallel.ring_attention import ring_attention
-
-# the whole module probes vma typing, which pre-vma (check_rep era) jax
-# does not implement — nothing here is meaningful there
-pytestmark = pytest.mark.skipif(
-    not HAS_VMA, reason="this jax has no vma tracking (check_rep era)"
-)
 
 
 @pytest.fixture
@@ -390,7 +383,7 @@ def test_vma_cond_mixed_vma_branches_checked():
     plain lax.cond typecheck under checked shard_map; parallel.vma_cond
     widens both outputs to their vma join INSIDE each branch and keeps
     cond's single-branch evaluation (the former known limitation in
-    docs/parallel.md, VERDICT r4 item 6)."""
+    docs/parallel.md)."""
     from apex_tpu.parallel import vma_cond
 
     mesh = Mesh(np.asarray(jax.devices()), ("dp",))

@@ -17,12 +17,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.compat import HAS_VMA, shard_map
+from apex_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.monitor.xray import ledger as xlax
 from apex_tpu.parallel import CompressionConfig, compress
 from apex_tpu.parallel.ddp import all_reduce_gradients
+from apex_tpu.parallel.utils import pcast_varying
 
 DEVS = np.asarray(jax.devices())
 pytestmark = pytest.mark.skipif(
@@ -221,7 +222,6 @@ class TestQuantizedCollectives:
         ops = {(e.op, e.dtype) for e in led.entries}
         assert ops == {("psum", "float32")}
 
-    @pytest.mark.skipif(not HAS_VMA, reason="checked shard_map (vma) only")
     def test_checked_vma_mode_invariant_result(self, mesh):
         """Under jax's default CHECKED shard_map the gathered result must
         type invariant (out_specs P()) exactly like the psum it replaces
@@ -234,7 +234,9 @@ class TestQuantizedCollectives:
         )
         def qsum(x):
             x = x.reshape(x.shape[-1])
-            x = jax.lax.pcast(x, "dp", to="varying")
+            # already dp-varying through in_specs: pcast_varying must
+            # pass it through (a raw varying -> varying pcast is refused)
+            x = pcast_varying(x, "dp")
             return compress.quantized_psum(x, "dp", CFG)
 
         got = np.asarray(qsum(g))

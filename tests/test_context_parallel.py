@@ -28,18 +28,6 @@ B, H, D = 2, 4, 8
 SEQ = 32
 
 
-def _skip_if_old_jaxlib_noncausal(causal, window=None):
-    """The non-causal, windowless ring schedule visits every chunk, which
-    this old jaxlib lowers through a PartitionId instruction that its SPMD
-    partitioner rejects ('PartitionId instruction is not supported for
-    SPMD partitioning'). Current jax lowers it fine; skip there-only."""
-    from apex_tpu.compat import HAS_VMA
-
-    if not HAS_VMA and not causal and window is None:
-        pytest.skip("old jaxlib: PartitionId unsupported in SPMD lowering "
-                    "of the non-causal ring schedule")
-
-
 def full_reference(q, k, v, causal):
     return flash_attention(q, k, v, causal=causal, impl="xla")
 
@@ -52,7 +40,6 @@ class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("cp", [4, 8])
     def test_forward_parity(self, rng, causal, cp):
-        _skip_if_old_jaxlib_noncausal(causal)
         mesh = parallel_state.initialize_model_parallel(
             context_parallel_size=cp, devices=jax.devices()[:cp]
         )
@@ -295,7 +282,7 @@ class TestRingAttention:
 
 
 class TestRingGQAAndKeyPadding:
-    """GQA x causal x window x kpm through the ring (VERDICT r3 item 3):
+    """GQA x causal x window x kpm through the ring:
     grouped K/V rotate (not repeated pre-ring), the sequence-sharded
     key_padding_mask rides with its chunk, and an all-padded visiting
     chunk is skipped like an out-of-band one."""
@@ -313,7 +300,6 @@ class TestRingGQAAndKeyPadding:
                              [(False, None), (True, None), (True, 12)])
     @pytest.mark.parametrize("use_kpm", [False, True])
     def test_parity_and_grads(self, rng, h_kv, causal, window, use_kpm):
-        _skip_if_old_jaxlib_noncausal(causal, window)
         cp = 4
         mesh = parallel_state.initialize_model_parallel(
             context_parallel_size=cp, devices=jax.devices()[:cp]

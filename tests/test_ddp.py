@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.compat import HAS_VMA, shard_map
+from apex_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.parallel import (
@@ -24,13 +24,6 @@ from apex_tpu.parallel import (
     all_reduce_gradients,
     broadcast_params,
 )
-
-_requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="asserts vma-typing semantics (jax.lax.pcast / "
-           "varying-vs-unvarying grads) absent on check_rep-era jax",
-)
-
 
 @pytest.fixture
 def mesh():
@@ -70,7 +63,6 @@ class TestAllReduceGradients:
                 np.asarray(got[k]), np.asarray(full[k]), rtol=1e-5, atol=1e-6
             )
 
-    @_requires_vma
     def test_predivide_buys_fp16_overflow_headroom(self, mesh):
         """Per-rank VARYING fp16 grads of 30000: a postdivide sum
         overflows fp16 (8 x 30000 >> 65504 -> inf) while
@@ -96,7 +88,6 @@ class TestAllReduceGradients:
             np.asarray(reduce(8.0)), 30000.0, rtol=1e-3
         )  # predivide: in-range mean (fp16 sequential-sum rounding)
 
-    @_requires_vma
     def test_allreduce_always_fp32_keeps_dtype_and_value(self, mesh):
         """fp32 accumulation around the psum rescues the same overflow case
         WITHOUT predivide, and the result comes back in the grads' dtype."""
@@ -115,7 +106,6 @@ class TestAllReduceGradients:
         assert out.dtype == jnp.float16
         np.testing.assert_allclose(np.asarray(out), 30000.0)
 
-    @_requires_vma
     def test_pmean_global_loss_grads_are_final_skip_allreduce(self, mesh):
         """The documented pmean'd-GLOBAL-loss regime (the SyncBatchNorm
         pattern): under checked shard_map those grads arrive unvarying and

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from apex_tpu.compat import HAS_VMA, shard_map
+from apex_tpu.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.optimizers import (
@@ -19,13 +19,6 @@ from apex_tpu.optimizers import (
     fused_lamb,
 )
 from apex_tpu.parallel import parallel_state
-
-_requires_vma = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="asserts vma-typing semantics (jax.lax.pcast / "
-           "varying-vs-unvarying grads) absent on check_rep-era jax",
-)
-
 
 DP = 4
 
@@ -224,7 +217,7 @@ class TestDistributedFusedAdam:
             init(params)
 
     def test_sharded_state_checkpoint_resume(self, rng, grads_seq, tmp_path):
-        """VERDICT r3 item 5: the ZeRO state crosses the shard_map boundary
+        """the ZeRO state crosses the shard_map boundary
         with zero_state_specs (per-rank shards concatenated into global
         flat arrays), round-trips through utils.checkpoint, and a resumed
         run continues the param trace exactly where the straight run is
@@ -617,7 +610,6 @@ class TestCheckedShardMapGrads:
         np.testing.assert_allclose(got, np.asarray(want_flat),
                                    rtol=1e-5, atol=1e-6)
 
-    @_requires_vma
     def test_pmean_global_loss_grads_with_average_off(self, rng):
         """The SyncBatchNorm doc pattern: jax.grad of a pmean'd GLOBAL
         loss returns the MEAN already — average_grads=False must slice it
@@ -655,7 +647,6 @@ class TestCheckedShardMapGrads:
         np.testing.assert_allclose(got, np.asarray(want_flat),
                                    rtol=1e-5, atol=1e-6)
 
-    @_requires_vma
     def test_mixed_vma_tree_per_leaf_dispatch(self, rng):
         """One varying leaf must not drag already-summed leaves through a
         second psum (concatenate auto-pvarys mixed operands): each leaf

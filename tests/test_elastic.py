@@ -144,7 +144,7 @@ class TestZeroRegroup:
 
 
 class TestRestoreResharded:
-    def test_8_to_4_regroups_and_relays(self, tmp_path):
+    def test_8_to_4_regroups_and_lays_out_again(self, tmp_path):
         d = str(tmp_path)
         state8 = _state(_mesh(8), 8, seed=1)
         integrity.save_checkpoint_verified(d, 3, state8)

@@ -539,8 +539,8 @@ class TestCli:
 
     def test_cli_works_without_jax(self, tmp_path):
         """The docs' offline claim, pinned: a capture is analyzable on a
-        box with NO jax at all (docs/benchmarking.md — the relay's
-        grab-and-run economics). The subprocess poisons jax/jaxlib/flax
+        box with NO jax at all (a trace comes back from the chip and is
+        reduced wherever there is a Python). The subprocess poisons jax/jaxlib/flax
         in sys.modules so any import along the CLI path fails loudly;
         the lazy PEP-562 package inits are what make this hold."""
         import subprocess

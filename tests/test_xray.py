@@ -430,6 +430,7 @@ class TestGPTStepComms:
         model = GPTModel(config=cfg)
         tokens = jnp.zeros((b, s), jnp.int32)
 
+        @jax.jit  # un-jitted, a shard_map runs primitive by primitive
         @functools.partial(
             shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
             check_vma=False,
