@@ -1,0 +1,244 @@
+"""From a profiler trace to numbers: busy union, idle share, time by op name,
+idle gaps attributed to the host span they fall in.
+
+Two steps, so that the arithmetic can be checked on a small recorded trace
+with no profiler: ``load_xplane`` turns the ``.xplane.pb`` the JAX profiler
+writes into plain event dicts (``plane``, ``line``, ``name``, ``start_ns``,
+``dur_ns``, and for device events the few string stats that tell kernels
+apart), and ``reduce`` works on those alone.
+
+What a TPU trace looks like (checked by hand on a v5e trace, PERF.md 5): one
+plane per chip named ``/device:TPU:<n>`` whose line ``XLA Ops`` holds one event
+per executed HLO op, named by the op's whole HLO text (``%self_attention.117 =
+(bf16[2,64,1024,64]...) custom-call(...), custom_call_target="tpu_custom_call"``:
+the instruction's name carries the flax module's scope, which is what tells a
+flash-attention kernel from a layer-norm kernel today). Nested ops such as a
+``cond`` and its body overlap: the busy time is the UNION of the intervals, and
+time by name is reported for leaf events only. Beside it lie ``XLA Modules``
+(one event per program run), ``Steps`` and ``Async XLA Ops`` (copies that
+overlap the ops; not counted as busy). ``/host:CPU`` holds the host's thread
+lines with the ``TraceAnnotation`` spans.
+
+``python perf/trace_reduce.py <file.xplane.pb>`` prints what a trace holds,
+for the look by hand that has to come before any new reader.
+"""
+
+import collections
+import json
+import re
+import sys
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+OPS_LINE = "XLA Ops"
+_KEPT_STATS = ("tf_op", "hlo_category", "long_name", "kernel_details",
+               "hlo_op", "hlo_module", "name")
+
+
+_TARGET = re.compile(r'custom_call_target="([^"]+)"')
+
+
+def split_hlo(raw):
+    """An ``XLA Ops`` event's name is the op's HLO text. Returns the
+    instruction's own name (``self_attention.117``) and what else of the text
+    tells kernels apart: the custom-call target and the head of the result
+    shape."""
+    name, sep, rest = raw.partition(" = ")
+    stats = {}
+    if sep:
+        stats["result"] = rest[:80]
+        m = _TARGET.search(rest)
+        if m:
+            stats["custom_call_target"] = m.group(1)
+    return name.lstrip("%"), stats
+
+
+def base_name(name):
+    """``fusion.895`` -> ``fusion``: the name without XLA's numbering."""
+    return re.sub(r"(\.\d+)+$", "", name)
+
+
+def load_xplane(path):
+    """Event dicts from an ``.xplane.pb``: the device planes' and the host
+    planes' events."""
+    import jax
+
+    data = jax.profiler.ProfileData.from_file(path)
+    events = []
+    for plane in data.planes:
+        on_device = bool(DEVICE_PLANE.match(plane.name))
+        if not on_device and not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                e = {"plane": plane.name, "line": line.name, "name": ev.name,
+                     "start_ns": float(ev.start_ns),
+                     "dur_ns": float(ev.duration_ns)}
+                if on_device and line.name == OPS_LINE:
+                    e["name"], stats = split_hlo(ev.name)
+                    for k, v in ev.stats:
+                        if k in _KEPT_STATS and isinstance(v, str):
+                            stats[k] = v[:300]
+                    if stats:
+                        e["stats"] = stats
+                events.append(e)
+    return events
+
+
+def union(intervals):
+    """Merged, sorted, non-overlapping [start, end) intervals."""
+    out = []
+    for s, e in sorted(intervals):
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _leaf_events(events, slack_ns=2.0):
+    """Events that contain no other event of the same line (a ``cond`` and
+    the ops of its body nest; only the innermost did the work). Times come
+    in picoseconds and are read as float nanoseconds, so neighbours can
+    overlap by a rounding error: an event counts as inside another only if
+    it also ENDS inside it, give or take ``slack_ns``."""
+    evs = sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"]))
+    leaves, stack = [], []
+    for e in evs:
+        end = e["start_ns"] + e["dur_ns"]
+        while stack and (stack[-1][1] <= e["start_ns"] + slack_ns
+                         or end > stack[-1][1] + slack_ns):
+            ev, _, has_child = stack.pop()
+            if not has_child:
+                leaves.append(ev)
+        if stack:
+            stack[-1][2] = True
+        stack.append([e, end, False])
+    for ev, _, has_child in stack:
+        if not has_child:
+            leaves.append(ev)
+    return leaves
+
+
+def reduce(events, chips=1, spans=(), device_required=True):
+    """The reduction the per-layer metrics read.
+
+    ``window_s``: first to last instant of anything kept (device ops and the
+    named host spans). ``busy_s``: union of device-op intervals, averaged
+    over the ``chips`` first device planes. ``op_seconds``: summed duration of
+    leaf device ops by name, over those planes. ``op_counts``: how many such events.
+    ``op_stats``: for each op name, the string stats its first event
+    carried. ``top_ops``: the ten
+    longest of ``op_seconds`` grouped by name without XLA's numbering. ``idle_gaps``: idle time on the first chip by
+    the host span that overlaps each gap most (``(no span)`` where none
+    does), longest first. ``span_seconds``: host time by span name."""
+    planes = sorted({e["plane"] for e in events
+                     if DEVICE_PLANE.match(e["plane"])},
+                    key=lambda p: int(DEVICE_PLANE.match(p).group(1)))[:chips]
+    span_names = set(spans)
+    host = [e for e in events if e["plane"].startswith("/host:")
+            and e["name"] in span_names]
+    if not planes:
+        if device_required:
+            raise ValueError("the trace holds no device plane: no operation "
+                             "ran on the device in the traced window")
+        # a CPU run (a test): host spans only, nothing said of a device
+        span_seconds = collections.Counter()
+        for h in host:
+            span_seconds[h["name"]] += h["dur_ns"] * 1e-9
+        ends = [(h["start_ns"], h["start_ns"] + h["dur_ns"]) for h in host]
+        return {"window_s": (max(e for _, e in ends)
+                             - min(s for s, _ in ends)) * 1e-9 if ends else 0.0,
+                "busy_s": None, "chips": 0, "op_seconds": {}, "op_stats": {},
+                "op_counts": {}, "top_ops": [], "idle_gaps": [],
+                "span_seconds": dict(span_seconds)}
+    dev = {p: [e for e in events
+               if e["plane"] == p and e["line"] == OPS_LINE] for p in planes}
+    if not any(dev.values()):
+        raise ValueError(f"no events on the {OPS_LINE!r} line of {planes}")
+    everything = host + [e for evs in dev.values() for e in evs]
+    t0 = min(e["start_ns"] for e in everything)
+    t1 = max(e["start_ns"] + e["dur_ns"] for e in everything)
+
+    busy, op_seconds, op_stats = [], collections.Counter(), {}
+    op_counts = collections.Counter()
+    merged_first = None
+    for p in planes:
+        merged = union([(e["start_ns"], e["start_ns"] + e["dur_ns"])
+                        for e in dev[p]])
+        if merged_first is None:
+            merged_first = merged
+        busy.append(sum(e - s for s, e in merged))
+        for e in _leaf_events(dev[p]):
+            op_seconds[e["name"]] += e["dur_ns"] * 1e-9
+            op_counts[e["name"]] += 1
+            if "stats" in e:
+                op_stats.setdefault(e["name"], e["stats"])
+
+    gaps, cursor = [], t0
+    for s, e in merged_first + [[t1, t1]]:
+        if s > cursor:
+            gaps.append((cursor, s))
+        cursor = max(cursor, e)
+    by_span = collections.Counter()
+    for gs, ge in gaps:
+        best, best_ov = "(no span)", 0.0
+        for h in host:
+            ov = min(ge, h["start_ns"] + h["dur_ns"]) - max(gs, h["start_ns"])
+            if ov > best_ov:
+                best, best_ov = h["name"], ov
+        by_span[best] += (ge - gs) * 1e-9
+    span_seconds = collections.Counter()
+    for h in host:
+        span_seconds[h["name"]] += h["dur_ns"] * 1e-9
+
+    grouped = collections.Counter()
+    for name, secs in op_seconds.items():
+        grouped[base_name(name)] += secs
+    ranked = sorted(grouped.items(), key=lambda kv: -kv[1])
+    return {
+        "window_s": (t1 - t0) * 1e-9,
+        "busy_s": sum(busy) * 1e-9 / len(planes),
+        "chips": len(planes),
+        "op_seconds": dict(op_seconds),
+        "op_stats": op_stats,
+        "op_counts": dict(op_counts),
+        "top_ops": [[k, v] for k, v in ranked[:10]],
+        "idle_gaps": [[k, v] for k, v in sorted(
+            by_span.items(), key=lambda kv: -kv[1])],
+        "span_seconds": dict(span_seconds),
+    }
+
+
+def describe(path, top=40):
+    """What a trace holds: planes, lines, event counts, the longest op names
+    with their stats. For reading by hand."""
+    import jax
+
+    data = jax.profiler.ProfileData.from_file(path)
+    lines = []
+    for plane in data.planes:
+        lines.append(f"PLANE {plane.name}")
+        for line in plane.lines:
+            evs = list(line.events)
+            total = sum(e.duration_ns for e in evs) * 1e-9
+            lines.append(f"  LINE {line.name!r}: {len(evs)} events, "
+                         f"{total:.4f} s summed")
+            if not DEVICE_PLANE.match(plane.name) and len(evs) > 2000:
+                continue
+            by = collections.Counter()
+            first = {}
+            for e in evs:
+                by[e.name] += e.duration_ns * 1e-9
+                first.setdefault(e.name, e)
+            for name, secs in by.most_common(top):
+                stats = {k: (v[:120] if isinstance(v, str) else v)
+                         for k, v in first[name].stats}
+                lines.append(f"    {secs:10.6f} s  {name[:100]}  "
+                             f"{json.dumps(stats, default=str)[:400]}")
+    return "\n".join(lines)
+
+
+if __name__ == "__main__":
+    print(describe(sys.argv[1]))

@@ -1,0 +1,152 @@
+"""BENCHMARK.json against the contract's rules of form, and against the
+files it names."""
+
+import json
+import os
+import re
+
+import pytest
+
+from _bench import PERF, REPO, benchmark
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return benchmark()
+
+
+def _line(text):
+    return (isinstance(text, str) and 1 <= len(text) <= 200
+            and "\n" not in text and "\t" not in text)
+
+
+def test_parses_with_exactly_the_contracts_keys(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
+    assert isinstance(bench["run_seconds"], int)
+    assert 1 <= bench["run_seconds"] <= 51
+    assert 1 <= len(bench["command"]) <= 32
+    assert all(_line(w) for w in bench["command"])
+    assert 1 <= len(bench["paths"]) <= 16
+    for p in bench["paths"]:
+        assert PATH.match(p) and not p.startswith("/") and ".." not in p
+        assert os.path.isdir(os.path.join(REPO, p))
+
+
+def test_command_names_a_file_under_paths(bench):
+    files = [w for w in bench["command"] if "/" in w]
+    assert files, "the command names no program file"
+    for w in files:
+        assert any(w.startswith(p + "/") for p in bench["paths"])
+        assert os.path.isfile(os.path.join(REPO, w))
+
+
+def test_names_units_and_lengths(bench):
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    assert 1 <= len(bench["end_to_end"]) <= 16
+    assert 1 <= len(bench["per_layer"]) <= 128
+    assert 1 <= len(bench["configs"]) <= 24
+    assert 1 <= len(bench["workloads"]) <= 24
+    for group in (metrics, bench["workloads"], bench["configs"]):
+        names = [x["name"] for x in group]
+        assert len(names) == len(set(names))
+        assert all(NAME.match(n) for n in names)
+    for m in metrics:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+    for m in bench["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in bench["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert _line(m["layer"])
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
+    for w in bench["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and _line(w["why"])
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    assert len(pairs) == len(set(pairs))
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 4)
+
+
+def test_every_config_is_used_and_its_file_states_its_sizes(bench):
+    used = {w["config"] for w in bench["workloads"]}
+    files = [c["file"] for c in bench["configs"]]
+    assert len(files) == len(set(files))
+    for c in bench["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["name"] in used and _line(c["source"]) and _line(c["why"])
+        assert len(c["reduced"]) <= 16
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+        with open(os.path.join(REPO, c["file"])) as f:
+            body = json.load(f)
+        assert body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"]
+        for key in c["reduced"]:
+            assert NAME.match(key)
+            assert not re.search(r"(_dim|_rank|hidden|intermediate|head_)",
+                                 key), f"{key} is a width"
+
+
+def test_every_cells_files_exist(bench):
+    for w in bench["workloads"]:
+        path = os.path.join(PERF, "workloads", w["name"] + ".json")
+        assert os.path.isfile(path), path
+        with open(path) as f:
+            cell = json.load(f)
+        assert cell["config"] == w["config"]
+        assert cell["chips"] == w["chips"]
+        assert os.path.isfile(os.path.join(PERF, "drivers",
+                                           cell["driver"] + ".py"))
+        assert "limits" in cell and cell["limits"]
+    for m in bench["per_layer"]:
+        assert os.path.isfile(os.path.join(PERF, "layer_metrics",
+                                           m["name"] + ".py")), m["name"]
+
+
+def test_every_cell_reports_what_its_layer_metrics_move(bench):
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+
+    def reported_by(metric):
+        return set(metric.get("workloads", cells))
+
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert reported_by(m) <= cells, m["name"]
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e and m["moves"] != "setup_s"
+        target = reported_by(e2e[m["moves"]])
+        if "workloads" in m:
+            assert set(m["workloads"]) <= target, m["name"]
+    for cell in cells:
+        mine = [n for n, m in e2e.items() if cell in reported_by(m)]
+        assert "setup_s" in mine and len(mine) >= 2, cell
+        layer = [m for m in bench["per_layer"]
+                 if cell in reported_by(m) and m["moves"] in mine]
+        assert layer, f"{cell} reports no per-layer metric"
+        # beside a kernel's roofline, the whole step's share of the peak
+        for m in layer:
+            if m["name"].endswith("_roofline"):
+                assert any("mfu" in o["name"] and o["moves"] == m["moves"]
+                           for o in layer), m["name"]
+
+
+def test_layer_names_are_perf_mds(bench):
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        text = f.read()
+    for m in bench["per_layer"]:
+        assert m["layer"] in text, m["layer"]
