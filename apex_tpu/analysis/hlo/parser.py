@@ -26,7 +26,15 @@ What the parser understands, and deliberately nothing more:
   such), ``channel_id``, and the ``metadata={op_name=...
   source_file=... source_line=N}`` provenance XLA carries through,
 - entry parameters and the entry ROOT with their ``sharding={...}``
-  annotations and jax's human labels (``params['params'][...]``).
+  annotations and jax's human labels (``params['params'][...]``),
+- any single instruction (:func:`parse_instruction`, and all of a
+  module's through ``HloModule.instructions()``): name, opcode, operand
+  and called-computation names, the ``op_name`` scope path, a
+  custom-call's target and its ``kernel_metadata``. An instruction may
+  span lines (a Pallas kernel's ``kernel_metadata={...}`` prints as
+  multi-line JSON); the same function reads a TPU profiler event's
+  name, which is the instruction's whole text
+  (``monitor/xray/timeline``).
 
 Byte conventions match the xray ledger's (the differ depends on it):
 a collective's payload is its OPERAND — for all-gather the local shard,
@@ -45,10 +53,12 @@ __all__ = [
     "HloOperand",
     "HloCollective",
     "HloParam",
+    "HloInstruction",
     "HloModule",
     "COLLECTIVE_KINDS",
     "module_text",
     "parse_hlo_module",
+    "parse_instruction",
     "balanced",
     "parse_iota_list",
     "realized_aliases",
@@ -103,21 +113,36 @@ def balanced(text: str, start: int, open_ch: str = "{",
             f"expected {open_ch!r} at index {start}, found "
             f"{text[start:start + 1]!r}"
         )
-    depth = 0
-    i, n = start, len(text)
-    while i < n:
-        c = text[i]
+    # hop from bracket to bracket (and quote to quote) instead of walking
+    # every character: an instruction's text runs to kilobytes and a
+    # profiler capture holds tens of thousands of them
+    marks = re.compile('["' + re.escape(open_ch) + re.escape(close_ch) + "]")
+    depth, pos = 0, start
+    while True:
+        m = marks.search(text, pos)
+        if m is None:
+            break
+        i, c = m.start(), m.group()
+        pos = i + 1
         if c == '"':
-            i += 1
-            while i < n and text[i] != '"':
-                i += 2 if text[i] == "\\" else 1
+            while True:
+                k = text.find('"', pos)
+                if k < 0:
+                    raise ValueError(
+                        f"unbalanced {open_ch!r} section at index {start}"
+                    )
+                pos = k + 1
+                escapes = 0
+                while text[k - 1 - escapes] == "\\":
+                    escapes += 1
+                if escapes % 2 == 0:
+                    break
         elif c == open_ch:
             depth += 1
-        elif c == close_ch:
+        else:
             depth -= 1
             if depth == 0:
                 return text[start + 1:i], i
-        i += 1
     raise ValueError(f"unbalanced {open_ch!r} section at index {start}")
 
 
@@ -294,6 +319,26 @@ class HloParam:
         return self.shape.nbytes
 
 
+@dataclasses.dataclass(frozen=True)
+class HloInstruction:
+    """One instruction, as far as the scope join reads it
+    (``monitor/xray/timeline/hlo_scopes.py``)."""
+
+    name: str  # no leading %: the key a profiler event joins on
+    opcode: str
+    op_name: str  # metadata's scope path; "" when XLA kept none
+    operands: Tuple[str, ...]  # operand instruction names
+    #: computations it runs: a fusion's / reduce's (``calls=``,
+    #: ``to_apply=``), a conditional's branches, a while's body and
+    #: condition
+    calls: Tuple[str, ...]
+    custom_call_target: str = ""
+    #: a Pallas kernel's ``frontend_attributes={kernel_metadata={...}}``
+    kernel_metadata: Tuple[Tuple[str, str], ...] = ()
+    computation: str = ""
+    line: int = 0
+
+
 @dataclasses.dataclass
 class HloModule:
     """The parsed module: what the HLO passes read."""
@@ -305,9 +350,25 @@ class HloModule:
     entry_root_shardings: Optional[List[HloSharding]]
     input_output_alias: Dict[int, int]  # param index -> output index
     entry_name: str = ""
+    text: str = dataclasses.field(default="", repr=False)
+    _instructions: Optional[List[HloInstruction]] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
     def collectives_in_entry(self) -> List[HloCollective]:
         return [c for c in self.collectives if c.computation == self.entry_name]
+
+    def instructions(self) -> List[HloInstruction]:
+        """Every instruction of every computation, in text order. Parsed
+        on first use and kept: the passes that read collectives alone
+        never pay for it."""
+        if self._instructions is None:
+            parsed = (
+                parse_instruction(text, comp, lineno)
+                for comp, _, lineno, text in _iter_instructions(self.text)
+            )
+            self._instructions = [ins for ins in parsed if ins is not None]
+        return self._instructions
 
 
 _GROUPS_LITERAL_RE = re.compile(r"replica_groups=\{")
@@ -316,7 +377,8 @@ _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?"
 )
 _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
-_METADATA_RE = re.compile(r"metadata=\{")
+# not the tail of ``kernel_metadata={``
+_METADATA_RE = re.compile(r"(?<!\w)metadata=\{")
 _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _SOURCE_FILE_RE = re.compile(r'source_file="((?:[^"\\]|\\.)*)"')
 _SOURCE_LINE_RE = re.compile(r"source_line=(\d+)")
@@ -512,19 +574,38 @@ def mlir_marked_aliases(
 def _iter_instructions(text: str) -> Iterator[Tuple[str, bool, int, str]]:
     """``(computation_name, in_entry, line_number, instruction_text)``
     tuples. Computation bodies open with ``%name (...) ... {`` or
-    ``ENTRY ... {`` at column 0 and close with ``}`` at column 0."""
+    ``ENTRY ... {`` at column 0 and close with a line that is ``}`` alone.
+    An instruction that spans lines (a custom-call whose
+    ``kernel_metadata={`` prints as multi-line JSON, continued by a line
+    that STARTS with ``}}``) is yielded as one line, its continuations
+    joined by spaces."""
     comp, in_entry = "", False
+    pending: Optional[list] = None
     for lineno, line in enumerate(text.splitlines(), start=1):
         if line.startswith(("%", "ENTRY")):
             m = _COMPUTATION_RE.match(line)
             if m:
+                if pending:
+                    yield tuple(pending)
+                    pending = None
                 comp, in_entry = m.group("name"), bool(m.group("entry"))
                 continue
-        if line.startswith("}"):
+        if line.rstrip() == "}":
+            if pending:
+                yield tuple(pending)
+                pending = None
             comp, in_entry = "", False
             continue
-        if comp and line.lstrip().startswith(("%", "ROOT")):
-            yield (comp, in_entry, lineno, line)
+        if not comp:
+            continue
+        if _INSTR_RE.match(line):
+            if pending:
+                yield tuple(pending)
+            pending = [comp, in_entry, lineno, line]
+        elif pending:
+            pending[3] += " " + line.strip()
+    if pending:
+        yield tuple(pending)
 
 
 #: opcode right before its operand parens: ``<type> opcode(`` — the type
@@ -547,6 +628,63 @@ def _find_opcode(rest: str) -> Tuple[str, int]:
     return "", -1
 
 
+_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
+_KERNEL_METADATA_RE = re.compile(r"kernel_metadata=\{")
+_JSON_PAIR_RE = re.compile(r'"((?:[^"\\]|\\.)*)"\s*:\s*"((?:[^"\\]|\\.)*)"')
+_CALLS_RE = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=%?([\w.\-]+)"
+)
+_BRANCHES_RE = re.compile(r"branch_computations=\{")
+
+
+def parse_instruction(text: str, computation: str = "",
+                      line: int = 0) -> Optional[HloInstruction]:
+    """One instruction's text (``%name = type opcode(operands), attrs``,
+    with or without ``ROOT``, on one line or several) as an
+    :class:`HloInstruction`; None when ``text`` is no instruction. Reads
+    a line of a module and a TPU profiler event's name alike."""
+    m = _INSTR_RE.match(" ".join(text.split("\n")))
+    if m is None:
+        return None
+    rest = m.group("rest")
+    opcode, paren = _find_opcode(rest)
+    operands: Tuple[str, ...] = ()
+    attrs = rest
+    if paren >= 0:
+        try:
+            operand_text, end = balanced(rest, paren, "(", ")")
+        except ValueError:  # an event name cut short by the exporter
+            operand_text, end = rest[paren + 1:], len(rest)
+        operands = tuple(_OPERAND_NAME_RE.findall(operand_text))
+        attrs = rest[end + 1:]
+    calls = _CALLS_RE.findall(attrs)
+    bm = _BRANCHES_RE.search(attrs)
+    if bm:
+        body, _ = balanced(attrs, bm.end() - 1)
+        calls += _OPERAND_NAME_RE.findall(body)
+    target = _TARGET_RE.search(attrs)
+    kernel: Tuple[Tuple[str, str], ...] = ()
+    km = _KERNEL_METADATA_RE.search(attrs)
+    if km:
+        try:
+            body, _ = balanced(attrs, km.end() - 1)
+        except ValueError:
+            body = attrs[km.end():]
+        kernel = tuple(_JSON_PAIR_RE.findall(body))
+    return HloInstruction(
+        name=m.group("name"),
+        opcode=opcode,
+        op_name=_parse_metadata(attrs)[0],
+        operands=operands,
+        calls=tuple(calls),
+        custom_call_target=target.group(1) if target else "",
+        kernel_metadata=kernel,
+        computation=computation,
+        line=line,
+    )
+
+
 def parse_hlo_module(compiled_or_text) -> HloModule:
     """Parse one HLO module's text into the structured form above."""
     text = module_text(compiled_or_text)
@@ -558,6 +696,7 @@ def parse_hlo_module(compiled_or_text) -> HloModule:
         entry_root_shapes=[],
         entry_root_shardings=None,
         input_output_alias=realized_aliases(text),
+        text=text,
     )
     frames = _parse_stack_frames(text)
     # result shapes by instruction name: current XLA prints operands as

@@ -1,12 +1,16 @@
 """Run-level goodput: span ledger, accountant, fleet health, perf gate.
 
 The run-lifecycle layer of the observability stack (docs/observability.md
-"Goodput & fleet health"). Four cooperating pieces, all through the
+"Goodput & fleet health"). Cooperating pieces, all through the
 shared MetricRouter record schema:
 
 - ``spans``      — the ``kind="span"`` phase ledger (closed taxonomy
   :data:`~apex_tpu.monitor.goodput.spans.PHASES`), ``kind="run"``
   incarnation headers, and the torn-stream teardown flush.
+- ``scopes``     — the device-side counterpart of the phase ledger: the
+  closed registries of a training step's phases (``jax.named_scope``)
+  and of the Pallas kernels' names (``kernel_metadata``), which the
+  timeline reader joins a profiler capture to.
 - ``accountant`` — replays one or more streams (multiple incarnations,
   multiple hosts) into a goodput/badput partition whose identity
   ``productive + Σ badput + unattributed == wall`` is exact.
@@ -38,6 +42,11 @@ _EXPORTS = {
     "set_router": "spans",
     "get_router": "spans",
     "flush_open_spans": "spans",
+    # scopes
+    "STEP_PHASES": "scopes",
+    "KERNELS": "scopes",
+    "step_phase": "scopes",
+    "kernel_metadata": "scopes",
     # accountant
     "GoodputReport": "accountant",
     "account": "accountant",
@@ -55,7 +64,7 @@ _EXPORTS = {
 }
 
 __all__ = sorted(_EXPORTS) + [
-    "spans", "accountant", "fleet", "live", "sentinel",
+    "spans", "scopes", "accountant", "fleet", "live", "sentinel",
 ]
 
 _SUBMODULES = frozenset(__all__) - frozenset(_EXPORTS)

@@ -34,14 +34,24 @@ the router module's best-effort atexit/SIGTERM teardown so a real
 SIGTERM (the chaos harness's preemption drill) cannot tear the final
 spans off the stream.
 
+One clock with the device: when jax is already imported, an open span
+also holds a ``jax.profiler.TraceAnnotation(phase)`` (what
+``utils/timers.py:annotate`` opens), so a profiler capture shows
+``data_wait``, ``step``, ``ckpt_save``, ``snapshot`` ... on the profiler's
+own clock, and the timeline reader (``monitor/xray/timeline``) puts the
+device's idle gaps down to the phase that covers them. With no profiler
+session on, the annotation costs a flag test.
+
 jax-free by design (the router-module discipline): the accountant and
-this module must import on a box with no jax at all. The ``host`` field
+this module must import on a box with no jax at all — jax is looked up in
+``sys.modules``, never imported. The ``host`` field
 comes from ``make_record`` (router.py), which resolves
 ``jax.process_index()`` only when a jax backend is already live.
 """
 
 import hashlib
 import os
+import sys
 import threading
 import time
 import uuid
@@ -77,6 +87,9 @@ __all__ = [
 #: - ``ckpt_save``     — host blocked issuing/finalizing a checkpoint
 #: - ``ckpt_restore``  — restoring one at startup
 #: - ``rollback``      — in-memory snapshot restore after an anomaly
+#: - ``snapshot``      — the rollback ring's device-to-host copy of the
+#:   carried state on its cadence (``RollbackBuffer.snapshot``): the host
+#:   loop blocks on it, so it is badput the rollback pays in advance
 #: - ``stall``         — watchdog-detected dead time (no heartbeat)
 #: - ``incident``      — a stall that escalated: the wedged time from the
 #:   last heartbeat to the incident responder's self-termination
@@ -120,6 +133,7 @@ PHASES = (
     "ckpt_save",
     "ckpt_restore",
     "rollback",
+    "snapshot",
     "stall",
     "incident",
     "remediation",
@@ -160,6 +174,10 @@ PRODUCTIVE_PHASES = ("step", "prefill", "decode")
 #: ``handoff`` sits just below the serving work phases: the block copy
 #: blocks the fleet loop, but a decode tick overlapping it (another
 #: replica's lane advancing) is still productive time.
+#: ``snapshot`` sits with ``rollback``, whose insurance it is: below
+#: ``step`` (the ring copies a state the step has already produced; a
+#: caller that snapshots inside its step span keeps that second
+#: productive) and above ``compile``/``data_wait``.
 #: ``drain`` sits below the serving work phases (a drain window is an
 #: envelope: decode ticks inside it are still productive) but above
 #: ``init``/``shutdown`` so its exposed overhead is named, not generic.
@@ -174,6 +192,7 @@ PHASE_PRIORITY = (
     "ckpt_save",
     "ckpt_restore",
     "rollback",
+    "snapshot",
     "compile",
     "data_wait",
     "stall",
@@ -231,6 +250,18 @@ def emit_span(router, phase: str, start: float, dur_s: float,
     )
 
 
+def _open_annotation(phase: str):
+    """The span on the profiler's clock: an entered
+    ``jax.profiler.TraceAnnotation``, or None when jax is not imported
+    (never imported from here: the module stays jax-free)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(phase)
+    annotation.__enter__()
+    return annotation
+
+
 class Span:
     """One open phase span; emits its record on :meth:`close`.
 
@@ -252,6 +283,7 @@ class Span:
         self.fields = fields
         self._router = router
         self._closed = False
+        self._annotation = _open_annotation(phase)
         self.start = time.perf_counter()
         with _LOCK:
             _OPEN[id(self)] = self
@@ -263,6 +295,9 @@ class Span:
         with _LOCK:
             _OPEN.pop(id(self), None)
         dur = time.perf_counter() - self.start
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         router = self._router if self._router is not None else _ROUTER
         return emit_span(
             router, self.phase, self.start, dur, step=self.step,

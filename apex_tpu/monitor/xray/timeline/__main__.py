@@ -7,11 +7,19 @@ overlap and bubble fractions. Exit status: 0 on a successful analysis
 with at least one step, 1 when no trace files / no device ops were
 found (so CI can gate on "the capture was analyzable").
 
-The bandwidth join needs the compiled step's HLO and the mesh, which a
-bare log dir does not carry — run the examples with
-``--profile-analyze`` for the joined report, or call
-``timeline.analyze_logdir(logdir, module=..., mesh=..., ledger=...)``
-programmatically.
+``--hlo PATH`` joins the capture to the compiled step's text (what
+``pretrain_gpt.py --profile-analyze`` writes beside its capture as
+``step.hlo.txt``, ``benchmarks/capture_cell.py`` beside a benchmark
+cell's): device self time by step phase (forward / backward / unscale /
+optimizer / guard), by Pallas kernel and by flax module, the share no
+rule could place, and the device's idle gaps by the host annotation
+that covers them (the goodput phases; ``--annotation NAME`` adds
+others). docs/observability.md "Reading a chip capture".
+
+The bandwidth join needs the mesh as well, which a bare log dir does not
+carry — run the examples with ``--profile-analyze`` for that report, or
+call ``timeline.analyze_logdir(logdir, module=..., mesh=...,
+ledger=...)`` programmatically.
 
 Flags: ``--json PATH`` appends the ``kind="profile"`` records to a
 jsonl (the shared MetricRouter schema); ``--schedule NAME --pp P
@@ -42,6 +50,12 @@ def main(argv=None) -> int:
                    "jax.profiler.trace / ProfilerTrigger)")
     p.add_argument("--json", default=None,
                    help="append kind='profile' records to this jsonl")
+    p.add_argument("--hlo", default=None,
+                   help="the compiled step's HLO text: joins device time "
+                   "to step phases, Pallas kernels and modules")
+    p.add_argument("--annotation", action="append", default=[],
+                   help="a host TraceAnnotation name to put idle gaps down "
+                   "to, beside the goodput phases (repeatable)")
     p.add_argument("--schedule", default=None, choices=_SCHEDULE_CHOICES,
                    help="pipeline schedule name for the predicted-bubble "
                    "join")
@@ -68,12 +82,24 @@ def main(argv=None) -> int:
             # a usage message, not a traceback
             p.error(str(e))
 
+    from apex_tpu.monitor.goodput.spans import PHASES
     from apex_tpu.monitor.xray.timeline.analyzer import analyze_logdir
 
+    module = None
+    if args.hlo is not None:
+        from apex_tpu.analysis.hlo.parser import parse_hlo_module
+
+        try:
+            with open(args.hlo) as f:
+                module = parse_hlo_module(f.read())
+        except OSError as e:
+            print(f"timeline: {e}", file=sys.stderr)
+            return 1
     try:
         report = analyze_logdir(
-            args.logdir, predicted_bubble_fraction=predicted,
+            args.logdir, module=module, predicted_bubble_fraction=predicted,
             schedule=args.schedule,
+            annotations=PHASES + tuple(args.annotation),
         )
     except (FileNotFoundError, ValueError) as e:
         print(f"timeline: {e}", file=sys.stderr)

@@ -28,6 +28,22 @@ wire bytes — **achieved bytes/s per mesh axis**, and with an ICI
 bandwidth a measured utilization percentage. The static roofline table
 becomes a measurement.
 
+The scope join speaks the program's names: with the compiled step's
+module (``module=``, the argument the bandwidth join already takes),
+every device op event is looked up by instruction name in
+``scope_map`` and its SELF time (its duration less the events nested in
+it: a ``cond`` and the ops of its body overlap, and only the innermost
+did the work) is booked to the step phase it was traced under
+(``goodput.scopes.STEP_PHASES``, ``forward_backward`` split into forward
+and backward), to the flax module, and — for a Pallas custom-call — to
+the kernel its ``kernel_metadata`` names. The self times of one device
+sum to its busy union, so the table's total is the device's busy time;
+what no rule could place is reported as ``(unattributed)``, never
+dropped. The device's idle gaps are put down to the host annotation
+that covers each (the goodput spans open a
+``jax.profiler.TraceAnnotation``, so ``data_wait``, ``ckpt_save``,
+``snapshot`` are on the capture's clock).
+
 Everything emits ``kind="profile"`` records through the shared
 MetricRouter schema; ``python -m apex_tpu.monitor.xray.timeline`` is
 the standalone entry point.
@@ -40,15 +56,24 @@ a real TPU capture; only the interpretation of absolute numbers changes
 (docs/observability.md#timeline).
 """
 
+import collections
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from apex_tpu.analysis.hlo.parser import COLLECTIVE_KINDS
+from apex_tpu.monitor.goodput.scopes import KERNELS
+from apex_tpu.monitor.goodput.spans import PHASES
 from apex_tpu.monitor.xray.timeline.parser import (
     StepSpan,
     Timeline,
     TraceEvent,
     parse_logdir,
+)
+from apex_tpu.monitor.xray.timeline.hlo_scopes import (
+    UNATTRIBUTED,
+    OpScope,
+    classify_path,
+    scope_map,
 )
 
 __all__ = [
@@ -66,6 +91,9 @@ __all__ = [
     "OpInterval",
     "StepBreakdown",
     "AxisBandwidth",
+    "ScopeBreakdown",
+    "self_times",
+    "attribute_scopes",
     "TimelineReport",
     "analyze",
     "analyze_logdir",
@@ -326,6 +354,202 @@ class AxisBandwidth:
         return a / self.roofline_bytes_per_s
 
 
+#: the label of idle time no chosen host annotation covers
+NO_ANNOTATION = "(no annotation)"
+#: a ``tpu_custom_call`` whose ``kernel_metadata`` names no registered
+#: kernel (``goodput.scopes.KERNELS``)
+UNREGISTERED_KERNEL = "(unregistered)"
+
+
+@dataclasses.dataclass
+class ScopeBreakdown:
+    """Device self time by the program's names (microseconds, averaged
+    over the devices of the capture; totals over the whole capture —
+    ``per_step`` divides by :attr:`n_steps`).
+
+    Identity (test-pinned): on a TPU, whose ops run on ONE lane a
+    device, the values of :attr:`by_part` sum to :attr:`busy_us`, the
+    busy union of that lane. A CPU capture runs its "device" ops on
+    several threads at once, so there the self times sum to more than
+    the union; shares are of :attr:`self_us` either way.
+    """
+
+    n_steps: int
+    n_devices: int
+    busy_us: float
+    window_us: float
+    #: forward / backward / grad_sync / unscale / optimizer / guard /
+    #: ``(unattributed)`` -> self time
+    by_part: Dict[str, float]
+    #: registered kernel (or UNREGISTERED_KERNEL) -> self time, calls
+    by_kernel: Dict[str, float]
+    kernel_calls: Dict[str, int]
+    #: (part, module path with the layer index collapsed) -> self time
+    by_module: Dict[Tuple[str, str], float]
+    #: (part, op name without its ordinal) -> self time
+    by_op: Dict[Tuple[str, str], float]
+    #: how each op was placed (scope_map's own/fused/caller/flow/none;
+    #: ``event`` = by the event's own ``tf_op``, no instruction matched)
+    by_how: Dict[str, float]
+    #: fusions whose fused instructions span several phases (booked to
+    #: the one most of them belong to; their time cannot be split):
+    #: (op name without ordinal, "phase n + phase n ...") -> self time
+    spanning: Dict[Tuple[str, str], float]
+    #: idle time of the first device by the host annotation covering it
+    idle_by_annotation: Dict[str, float]
+
+    @property
+    def self_us(self) -> float:
+        return sum(self.by_part.values())
+
+    @property
+    def attributed_fraction(self) -> float:
+        """Share of device self time booked to a registered phase."""
+        if self.self_us <= 0:
+            return 0.0
+        return 1.0 - self.by_part.get(UNATTRIBUTED, 0.0) / self.self_us
+
+    def per_step(self, us: float) -> float:
+        return us / max(self.n_steps, 1)
+
+
+def self_times(
+    events: Sequence[TraceEvent], slack_us: float = 2e-3,
+) -> List[Tuple[TraceEvent, float]]:
+    """``(event, self time)`` for the events of ONE lane: each event's
+    duration less the part covered by events nested in it. A ``cond`` and
+    the ops of its body nest on a TPU's op lane; booking durations would
+    count the body twice. Times are rounded by the exporter, so an event
+    counts as nested only if it also ENDS inside its parent, give or
+    take ``slack_us``; neighbours that overlap by a rounding error are
+    siblings."""
+    out: List[List] = []
+    stack: List[List] = []  # [event, end, index into out]
+    for e in sorted(events, key=lambda e: (e.ts, -e.dur)):
+        while stack and (stack[-1][1] <= e.ts + slack_us
+                         or e.end > stack[-1][1] + slack_us):
+            stack.pop()
+        if stack:
+            out[stack[-1][2]][1] -= min(e.end, stack[-1][1]) - e.ts
+        stack.append([e, e.end, len(out)])
+        out.append([e, e.dur])
+    return [(e, max(t, 0.0)) for e, t in out]
+
+
+def _scope_of_event(e: TraceEvent) -> Optional[OpScope]:
+    """The scope an op event names itself: a TPU event carries its
+    instruction's ``op_name`` path as ``args["tf_op"]`` (with a trailing
+    ``:``) where XLA kept one. The fallback for an event no instruction
+    of the compiled module matches, and the whole join when no module
+    was given."""
+    path = str(e.args.get("tf_op") or "").rstrip(":")
+    phase, direction, module = classify_path(path)
+    if phase == UNATTRIBUTED:
+        return None
+    return OpScope(phase=phase, direction=direction, module=module,
+                   kernel=None, op_name=path, how="event")
+
+
+def _kernel_of_event(e: TraceEvent, sc: Optional[OpScope]) -> Optional[str]:
+    """The Pallas kernel an op event ran: by the compiled module's
+    ``kernel_metadata``, else by the event's own text (a TPU event prints
+    it); a Mosaic call that names no registered kernel is
+    UNREGISTERED_KERNEL, anything else None."""
+    if sc is not None and sc.kernel is not None:
+        return sc.kernel
+    named = (e.args.get("kernel_metadata") or {}).get("kernel")
+    if named in KERNELS:
+        return named
+    if e.args.get("custom_call_target") == "tpu_custom_call":
+        return UNREGISTERED_KERNEL
+    return None
+
+
+def attribute_scopes(
+    timeline: Timeline,
+    scopes: Dict[str, OpScope],
+    n_steps: int,
+    annotations: Sequence[str] = PHASES,
+) -> Optional[ScopeBreakdown]:
+    """Join the capture's device op events to ``scopes`` (a
+    :func:`scope_map`) by instruction name. None when the capture holds
+    no device op."""
+    ops = timeline.device_op_events()
+    if not ops:
+        return None
+    lanes: Dict[Tuple[int, int], List[TraceEvent]] = collections.defaultdict(
+        list)
+    for e in ops:
+        lanes[(e.pid, e.tid)].append(e)
+    devices = sorted({pid for pid, _ in lanes})
+    n_dev = len(devices)
+
+    by_part: Dict[str, float] = collections.Counter()
+    by_kernel: Dict[str, float] = collections.Counter()
+    kernel_calls: Dict[str, int] = collections.Counter()
+    by_module: Dict[Tuple[str, str], float] = collections.Counter()
+    by_op: Dict[Tuple[str, str], float] = collections.Counter()
+    by_how: Dict[str, float] = collections.Counter()
+    spanning: Dict[Tuple[str, str], float] = collections.Counter()
+    for lane in lanes.values():
+        for e, t in self_times(lane):
+            name = e.name.lstrip("%")
+            sc = scopes.get(name) or _scope_of_event(e)
+            part = sc.part if sc is not None else UNATTRIBUTED
+            by_part[part] += t / n_dev
+            by_op[(part, op_base(name))] += t / n_dev
+            by_how[sc.how if sc is not None else "no instruction"] += (
+                t / n_dev)
+            if sc is not None and sc.phase != UNATTRIBUTED:
+                by_module[(part, sc.module)] += t / n_dev
+                if sc.mix:
+                    spanning[(op_base(name), " + ".join(
+                        f"{p} {n}" for p, n in sc.mix))] += t / n_dev
+            kernel = _kernel_of_event(e, sc)
+            if kernel is not None:
+                by_kernel[kernel] += t / n_dev
+                kernel_calls[kernel] += 1
+
+    busy = {
+        pid: merge_intervals([
+            (e.ts, e.end) for (p, _), lane in lanes.items() if p == pid
+            for e in lane
+        ]) for pid in devices
+    }
+    wanted = set(annotations)
+    # an op event is never an annotation, wherever it ran: a CPU capture
+    # runs some ops on the python thread, beside its spans
+    is_op = {id(e) for e in ops}
+    host = [e for e in timeline.events
+            if id(e) not in is_op
+            and (e.name in wanted or e.step_num is not None)]
+    lo = min([e.ts for e in ops] + [h.ts for h in host])
+    hi = max([e.end for e in ops] + [h.end for h in host])
+    idle: Dict[str, float] = collections.Counter()
+    for gs, ge in subtract_intervals([(lo, hi)], busy[devices[0]]):
+        best, best_ov = NO_ANNOTATION, 0.0
+        for h in host:
+            ov = min(ge, h.end) - max(gs, h.ts)
+            if ov > best_ov:
+                best = h.name if h.step_num is None else "step"
+                best_ov = ov
+        idle[best] += ge - gs
+    return ScopeBreakdown(
+        n_steps=n_steps,
+        n_devices=n_dev,
+        busy_us=sum(total_us(b) for b in busy.values()) / n_dev,
+        window_us=hi - lo,
+        by_part=dict(by_part),
+        by_kernel=dict(by_kernel),
+        kernel_calls=dict(kernel_calls),
+        by_module=dict(by_module),
+        by_op=dict(by_op),
+        by_how=dict(by_how),
+        spanning=dict(spanning),
+        idle_by_annotation=dict(idle),
+    )
+
+
 @dataclasses.dataclass
 class TimelineReport:
     """The analyzer's full output: per-step partitions + the per-axis
@@ -353,6 +577,7 @@ class TimelineReport:
     synthetic_step: bool = False  # no markers: whole capture = one span
     predicted_bubble_fraction: Optional[float] = None
     schedule: Optional[str] = None  # algebra schedule name, when joined
+    scopes: Optional[ScopeBreakdown] = None  # the join to the compiled step
 
     def to_records(self) -> List[dict]:
         """``kind="profile"`` records in the shared MetricRouter schema:
@@ -398,6 +623,21 @@ class TimelineReport:
                 roofline_bytes_per_s=ax.roofline_bytes_per_s,
                 utilization=ax.utilization,
             ))
+        sc = self.scopes
+        if sc is not None:
+            # one record per phase and per kernel, milliseconds a step
+            for part, us in sorted(sc.by_part.items()):
+                records.append(make_record(
+                    "profile", last_step, part=part,
+                    self_ms_per_step=sc.per_step(us) / 1e3,
+                    self_fraction=us / sc.self_us if sc.self_us else None,
+                ))
+            for kernel, us in sorted(sc.by_kernel.items()):
+                records.append(make_record(
+                    "profile", last_step, kernel=kernel,
+                    self_ms_per_step=sc.per_step(us) / 1e3,
+                    calls=sc.kernel_calls[kernel],
+                ))
         return records
 
     def summary(self) -> str:
@@ -447,6 +687,8 @@ class TimelineReport:
                 f"  ({self.n_unattributed_collectives} collective event(s) "
                 f"matched no HLO instruction / axis — not joined)"
             )
+        if self.scopes is not None:
+            lines.extend(_scope_lines(self.scopes))
         if self.predicted_bubble_fraction is not None and self.steps:
             measured = sum(s.bubble_fraction for s in self.steps) / len(
                 self.steps
@@ -459,6 +701,70 @@ class TimelineReport:
                 f"{len(self.steps)} step(s)) — gap is scheduler shortfall"
             )
         return "\n".join(lines)
+
+
+def _scope_lines(sc: ScopeBreakdown, top: int = 12) -> List[str]:
+    """The phase x kernel x module table of :meth:`TimelineReport.summary`
+    (milliseconds a step; shares of the device's busy time)."""
+    def row(label: str, us: float, extra: str = "") -> str:
+        share = 100 * us / sc.self_us if sc.self_us else 0.0
+        return (f"    {label:<58s} {sc.per_step(us) / 1e3:9.3f} ms "
+                f"{share:5.1f}%{extra}")
+
+    def ranked(d: dict) -> list:
+        return sorted(d.items(), key=lambda kv: -kv[1])
+
+    lines = [
+        f"  device self time by the program's names: {sc.n_steps} step(s), "
+        f"{sc.n_devices} device(s), self time {sc.self_us / 1e6:.6f} s "
+        f"({sc.per_step(sc.self_us) / 1e3:.3f} ms a step), busy union "
+        f"{sc.busy_us / 1e6:.6f} s of a {sc.window_us / 1e6:.6f} s window, "
+        f"{100 * sc.attributed_fraction:.2f}% under a registered phase",
+        "   by phase (forward_backward split by JAX's transpose mark):",
+    ]
+    lines += [row(part, us) for part, us in ranked(sc.by_part)]
+    if sc.by_kernel:
+        lines.append("   by Pallas kernel (kernel_metadata):")
+        lines += [
+            row(k, us, f"  {sc.kernel_calls[k] / max(sc.n_steps, 1):g} "
+                       f"calls a step")
+            for k, us in ranked(sc.by_kernel)
+        ]
+    lines.append(f"   by module (top {top}):")
+    lines += [
+        row(f"{part}: {module or '-'}", us)
+        for (part, module), us in ranked(sc.by_module)[:top]
+    ]
+    lines.append(f"   by op (top {top}):")
+    lines += [
+        row(f"{part}: {op}", us) for (part, op), us in ranked(sc.by_op)[:top]
+    ]
+    if sc.spanning:
+        total = sum(sc.spanning.values())
+        lines.append(
+            f"   fusions that span phases (booked where most of their "
+            f"instructions were traced; one pass, not separable): "
+            f"{sc.per_step(total) / 1e3:.3f} ms a step")
+        lines += [
+            row(f"{op} [{mix}]", us)
+            for (op, mix), us in ranked(sc.spanning)[:top // 2]
+        ]
+    lines.append(
+        "   placed by: " + ", ".join(
+            f"{how} {100 * us / sc.self_us:.1f}%"
+            for how, us in ranked(sc.by_how)
+        )
+    )
+    idle = sum(sc.idle_by_annotation.values())
+    lines.append(
+        f"   idle {idle / 1e3:.3f} ms "
+        f"({100 * idle / sc.window_us if sc.window_us else 0:.2f}% of the "
+        f"window) by host annotation: " + ", ".join(
+            f"{name} {us / 1e3:.3f} ms"
+            for name, us in ranked(sc.idle_by_annotation)
+        )
+    )
+    return lines
 
 
 def _axis_of_collective(instr, mesh, partitions) -> str:
@@ -498,6 +804,7 @@ def analyze(
     ici_bandwidth: Optional[float] = None,
     predicted_bubble_fraction: Optional[float] = None,
     schedule: Optional[str] = None,
+    annotations: Sequence[str] = PHASES,
 ) -> TimelineReport:
     """Compute the full report from one parsed capture.
 
@@ -508,6 +815,13 @@ def analyze(
     utilization column — pass
     ``xray.ledger.ici_bandwidth_per_device()`` or a pinned number; the
     analyzer itself never guesses one.
+
+    ``module`` alone (no mesh: a bare capture plus the compiled step's
+    text) enables the scope join: device self time by step phase, Pallas
+    kernel and flax module (:class:`ScopeBreakdown`). ``annotations``
+    names the host ``TraceAnnotation`` spans the device's idle gaps are
+    put down to — the goodput phases by default, which
+    ``goodput.span`` opens on the profiler's clock.
 
     ``predicted_bubble_fraction`` / ``schedule`` attach the pipeline
     schedule algebra's prediction
@@ -610,6 +924,15 @@ def analyze(
                 roofline_bytes_per_s=ici_bandwidth,
             ))
 
+    scopes = None
+    has_text = module is not None and getattr(module, "text", "")
+    if has_text or any("tf_op" in e.args for e in ops):
+        scopes = attribute_scopes(
+            timeline, scope_map(module) if has_text else {},
+            n_steps=(timeline.program_runs() if synthetic else len(steps)),
+            annotations=annotations,
+        )
+
     return TimelineReport(
         steps=steps,
         axes=axes,
@@ -618,6 +941,7 @@ def analyze(
         synthetic_step=synthetic,
         predicted_bubble_fraction=predicted_bubble_fraction,
         schedule=schedule,
+        scopes=scopes,
     )
 
 
@@ -629,6 +953,7 @@ def analyze_logdir(
     ici_bandwidth: Optional[float] = None,
     predicted_bubble_fraction: Optional[float] = None,
     schedule: Optional[str] = None,
+    annotations: Sequence[str] = PHASES,
 ) -> TimelineReport:
     """Parse the newest capture under ``logdir`` and :func:`analyze` it
     (the ``--profile-analyze`` and CLI entry path)."""
@@ -637,7 +962,7 @@ def analyze_logdir(
         timeline, module=module, mesh=mesh, ledger=ledger,
         ici_bandwidth=ici_bandwidth,
         predicted_bubble_fraction=predicted_bubble_fraction,
-        schedule=schedule,
+        schedule=schedule, annotations=annotations,
     )
     report.files = files
     return report

@@ -31,7 +31,19 @@ exporter (and deliberately nothing more):
     backend; ``args["hlo_module"]`` names the module) or living on a
     ``/device:...`` process (TPU). Their names are HLO instruction
     names (``all-reduce.1``, ``fusion.42``) — joinable against a parsed
-    ``HloModule``'s collectives by exact instruction name.
+    ``HloModule``'s instructions by exact instruction name. A TPU
+    (v5e) event carries NO ``hlo_op`` argument. What it carries is the
+    instruction's whole HLO text (``%self_attention.117 = (bf16[...])
+    custom-call(...), custom_call_target="tpu_custom_call",
+    frontend_attributes={kernel_metadata={...}}``, no ``metadata=``):
+    as ``args["long_name"]`` in this export, beside the short name, the
+    ``op_name`` path as ``args["tf_op"]`` (where XLA kept one: a third
+    of the events, 7% of the time, have none) and ``hlo_category``; as
+    the event's NAME in the raw ``.xplane.pb``. The reader takes either:
+    the instruction's name is the event's name and ``args`` gains the
+    ``opcode``, the ``custom_call_target`` and the ``kernel_metadata``
+    the text held (parsed by ``analysis/hlo/parser.py``, the one home
+    of HLO text).
   - everything else (python frames, runtime bookkeeping like
     ``ThreadpoolListener::*``) — host noise the analyzer ignores.
 
@@ -45,7 +57,9 @@ import dataclasses
 import gzip
 import json
 import os
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+from apex_tpu.analysis.hlo.parser import parse_instruction
 
 __all__ = [
     "TraceEvent",
@@ -60,6 +74,11 @@ __all__ = [
 
 #: filename suffixes of the trace-event export (gzipped and plain)
 TRACE_SUFFIXES = (".trace.json.gz", ".trace.json")
+
+#: a TPU device process's lanes: one event per executed HLO op, and one
+#: per program execution
+OPS_LANE = "XLA Ops"
+MODULES_LANE = "XLA Modules"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,8 +160,9 @@ class Timeline:
            exporter; exact and lane-agnostic);
         2. if none exist but some process is named ``/device:...``
            (TPU), every complete event on those processes whose thread
-           is an op lane (``XLA Ops``) — or all device-process events
-           when no lane carries that label.
+           is THE op lane (``XLA Ops``; not ``Async XLA Ops``, whose
+           copies overlap the ops and occupy no core) — or all
+           device-process events when no lane carries that label.
 
         A device event that is ALSO a step marker is never an op.
         """
@@ -164,9 +184,28 @@ class Timeline:
         ]
         op_lanes = [
             e for e in on_device
-            if "XLA Ops" in self.thread_names.get((e.pid, e.tid), "")
+            if self.thread_names.get((e.pid, e.tid), "").strip() == OPS_LANE
         ]
         return op_lanes or on_device
+
+    def program_runs(self) -> int:
+        """How many times the capture's busiest program ran on one
+        device: the events of the TPU's ``XLA Modules`` lane (one per
+        program execution), counted for the name with the most device
+        time, on the first device. A capture without step markers is
+        segmented by it; 1 where no such lane exists (a CPU capture)."""
+        runs: Dict[Tuple[int, str], List[float]] = {}
+        for e in self.events:
+            if (self.thread_names.get((e.pid, e.tid), "").strip()
+                    == MODULES_LANE):
+                runs.setdefault((e.pid, e.name), []).append(e.dur)
+        if not runs:
+            return 1
+        first = min(pid for pid, _ in runs)
+        return max(
+            (sum(durs), len(durs)) for (pid, _), durs in runs.items()
+            if pid == first
+        )[1]
 
     def merged(self, other: "Timeline") -> "Timeline":
         """This capture plus ``other`` (a second host's file of the same
@@ -204,6 +243,27 @@ def load_trace_json(path: str) -> dict:
         return json.load(f)
 
 
+def _split_hlo_text(raw: str, seen: Dict[str, Tuple[str, dict]]):
+    """``(instruction name, parsed args)`` of an instruction's whole HLO
+    text (a TPU op event's ``long_name``, or its raw name);
+    ``(raw, {})`` for any other string. A step repeats its instructions,
+    so texts are parsed once (``seen``)."""
+    if not raw.startswith("%") or " = " not in raw:
+        return raw, {}
+    if raw not in seen:
+        ins = parse_instruction(raw)
+        if ins is None:
+            seen[raw] = (raw, {})
+        else:
+            extra = {"opcode": ins.opcode}
+            if ins.custom_call_target:
+                extra["custom_call_target"] = ins.custom_call_target
+            if ins.kernel_metadata:
+                extra["kernel_metadata"] = dict(ins.kernel_metadata)
+            seen[raw] = (ins.name, extra)
+    return seen[raw]
+
+
 def parse_trace(data: dict) -> Timeline:
     """Structure one loaded trace dict (tests inject synthetic dicts
     here — the same seam as ``parse_hlo_module`` taking text)."""
@@ -217,6 +277,7 @@ def parse_trace(data: dict) -> Timeline:
     events: List[TraceEvent] = []
     process_names: Dict[int, str] = {}
     thread_names: Dict[Tuple[int, int], str] = {}
+    hlo_texts: Dict[str, Tuple[str, dict]] = {}
     for e in raw:
         if not isinstance(e, dict):
             continue
@@ -230,13 +291,17 @@ def parse_trace(data: dict) -> Timeline:
                     (int(e.get("pid", 0)), int(e.get("tid", 0)))
                 ] = str(args["name"])
         elif ph == "X" and "ts" in e:
+            args = e.get("args") or {}
+            name, parsed = _split_hlo_text(str(e.get("name", "")), hlo_texts)
+            if not parsed and isinstance(args.get("long_name"), str):
+                parsed = _split_hlo_text(args["long_name"], hlo_texts)[1]
             events.append(TraceEvent(
-                name=str(e.get("name", "")),
+                name=name,
                 pid=int(e.get("pid", 0)),
                 tid=int(e.get("tid", 0)),
                 ts=float(e["ts"]),
                 dur=float(e.get("dur", 0.0)),
-                args=e.get("args") or {},
+                args={**args, **parsed} if parsed else args,
             ))
     return Timeline(
         events=events,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.goodput.scopes import kernel_metadata
 from apex_tpu.ops._dispatch import resolve_impl
 
 _NEG_INF = -1e30
@@ -232,6 +233,7 @@ def _flash_fwd(q3, kv3, kpm, heads, group, scale, causal, interpret, bq, bk, win
             pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
         ),
         interpret=interpret,
+        metadata=kernel_metadata("flash_fwd"),
     )(*inputs)
     return o, lse.reshape(bh, sq)
 
@@ -382,6 +384,7 @@ def _flash_bwd(heads, group, scale, causal, interpret, bq, bk, window, res, do):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        metadata=kernel_metadata("flash_bwd_dq"),
     )(*inputs)
 
     in_specs_kv = [
@@ -414,6 +417,7 @@ def _flash_bwd(heads, group, scale, causal, interpret, bq, bk, window, res, do):
             pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
         ),
         interpret=interpret,
+        metadata=kernel_metadata("flash_bwd_dkv"),
     )(*inputs)
     if group > 1:
         # q-head row r = b*heads + kv*group + j  ->  sum over j

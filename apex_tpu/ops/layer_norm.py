@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.goodput.scopes import kernel_metadata
 from apex_tpu.ops._dispatch import resolve_impl
 
 
@@ -165,6 +166,8 @@ def _ln_pallas_fwd(x2d, w, b, eps, interpret):
         ],
         out_specs=pl.BlockSpec((br, hidden), lambda i: (i, 0)),
         interpret=interpret,
+        name="ln_fwd",
+        metadata=kernel_metadata("ln_fwd"),
     )(xp, w.reshape(1, -1), b.reshape(1, -1))
     return y[:rows], (x2d, w, b)
 
@@ -195,6 +198,8 @@ def _ln_pallas_bwd(eps, interpret, res, dy):
             pl.BlockSpec((1, hidden), lambda i: (0, 0)),
         ),
         interpret=interpret,
+        name="ln_bwd",
+        metadata=kernel_metadata("ln_bwd"),
     )(xp, w.reshape(1, -1), dyp)
     dg = dgp.reshape(-1).astype(w.dtype)
     db = dbp.reshape(-1).astype(b.dtype)
@@ -225,6 +230,8 @@ def _rms_pallas_fwd(x2d, w, eps, interpret):
         ],
         out_specs=pl.BlockSpec((br, hidden), lambda i: (i, 0)),
         interpret=interpret,
+        name="rms_fwd",
+        metadata=kernel_metadata("rms_fwd"),
     )(xp, w.reshape(1, -1))
     return y[:rows], (x2d, w)
 
@@ -253,6 +260,8 @@ def _rms_pallas_bwd(eps, interpret, res, dy):
             pl.BlockSpec((1, hidden), lambda i: (0, 0)),
         ),
         interpret=interpret,
+        name="rms_bwd",
+        metadata=kernel_metadata("rms_bwd"),
     )(xp, w.reshape(1, -1), dyp)
     dg = dgp.reshape(-1).astype(w.dtype)
     return dx[:rows], dg

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.goodput.scopes import kernel_metadata
 from apex_tpu.ops._dispatch import resolve_impl
 from apex_tpu.ops.multi_tensor import CHUNK_SIZE
 
@@ -114,6 +115,8 @@ def adam_flat(
         ],
         out_specs=(chunk_spec, chunk_spec, chunk_spec),
         interpret=interpret,
+        name="adam_flat",
+        metadata=kernel_metadata("adam_flat"),
     )(sc, view(g_flat), view(p_flat), view(m_flat), view(v_flat))
     return upd.reshape(n), m.reshape(n), v.reshape(n)
 
@@ -163,6 +166,8 @@ def sumsq_flat(x_flat, impl: str = "auto"):
             (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
         ),
         interpret=interpret,
+        name="sumsq_flat",
+        metadata=kernel_metadata("sumsq_flat"),
     )(xf.reshape(rows, _LANES))
     return sq[0, 0]
 

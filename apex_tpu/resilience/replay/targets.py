@@ -238,6 +238,7 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
     from apex_tpu.amp import GradScaler
     from apex_tpu.compat import shard_map
     from apex_tpu.models import GPTModel, gpt_loss_fn
+    from apex_tpu.monitor.goodput.scopes import step_phase
     from apex_tpu.optimizers import fused_adam
     from apex_tpu.parallel import parallel_state
     from apex_tpu.parallel.ddp import all_reduce_gradients
@@ -362,12 +363,16 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
     )
     def train_step(params, opt_state, scaler_state, sent_state, bag, tokens,
                    labels, inject_nan, lr_scale):
+        # every op of the step is traced under one registered phase
+        # (goodput.scopes.STEP_PHASES): a profiler capture then says whose
+        # each device op is (monitor/xray/timeline scope_map)
         if ddp_compressed:
             # unpack the slot: adam state + this rank's EF residuals
             # (leading dp dim sliced off by shard_map's in_specs)
-            ef = jax.tree_util.tree_map(
-                lambda e: e[0], opt_state["ef_residual"]
-            )
+            with step_phase("grad_sync"):
+                ef = jax.tree_util.tree_map(
+                    lambda e: e[0], opt_state["ef_residual"]
+                )
             opt_state = opt_state["opt"]
 
         # tokens: (num_micro, micro*dp, seq) -> this dp shard's microbatches
@@ -408,7 +413,7 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
         # comms-ledger weighting: collectives inside the vmapped model
         # (fwd AND the custom_vjp bwds) trace with per-MICROBATCH avals
         # while the batched collective ships num_micro x the bytes
-        with monitor.xray.scaled(num_micro):
+        with monitor.xray.scaled(num_micro), step_phase("forward_backward"):
             (loss, layer_rms), grads = jax.value_and_grad(
                 scaled_total, has_aux=True
             )(params)
@@ -418,38 +423,46 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             # shard-local tap values would silently violate), then sqrt.
             # Size-1 axes elide to nothing; ledger-routed so the comms
             # prediction and the hlo differ both see the (tiny) traffic.
-            layer_rms = jnp.sqrt(
-                monitor.xray.ledger.pmean(
-                    monitor.xray.ledger.pmean(layer_rms, "tp"), "dp"
+            with step_phase("guard"):
+                layer_rms = jnp.sqrt(
+                    monitor.xray.ledger.pmean(
+                        monitor.xray.ledger.pmean(layer_rms, "tp"), "dp"
+                    )
                 )
-            )
         new_ef = None
         if not cfg.zero:
             # ZeRO's reduce-scatter inside opt.update replaces this
             # all-reduce (feeding it pre-averaged grads would double-count)
-            if ddp_compressed:
-                # error-compensated quantized all-reduce: grads travel
-                # int8 + scales; non-finite grads poison the scales and
-                # still reach found_inf below (the exact consensus path)
-                grads, new_ef = all_reduce_gradients(
-                    grads, axis_name="dp", compression=compress_cfg,
-                    ef_state=ef,
-                )
-            else:
-                grads = all_reduce_gradients(grads, axis_name="dp")
-        grads, found_inf = scaler.unscale(scaler_state, grads)
-        # the scaler's dynamic schedule reacts to true overflow only; the
-        # sentinel's spike gate must NOT halve the scale (a spike is not a
-        # precision problem)
-        new_scaler_state = scaler.update(scaler_state, found_inf)
+            with step_phase("grad_sync"):
+                if ddp_compressed:
+                    # error-compensated quantized all-reduce: grads travel
+                    # int8 + scales; non-finite grads poison the scales
+                    # and still reach found_inf below (the exact
+                    # consensus path)
+                    grads, new_ef = all_reduce_gradients(
+                        grads, axis_name="dp", compression=compress_cfg,
+                        ef_state=ef,
+                    )
+                else:
+                    grads = all_reduce_gradients(grads, axis_name="dp")
+        with step_phase("unscale"):
+            grads, found_inf = scaler.unscale(scaler_state, grads)
+            # the scaler's dynamic schedule reacts to true overflow only;
+            # the sentinel's spike gate must NOT halve the scale (a spike
+            # is not a precision problem)
+            new_scaler_state = scaler.update(scaler_state, found_inf)
 
-        # the loss is tp-replicated even under SP: model.apply gathers the
-        # sequence before the head and vocab_parallel_cross_entropy psums
-        # over tp internally — only the dp average is needed
-        unscaled = monitor.xray.ledger.pmean(loss / scaler_state.scale, "dp")
-        gate = jnp.logical_or(
-            found_inf, sentinel.is_anomalous_loss(sent_state, unscaled)
-        )
+        with step_phase("guard"):
+            # the loss is tp-replicated even under SP: model.apply gathers
+            # the sequence before the head and
+            # vocab_parallel_cross_entropy psums over tp internally — only
+            # the dp average is needed
+            unscaled = monitor.xray.ledger.pmean(
+                loss / scaler_state.scale, "dp"
+            )
+            gate = jnp.logical_or(
+                found_inf, sentinel.is_anomalous_loss(sent_state, unscaled)
+            )
 
         # the skip must gate the OPTIMIZER STATE too: opt.update on inf
         # grads would fold inf into the Adam moments permanently, nan-ing
@@ -461,45 +474,48 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             updates = jax.tree_util.tree_map(lambda u: u * lr_scale, updates)
             return optax.apply_updates(params, updates), new_opt
 
-        new_params, new_opt_state = vma_cond(
-            gate, lambda: (params, opt_state), apply
-        )
+        with step_phase("optimizer"):
+            new_params, new_opt_state = vma_cond(
+                gate, lambda: (params, opt_state), apply
+            )
         if ddp_compressed:
             # the residual updates even on gated steps (poisoned leaves
             # RESET inside ef_update, so a skipped step cannot freeze a
             # NaN residual); re-pack with the leading dp dim restored
-            new_opt_state = {
-                "opt": new_opt_state,
-                "ef_residual": jax.tree_util.tree_map(
-                    lambda e: e[None], new_ef
+            with step_phase("grad_sync"):
+                new_opt_state = {
+                    "opt": new_opt_state,
+                    "ef_residual": jax.tree_util.tree_map(
+                        lambda e: e[None], new_ef
+                    ),
+                }
+        with step_phase("guard"):
+            new_sent_state, verdict = sentinel.update(
+                sent_state, unscaled, anomaly=gate,
+                bad_params=tree_any_non_finite(new_params),
+            )
+            # metric taps: cheap scalars folded into the on-device bag; the
+            # z-score reuses the sentinel's pre-update EMA/var, so the record
+            # shows exactly the statistic the verdict was computed from
+            new_bag = bag.add(
+                loss=unscaled,
+                # tp-AWARE global norm: grads of tp-sharded weights are local
+                # shards inside shard_map, so the partial sums psum over tp
+                # (replicated params counted on rank 0 only); a plain
+                # global_grad_norm here would report one shard's norm
+                grad_norm=calc_params_l2_norm(
+                    grads, tp_duplicate_predicate=tp_duplicated, axis_name="tp"
                 ),
-            }
-        new_sent_state, verdict = sentinel.update(
-            sent_state, unscaled, anomaly=gate,
-            bad_params=tree_any_non_finite(new_params),
-        )
-        # metric taps: cheap scalars folded into the on-device bag; the
-        # z-score reuses the sentinel's pre-update EMA/var, so the record
-        # shows exactly the statistic the verdict was computed from
-        new_bag = bag.add(
-            loss=unscaled,
-            # tp-AWARE global norm: grads of tp-sharded weights are local
-            # shards inside shard_map, so the partial sums psum over tp
-            # (replicated params counted on rank 0 only); a plain
-            # global_grad_norm here would report one shard's norm
-            grad_norm=calc_params_l2_norm(
-                grads, tp_duplicate_predicate=tp_duplicated, axis_name="tp"
-            ),
-            loss_scale=new_scaler_state.scale,
-            loss_z=jnp.where(
-                sent_state.count > 0,  # cold-start var=0 makes z garbage
-                (unscaled - sent_state.ema)
-                * jax.lax.rsqrt(sent_state.var + 1e-12),
-                0.0,
-            ),
-            skipped=jnp.asarray(gate, jnp.float32),
-            anomalies=jnp.asarray(new_sent_state.anomalies, jnp.float32),
-        )
+                loss_scale=new_scaler_state.scale,
+                loss_z=jnp.where(
+                    sent_state.count > 0,  # cold-start var=0 makes z garbage
+                    (unscaled - sent_state.ema)
+                    * jax.lax.rsqrt(sent_state.var + 1e-12),
+                    0.0,
+                ),
+                skipped=jnp.asarray(gate, jnp.float32),
+                anomalies=jnp.asarray(new_sent_state.anomalies, jnp.float32),
+            )
         out = (new_params, new_opt_state, new_scaler_state, new_sent_state,
                new_bag, unscaled, verdict)
         if cfg.collect_layer_rms:

@@ -170,8 +170,11 @@ def parse_args(argv=None):
                    help="after the run, analyze the profiler capture(s) "
                         "taken: per-step compute/collective/exposed/idle "
                         "breakdown + achieved bytes/s per mesh axis vs the "
-                        "ledger prediction (apex_tpu.monitor.xray.timeline; "
-                        "kind='profile' records). Implies --profile-step 1 "
+                        "ledger prediction + device time by step phase, "
+                        "Pallas kernel and module "
+                        "(apex_tpu.monitor.xray.timeline; kind='profile' "
+                        "records); writes the compiled step's text beside "
+                        "the capture. Implies --profile-step 1 "
                         "when no capture was otherwise requested")
     p.add_argument("--step-deadline", type=float, default=None,
                    help="stall watchdog: flag a step exceeding this many "
@@ -1214,6 +1217,16 @@ def main(argv=None):
                       "(the run must continue window-steps past the capture "
                       "start)")
             for cap in trigger.captures:
+                if audit_module is not None and audit_module.text:
+                    # the compiled step beside its capture: an operator
+                    # holds both halves of the scope join (device time by
+                    # phase, kernel and module) and can re-read them with
+                    # python -m apex_tpu.monitor.xray.timeline <dir> --hlo
+                    hlo_path = os.path.join(cap["path"], "step.hlo.txt")
+                    with open(hlo_path, "w") as f:
+                        f.write(audit_module.text)
+                    print(f"profile analyze: compiled step written to "
+                          f"{hlo_path}")
                 try:
                     report = timeline.analyze_logdir(
                         cap["path"], module=audit_module, mesh=mesh,

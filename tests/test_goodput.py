@@ -162,7 +162,7 @@ class TestAccountant:
         assert rep.productive_s == 6.0
         assert rep.badput_s == {
             "ckpt_save": 0.5, "ckpt_restore": 2.0, "rollback": 0.0,
-            "compile": 3.0, "data_wait": 0.0, "stall": 0.0,
+            "snapshot": 0.0, "compile": 3.0, "data_wait": 0.0, "stall": 0.0,
             "incident": 0.0, "remediation": 0.0, "drain": 0.0,
             "handoff": 0.0, "failover": 0.0,
             "init": 2.0, "shutdown": 0.0,
