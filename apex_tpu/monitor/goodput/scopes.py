@@ -93,11 +93,13 @@ def step_phase(name: str):
     return jax.named_scope(name)
 
 
-def kernel_metadata(name: str) -> dict:
-    """The ``metadata=`` of a ``pl.pallas_call`` for a registered kernel."""
+def kernel_metadata(name: str, **tiles: int) -> dict:
+    """The ``metadata=`` of a ``pl.pallas_call`` for a registered kernel;
+    ``tiles`` (``block_q=512, block_k=512``) ride beside the name, so a
+    capture shows which tiles each call of a step ran."""
     if name not in KERNELS:
         raise ValueError(
             f"unknown kernel {name!r}; the registry is closed "
             f"(goodput.scopes.KERNELS): {KERNELS}"
         )
-    return {KERNEL_KEY: name}
+    return {KERNEL_KEY: name, **{k: str(v) for k, v in tiles.items()}}

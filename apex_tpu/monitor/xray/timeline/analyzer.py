@@ -74,6 +74,7 @@ from apex_tpu.monitor.xray.timeline.hlo_scopes import (
     OpScope,
     classify_path,
     scope_map,
+    tiles_of,
 )
 
 __all__ = [
@@ -384,6 +385,9 @@ class ScopeBreakdown:
     #: registered kernel (or UNREGISTERED_KERNEL) -> self time, calls
     by_kernel: Dict[str, float]
     kernel_calls: Dict[str, int]
+    #: kernel -> {tiles ("512x512", block_q x block_k): calls}, for the
+    #: kernels whose ``kernel_metadata`` carries their tiles
+    kernel_tiles: Dict[str, Dict[str, int]]
     #: (part, module path with the layer index collapsed) -> self time
     by_module: Dict[Tuple[str, str], float]
     #: (part, op name without its ordinal) -> self time
@@ -450,19 +454,20 @@ def _scope_of_event(e: TraceEvent) -> Optional[OpScope]:
                    kernel=None, op_name=path, how="event")
 
 
-def _kernel_of_event(e: TraceEvent, sc: Optional[OpScope]) -> Optional[str]:
-    """The Pallas kernel an op event ran: by the compiled module's
-    ``kernel_metadata``, else by the event's own text (a TPU event prints
-    it); a Mosaic call that names no registered kernel is
+def _kernel_of_event(
+        e: TraceEvent, sc: Optional[OpScope]) -> Tuple[Optional[str], str]:
+    """The Pallas kernel an op event ran and the tiles it ran with: by the
+    compiled module's ``kernel_metadata``, else by the event's own text (a
+    TPU event prints it); a Mosaic call that names no registered kernel is
     UNREGISTERED_KERNEL, anything else None."""
     if sc is not None and sc.kernel is not None:
-        return sc.kernel
-    named = (e.args.get("kernel_metadata") or {}).get("kernel")
-    if named in KERNELS:
-        return named
+        return sc.kernel, sc.tiles
+    meta = e.args.get("kernel_metadata") or {}
+    if meta.get("kernel") in KERNELS:
+        return meta["kernel"], tiles_of(meta)
     if e.args.get("custom_call_target") == "tpu_custom_call":
-        return UNREGISTERED_KERNEL
-    return None
+        return UNREGISTERED_KERNEL, ""
+    return None, ""
 
 
 def attribute_scopes(
@@ -487,6 +492,8 @@ def attribute_scopes(
     by_part: Dict[str, float] = collections.Counter()
     by_kernel: Dict[str, float] = collections.Counter()
     kernel_calls: Dict[str, int] = collections.Counter()
+    kernel_tiles: Dict[str, Dict[str, int]] = collections.defaultdict(
+        collections.Counter)
     by_module: Dict[Tuple[str, str], float] = collections.Counter()
     by_op: Dict[Tuple[str, str], float] = collections.Counter()
     by_how: Dict[str, float] = collections.Counter()
@@ -505,10 +512,12 @@ def attribute_scopes(
                 if sc.mix:
                     spanning[(op_base(name), " + ".join(
                         f"{p} {n}" for p, n in sc.mix))] += t / n_dev
-            kernel = _kernel_of_event(e, sc)
+            kernel, tiles = _kernel_of_event(e, sc)
             if kernel is not None:
                 by_kernel[kernel] += t / n_dev
                 kernel_calls[kernel] += 1
+                if tiles:
+                    kernel_tiles[kernel][tiles] += 1
 
     busy = {
         pid: merge_intervals([
@@ -542,6 +551,7 @@ def attribute_scopes(
         by_part=dict(by_part),
         by_kernel=dict(by_kernel),
         kernel_calls=dict(kernel_calls),
+        kernel_tiles={k: dict(v) for k, v in kernel_tiles.items()},
         by_module=dict(by_module),
         by_op=dict(by_op),
         by_how=dict(by_how),
@@ -637,6 +647,7 @@ class TimelineReport:
                     "profile", last_step, kernel=kernel,
                     self_ms_per_step=sc.per_step(us) / 1e3,
                     calls=sc.kernel_calls[kernel],
+                    tiles=sc.kernel_tiles.get(kernel) or None,
                 ))
         return records
 
@@ -724,10 +735,20 @@ def _scope_lines(sc: ScopeBreakdown, top: int = 12) -> List[str]:
     ]
     lines += [row(part, us) for part, us in ranked(sc.by_part)]
     if sc.by_kernel:
-        lines.append("   by Pallas kernel (kernel_metadata):")
+        def tiles(k: str) -> str:
+            ran = sc.kernel_tiles.get(k)
+            if not ran:
+                return ""
+            if len(ran) == 1:
+                return f"  tiles {next(iter(ran))}"
+            return "  tiles " + ", ".join(
+                f"{t} ({n / max(sc.n_steps, 1):g})" for t, n in ranked(ran))
+
+        lines.append("   by Pallas kernel (kernel_metadata; tiles = "
+                     "block_q x block_k):")
         lines += [
             row(k, us, f"  {sc.kernel_calls[k] / max(sc.n_steps, 1):g} "
-                       f"calls a step")
+                       f"calls a step{tiles(k)}")
             for k, us in ranked(sc.by_kernel)
         ]
     lines.append(f"   by module (top {top}):")

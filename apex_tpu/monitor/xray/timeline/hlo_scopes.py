@@ -65,6 +65,7 @@ __all__ = [
     "split_path",
     "classify_path",
     "kernel_of",
+    "tiles_of",
     "scope_map",
     "STRUCTURAL",
 ]
@@ -105,6 +106,8 @@ class OpScope:
     #: for a fusion whose fused instructions span several phases:
     #: ``((phase, instructions), ...)``, most first; else empty
     mix: Tuple[Tuple[str, int], ...] = ()
+    #: the tiles a Pallas kernel's call ran (:func:`tiles_of`); "" = none
+    tiles: str = ""
 
     @property
     def part(self) -> str:
@@ -171,6 +174,15 @@ def kernel_of(ins: HloInstruction) -> Optional[str]:
         return None
     name = dict(ins.kernel_metadata).get(KERNEL_KEY)
     return name if name in KERNELS else None
+
+
+def tiles_of(kernel_metadata) -> str:
+    """``"512x256"`` — ``block_q`` x ``block_k`` — from a kernel's
+    ``kernel_metadata`` (pairs or a dict: the flash kernels choose their
+    tiles per call, ``ops/attention.py:_flash_tiles``); "" where the
+    kernel names none."""
+    meta = dict(kernel_metadata)
+    return "x".join(meta[k] for k in ("block_q", "block_k") if k in meta)
 
 
 def _scope(ins: HloInstruction, op_name: str, how: str) -> Optional[OpScope]:
@@ -269,6 +281,10 @@ def scope_map(module_or_text) -> Dict[str, OpScope]:
                 phase=UNATTRIBUTED, direction=None, module="",
                 kernel=kernel_of(ins), op_name=ins.op_name, how="none",
             )
+    for ins in instructions:
+        if out[ins.name].kernel is not None:
+            out[ins.name] = dataclasses.replace(
+                out[ins.name], tiles=tiles_of(ins.kernel_metadata))
     return out
 
 

@@ -109,9 +109,10 @@ def server_programs(device):
 
 
 def attention_edge(device):
-    """Every flash-attention variant AT the longest sequence the dispatch
-    still sends to the Pallas kernels (``_KV_RESIDENT_BYTES``), and one
-    block past it, which must reach no kernel."""
+    """The benchmark cell's call with its derived tiles, then every
+    flash-attention variant AT the longest sequence the dispatch still
+    sends to the Pallas kernels (``_KV_RESIDENT_BYTES``), and one block
+    past it, which must reach no kernel."""
     from apex_tpu.ops import attention as A
 
     sharding = SingleDeviceSharding(device)
@@ -135,7 +136,13 @@ def attention_edge(device):
             sds((b, h, sq, d), dtype), k, k, sds((b, sk), jnp.bool_)
         ).compile()
 
-    ok = True
+    # the benchmark cell's call: far from the edge, so the tiles are the
+    # widest the rule derives (ops/attention.py:_flash_tiles)
+    ok = attempt(
+        "attention gpt2_345m.pretrain (8, 16, 1024, 64) bf16 causal fwd+bwd, "
+        f"tiles {A._flash_tiles(1024, 1024, None, A._kv_vmem_bytes(1024, 64, 2))}",
+        lambda: compile_attn(8, 16, 16, 1024, 1024, 64, jnp.bfloat16,
+                             True, True))
     for dtype in (jnp.bfloat16, jnp.float32):
         for d in (64, 128, 256):
             per_key = A._kv_vmem_bytes(1, d, jnp.dtype(dtype).itemsize)

@@ -113,6 +113,9 @@ class TestCrossLowering:
          dict(causal=True)),
         ("gpt2 bf16 causal", (4, 16, 16, 1024, 1024, 64, jnp.bfloat16),
          dict(causal=True)),
+        # the benchmark cell's call (global batch 8), tiles derived
+        ("gpt2 345m cell", (8, 16, 16, 1024, 1024, 64, jnp.bfloat16),
+         dict(causal=True)),
         ("gqa+window", (1, 8, 2, 4096, 4096, 128, jnp.bfloat16),
          dict(causal=True, window=1024)),
         ("long causal", (1, 2, 2, 8192, 8192, 128, jnp.bfloat16),

@@ -544,6 +544,92 @@ class TestFlashAttention:
         for a, b in zip(gp, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
+    @pytest.mark.parametrize("name,sq,sk,d,dtype,window", [
+        ("gpt2 345m cell", 1024, 1024, 64, jnp.bfloat16, None),
+        ("decode", 1, 1024, 64, jnp.bfloat16, None),
+        ("f32 256", 256, 256, 64, jnp.float32, None),
+        ("bert 512 key padding", 512, 512, 64, jnp.bfloat16, None),
+        ("gqa+window 4096x128", 4096, 4096, 128, jnp.bfloat16, 1024),
+        ("narrow window", 4096, 4096, 128, jnp.bfloat16, 200),
+        ("cross sq>sk", 2048, 384, 64, jnp.bfloat16, None),
+        ("no tile divides", 200, 200, 64, jnp.float32, None),
+        ("edge bf16 d=64", 14336, 14336, 64, jnp.bfloat16, None),
+        ("edge bf16 d=128", 14336, 14336, 128, jnp.bfloat16, None),
+        ("edge f32 d=64", 7168, 7168, 64, jnp.float32, None),
+        ("edge f32 d=128", 7168, 7168, 128, jnp.float32, None),
+    ])
+    def test_tile_rule(self, name, sq, sk, d, dtype, window):
+        """``_flash_tiles``: every tile divides its sequence, and no call
+        the 128 x 128 kernels took goes to XLA for want of a tile; short
+        sequences and the K/V residency edge keep the 128 x 128 of before,
+        the shapes with room get wider steps."""
+        from apex_tpu.ops import attention as A
+
+        resident = A._kv_vmem_bytes(max(sq, sk), d, jnp.dtype(dtype).itemsize)
+        tiles = A._flash_tiles(sq, sk, window, resident)
+        before = (min(128, sq), min(128, sk))
+        if sq % before[0] or sk % before[1]:
+            assert tiles is None
+            return
+        bq, bk = tiles
+        assert sq % bq == 0 and sk % bk == 0
+        assert bq >= before[0] and bk >= before[1]
+        if min(sq, sk) < 128 or name.startswith("edge"):
+            assert tiles == before
+        if name == "gpt2 345m cell":
+            assert min(tiles) >= 512
+        if window is not None:
+            assert max(tiles) <= max(128, window // 2)
+        # a forced tile is taken as given
+        assert A._flash_tiles(sq, sk, window, resident, 128, 128) == before
+
+    @pytest.mark.parametrize("bq,bk,sub", [
+        (64, 32, 16), (32, 64, 16), (256, 128, 128), (128, 256, 128)])
+    @pytest.mark.parametrize("case", [
+        "causal", "causal+window", "key padding", "gqa causal"])
+    def test_unequal_tiles_and_subtiles_match_reference(
+            self, rng, monkeypatch, case, bq, bk, sub):
+        """The kernels' loops split their range into fully visible blocks
+        (no mask code) and blocks that cross the diagonal, the window's
+        edge or carry padding; a tile is several sub-tiles; the dk/dv
+        kernel works on transposed scores. Forward and gradients against
+        ``_attn_ref`` with bq > bk and bq < bk (each order puts the
+        statically unrolled diagonal in other kernels), two tiles a
+        sequence so every loop has a fully visible block, and a fully
+        padded batch row."""
+        from apex_tpu.ops import attention as A
+
+        monkeypatch.setattr(A, "_SUBTILE", sub)
+        s = 2 * max(bq, bk)
+        h_kv = 1 if case == "gqa causal" else 2
+        k1, k2, k3, k4 = jax.random.split(rng, 4)
+        q = jax.random.normal(k1, (3, 2, s, 32))
+        k = jax.random.normal(k2, (3, h_kv, s, 32))
+        v = jax.random.normal(k3, (3, h_kv, s, 32))
+        ct = jax.random.normal(k4, (3, 2, s, 32))
+        kw = dict(causal=case != "key padding")
+        if case == "causal+window":
+            kw["window"] = s // 3 + 1
+        if case == "key padding":
+            kpm = np.zeros((3, s), bool)
+            kpm[0, s // 2 + 5:] = True
+            kpm[2, :] = True
+            kw["key_padding_mask"] = jnp.asarray(kpm)
+
+        def loss(impl):
+            return lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, impl=impl, block_q=bq, block_k=bk, **kw) * ct)
+
+        out = flash_attention(q, k, v, impl="pallas", block_q=bq, block_k=bk,
+                              **kw)
+        ref = flash_attention(q, k, v, impl="xla", **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+        gp = jax.grad(loss("pallas"), (0, 1, 2))(q, k, v)
+        gr = jax.grad(loss("xla"), (0, 1, 2))(q, k, v)
+        for a, b in zip(gp, gr):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
     def test_mask_path(self, rng):
         k1, k2, k3, k4 = jax.random.split(rng, 4)
         q = jax.random.normal(k1, (2, 2, 64, 32))

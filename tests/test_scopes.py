@@ -115,19 +115,24 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _kernels_lowered_for_tpu(f, *args):
-    """The ``kernel`` of every ``tpu_custom_call`` in ``f`` lowered for the
-    TPU platform (None where a call carries none)."""
+def _metadata_lowered_for_tpu(f, *args):
+    """The ``kernel_metadata`` (a dict) of every ``tpu_custom_call`` in
+    ``f`` lowered for the TPU platform ({} where a call carries none)."""
     text = jax.export.export(jax.jit(f), platforms=["tpu"])(
         *args).mlir_module()
     found = []
     for line in text.splitlines():
         if "@tpu_custom_call" in line:
-            m = re.search(
-                r'kernel_metadata = "\{\\0A\\22kernel\\22:\\22(\w+)\\22',
-                line)
-            found.append(m.group(1) if m else None)
+            m = re.search(r'kernel_metadata = "(\{[^"]*\})"', line)
+            found.append(dict(re.findall(
+                r'\\22(\w+)\\22:\\22(\w+)\\22', m.group(1))) if m else {})
     return found
+
+
+def _kernels_lowered_for_tpu(f, *args):
+    """The ``kernel`` of every ``tpu_custom_call`` in ``f`` lowered for the
+    TPU platform (None where a call carries none)."""
+    return [m.get("kernel") for m in _metadata_lowered_for_tpu(f, *args)]
 
 
 def _norm(op):
@@ -183,6 +188,18 @@ def test_every_tpu_custom_call_carries_a_registered_kernel(build, expected):
     found = _kernels_lowered_for_tpu(f, *args)
     assert found and all(k in scopes.KERNELS for k in found), found
     assert set(found) == expected
+
+
+@pytest.mark.usefixtures("as_tpu")
+def test_the_flash_kernels_carry_their_tiles():
+    """The tiles are chosen at trace time, so the compiled step is what
+    says which ones ran: beside each flash kernel's name."""
+    f, args = _flash()
+    for meta in _metadata_lowered_for_tpu(f, *args):
+        assert meta["kernel"].startswith("flash_")
+        assert (meta["block_q"], meta["block_k"]) == ("256", "256")
+    assert scopes.kernel_metadata("flash_fwd", block_q=512, block_k=128) == {
+        "kernel": "flash_fwd", "block_q": "512", "block_k": "128"}
 
 
 def test_the_lowerings_above_cover_the_registry():
@@ -389,7 +406,8 @@ def _tpu_capture(op_texts):
           '%bitcast.3), custom_call_target="tpu_custom_call", '
           'frontend_attributes={kernel_metadata={\n"kernel":"ln_fwd"\n}}')
     attn = attn_old.replace("kernel_metadata={}",
-                            'kernel_metadata={\n"kernel":"flash_bwd_dq"\n}')
+                            'kernel_metadata={\n"block_k":"512",\n'
+                            '"block_q":"1024",\n"kernel":"flash_bwd_dq"\n}')
     cond = ('%cond.882 = (f32[1024,1024]{1,0:T(8,128)}, f32[1024]{0:T(1024)})'
             ' conditional(pred[]{:T(512)} %gate.1, (f32[1024,1024], '
             'f32[1024]) %tuple.30, (f32[1024,1024], f32[1024]) %tuple.30), '
@@ -478,6 +496,8 @@ class TestTpuLayout:
         assert sc.by_kernel == pytest.approx(
             {"ln_fwd": 200.0, "flash_bwd_dq": 400.0})
         assert sc.kernel_calls == {"ln_fwd": 2, "flash_bwd_dq": 2}
+        # block_q x block_k as the compiled module's kernel_metadata has them
+        assert sc.kernel_tiles == {"flash_bwd_dq": {"1024x512": 2}}
         assert sc.by_module[
             ("backward", "transformer/layer_*/self_attention")
         ] == pytest.approx(400.0)
@@ -492,6 +512,7 @@ class TestTpuLayout:
             {"data_wait": 400.0, "snapshot": 500.0})
         text = rep.summary()
         assert "by Pallas kernel" in text and "flash_bwd_dq" in text
+        assert "1 calls a step  tiles 1024x512" in text
         kinds = [r for r in rep.to_records() if "part" in r or "kernel" in r]
         assert {r.get("part") for r in kinds} >= {"forward", "optimizer"}
 
@@ -538,6 +559,7 @@ class TestTpuLayout:
         assert bare.by_how["event"] == pytest.approx(600.0)
         assert bare.by_kernel == pytest.approx(
             {"ln_fwd": 200.0, "flash_bwd_dq": 400.0})
+        assert bare.kernel_tiles == {"flash_bwd_dq": {"1024x512": 2}}
         # a capture that says nothing of scopes gets no table
         assert timeline.analyze(whole).scopes is None
 
