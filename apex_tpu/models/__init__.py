@@ -1,7 +1,7 @@
 """Flagship models (ref: apex/transformer/testing/standalone_{gpt,bert}.py,
 examples/imagenet) re-built TPU-native on the apex_tpu transformer stack."""
 
-from apex_tpu.models.gpt import GPTModel, gpt_loss_fn
+from apex_tpu.models.gpt import GPTModel, gpt_loss_fn, gpt_mtp_loss_fn
 from apex_tpu.models.generate import generate
 from apex_tpu.models.hf_import import (
     gpt2_from_hf,
@@ -31,6 +31,7 @@ __all__ = [
     "params_to_hf_llama",
     "BertModel",
     "gpt_loss_fn",
+    "gpt_mtp_loss_fn",
     "ResNet",
     "ResNet18",
     "ResNet34",

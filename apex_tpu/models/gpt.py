@@ -29,7 +29,12 @@ from apex_tpu.parallel.mappings import (
 )
 from apex_tpu.transformer.config import TransformerConfig
 from apex_tpu.transformer.enums import AttnMaskType
-from apex_tpu.transformer.layer import ParallelTransformer, rotary_embedding_for
+from apex_tpu.monitor.goodput.scopes import model_scope
+from apex_tpu.transformer.layer import (
+    MultiTokenPrediction,
+    ParallelTransformer,
+    rotary_embedding_for,
+)
 
 
 class Embedding(nn.Module):
@@ -126,6 +131,13 @@ class GPTModel(nn.Module):
     when ``post_process`` and labels are given, returns per-token CE losses
     (ref: post_language_model_processing in standalone_gpt.py), else logits
     (vocab-sharded over tp) or, for intermediate stages, hidden states.
+
+    With ``config.mtp_num_layers`` (1 is what exists) and labels, returns
+    ``(losses, mtp_losses)``: the second from the multi-token-prediction
+    block, which reads the trunk's last hidden state and the embedding of
+    the NEXT token (``labels``) through the trunk's own embedding and head,
+    and predicts the token after it; its last position has no target and
+    reads 0 (``gpt_mtp_loss_fn`` takes the mean over the others).
     """
 
     config: TransformerConfig
@@ -155,13 +167,21 @@ class GPTModel(nn.Module):
                 output_dtype=jnp.float32,
                 name="output_layer",
             )
+        self.with_mtp = bool(cfg.mtp_num_layers) and self.post_process
+        if cfg.mtp_num_layers > 1 or (self.with_mtp and not self.pre_process):
+            raise NotImplementedError(
+                "one multi-token-prediction block, on a model that holds "
+                "its own embedding")
         self.transformer = ParallelTransformer(
             config=cfg,
             num_layers=self.num_layers,
             post_layer_norm=self.post_process,
             attn_mask_type=AttnMaskType.causal,
+            also_pre_norm=self.with_mtp,
             name="transformer",
         )
+        if self.with_mtp:
+            self.mtp = MultiTokenPrediction(config=cfg, name="mtp")
 
     def __call__(
         self,
@@ -219,7 +239,29 @@ class GPTModel(nn.Module):
         )
         if not self.post_process:
             return h
+        if self.with_mtp:
+            h, trunk_out = h
+        losses = self._head(h, labels, loss_mask, decode_step)
+        if not self.with_mtp or labels is None:
+            return losses
+        with model_scope("mtp"):
+            # position i: the trunk's state there and token i+1 (its label)
+            # predict token i+2 (the next position's label)
+            nxt = jnp.transpose(self.embedding.word_embeddings(labels),
+                                (1, 0, 2)).astype(cfg.compute_dtype)
+            h2 = self.mtp(trunk_out, nxt, rotary, key_padding_mask,
+                          deterministic)
+            targets = jnp.roll(labels, -1, axis=1)
+            has_target = jnp.arange(labels.shape[1]) < labels.shape[1] - 1
+            mask = has_target[None, :].astype(jnp.float32)
+            if loss_mask is not None:
+                mask = mask * jnp.roll(loss_mask, -1, axis=1)
+            return losses, self._head(h2, targets, mask, decode_step)
 
+    def _head(self, h, labels, loss_mask, decode_step):
+        """Final hidden states (s, b, h) -> logits, or per-token losses
+        when ``labels`` are given."""
+        cfg = self.config
         tied = cfg.share_embeddings_and_output_weights
         # decode steps carry a replicated single token — nothing is
         # sequence-sharded, so the SP head gather must not run
@@ -254,6 +296,16 @@ class GPTModel(nn.Module):
         if loss_mask is not None:
             losses = losses * loss_mask
         return losses
+
+
+def gpt_mtp_loss_fn(losses, mtp_losses, coeff: float):
+    """``mean(losses) + coeff * mean(mtp_losses over the positions that
+    have a target two ahead)`` (all but the last of each row; the model
+    hands that one back as 0). Returns (total, main, mtp)."""
+    main = jnp.mean(losses)
+    rows, seq = mtp_losses.shape
+    mtp = jnp.sum(mtp_losses) / (rows * (seq - 1))
+    return main + coeff * mtp, main, mtp
 
 
 def gpt_loss_fn(losses, loss_mask=None):

@@ -77,13 +77,36 @@ def _cfg_dims(cfg):
     return h, heads, kv_heads, head_dim, ffn
 
 
-def transformer_layer_flops_per_token(cfg, seq_len: int) -> float:
-    """Forward matmul FLOPs per token for ONE ParallelTransformerLayer.
+def _attention_flops_per_token(cfg, seq_len: int, kind: str) -> float:
+    """Projections + scores + context of one attention block, forward."""
+    h, heads, kv_heads, head_dim, _ = _cfg_dims(cfg)
+    if kind == "latent":
+        # low-rank q (down, up), joint kv down-projection with the shared
+        # rope key, kv up-projection, output; scores over nope + rope
+        # channels, context over v_head_dim
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        proj = (h * cfg.q_lora_rank + cfg.q_lora_rank * heads * qk
+                + h * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                + cfg.kv_lora_rank * heads * (cfg.qk_nope_head_dim
+                                              + cfg.v_head_dim)
+                + heads * cfg.v_head_dim * h)
+        return float(2 * proj
+                     + 2 * seq_len * heads * (qk + cfg.v_head_dim))
+    q = heads * head_dim
+    kv = kv_heads * head_dim
+    return float(2 * h * (q + 2 * kv) + 4 * seq_len * q + 2 * q * h)
+
+
+def transformer_layer_flops_per_token(cfg, seq_len: int,
+                                      layer: int = 0) -> float:
+    """Forward matmul FLOPs per token for ONE ParallelTransformerLayer,
+    of the kinds ``cfg.layer_kinds(layer)`` gives it.
 
     Counts (2*m*n*k per matmul, per token):
 
     - QKV projection: ``2*h*(q + 2*kv)`` where q = heads*head_dim and
-      kv = kv_heads*head_dim (GQA shrinks the K/V columns);
+      kv = kv_heads*head_dim (GQA shrinks the K/V columns); latent
+      attention: its five projections instead;
     - attention scores + context: ``2*s*q`` each — every query token
       multiplies against s keys and weights s values (causal masking
       halves the REACHABLE area, but the dense kernels here compute the
@@ -91,45 +114,56 @@ def transformer_layer_flops_per_token(cfg, seq_len: int) -> float:
     - output projection: ``2*q*h``;
     - dense MLP: ``2*h*ffn + 2*ffn*h``, plus ``2*h*ffn`` more for the
       extra gate matmul of geglu/swiglu;
-    - MoE MLP (``cfg.num_moe_experts`` set — the MLP block is MoEMLP):
-      router ``2*h*E`` plus ``moe_top_k`` expert-FFN passes of
-      ``2*h*ffn + 2*ffn*h`` each (MoEMLP experts are ungated two-matmul
-      FFNs). Each token mathematically runs top_k experts, so a top-2
-      MoE spends ~2x the dense MLP FLOPs — the dense formula both
-      under-counts top-2 and ignores the router, which is exactly how
-      MoE MFU went wrong before. Capacity-dropped tokens still count
-      (the convention counts the model's assignment math; drops are a
-      lossy implementation detail, and counting them would make MFU
-      improve when the router overflows).
+    - expert MLP: router ``2*h*E``; each of the ``moe_top_k`` experts a
+      token runs costs two matmuls of ``h x moe_ffn`` (three when
+      ``moe_gated_experts``), counted for the share of them that lands on
+      the experts held here (``moe_experts_held / E``; all of them when no
+      share is set); ``moe_shared_experts`` more run for every token.
+      Each token mathematically runs top_k experts, so a top-2 MoE spends
+      ~2x the dense MLP FLOPs. Capacity-dropped tokens still count (the
+      convention counts the model's assignment math; drops are a lossy
+      implementation detail, and counting them would make MFU improve when
+      the router overflows).
 
     Element-wise work (norms, softmax, residuals, gating combines) is
     O(h) per token and omitted, per the standard model-FLOPs convention.
     """
-    h, heads, kv_heads, head_dim, ffn = _cfg_dims(cfg)
-    q = heads * head_dim
-    kv = kv_heads * head_dim
-    qkv_proj = 2 * h * (q + 2 * kv)
-    attn = 2 * seq_len * q + 2 * seq_len * q
-    out_proj = 2 * q * h
-    num_experts = getattr(cfg, "num_moe_experts", None)
-    if num_experts:
+    h, _, _, _, ffn = _cfg_dims(cfg)
+    kinds = getattr(cfg, "layer_kinds", None)
+    attn_kind, mlp_kind = kinds(layer) if kinds else (
+        "mha", "experts" if getattr(cfg, "num_moe_experts", None)
+        else "dense")
+    attn = _attention_flops_per_token(cfg, seq_len, attn_kind)
+    if mlp_kind == "experts":
+        num_experts = cfg.num_moe_experts
         top_k = getattr(cfg, "moe_top_k", 1) or 1
-        router = 2 * h * num_experts
-        mlp = router + top_k * (2 * h * ffn + 2 * ffn * h)
+        e_ffn = getattr(cfg, "moe_ffn_hidden_size", None) or ffn
+        n_mats = 3 if getattr(cfg, "moe_gated_experts", False) else 2
+        held = getattr(cfg, "moe_experts_held", None)
+        share = 1.0 if held is None else held / num_experts
+        expert = n_mats * 2 * h * e_ffn
+        mlp = (2 * h * num_experts + top_k * share * expert
+               + getattr(cfg, "moe_shared_experts", 0) * expert)
     else:
         n_mats = 3 if cfg.activation in ("geglu", "swiglu") else 2
         mlp = n_mats * 2 * h * ffn
-    return float(qkv_proj + attn + out_proj + mlp)
+    return float(attn + mlp)
 
 
 def gpt_flops_per_token(cfg, seq_len: Optional[int] = None) -> float:
     """Forward FLOPs per token of the GPT testing model: the layer stack
-    plus the tied-embedding logit matmul ``2*h*vocab``. Embedding lookups
-    are gathers (0 matmul FLOPs)."""
+    plus the logit matmul ``2*h*vocab``. A multi-token-prediction block
+    adds one layer of the last kind, its ``2h x h`` joining projection and
+    the head a second time. Embedding lookups are gathers (0 matmul
+    FLOPs)."""
     s = seq_len if seq_len is not None else cfg.max_position_embeddings
-    layers = cfg.num_layers * transformer_layer_flops_per_token(cfg, s)
+    layers = sum(transformer_layer_flops_per_token(cfg, s, i)
+                 for i in range(cfg.num_layers))
     head = 2 * cfg.hidden_size * cfg.vocab_size
-    return float(layers + head)
+    mtp = getattr(cfg, "mtp_num_layers", 0) * (
+        transformer_layer_flops_per_token(cfg, s, cfg.num_layers)
+        + 2 * 2 * cfg.hidden_size * cfg.hidden_size + head)
+    return float(layers + head + mtp)
 
 
 def bert_flops_per_token(cfg, seq_len: Optional[int] = None) -> float:

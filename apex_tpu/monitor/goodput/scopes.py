@@ -33,9 +33,11 @@ this module imports jax only inside :func:`step_phase`.
 
 __all__ = [
     "STEP_PHASES",
+    "MODEL_SCOPES",
     "KERNELS",
     "KERNEL_KEY",
     "step_phase",
+    "model_scope",
     "kernel_metadata",
 ]
 
@@ -59,6 +61,30 @@ STEP_PHASES = (
     "unscale",
     "optimizer",
     "guard",
+)
+
+#: Parts of the MODEL inside ``forward_backward`` that no flax module path
+#: tells apart: the reader buckets an op under the innermost of these in
+#: its path (``monitor/xray/timeline`` ``scope_map``), beside its phase.
+#:
+#: - ``mla_project``  — latent attention's five projections, their two
+#:   norms and the rope (everything of the module but the flash kernels)
+#: - ``moe_route``    — the router's matmul, scores, top-k, gates
+#: - ``moe_dispatch`` — the sort of the assignments, the gather of their
+#:   rows and, over an expert axis, the exchange out
+#: - ``moe_experts``  — the grouped matmuls of the routed experts
+#: - ``moe_combine``  — rows back to their tokens, weighted by the gates
+#:   (and the exchange back)
+#: - ``moe_shared``   — the shared expert, computed for every token
+#: - ``mtp``          — the multi-token-prediction block and its loss
+MODEL_SCOPES = (
+    "mla_project",
+    "moe_route",
+    "moe_dispatch",
+    "moe_experts",
+    "moe_combine",
+    "moe_shared",
+    "mtp",
 )
 
 #: Every Pallas kernel of the tree: ops/attention.py (flash forward, dq,
@@ -87,6 +113,18 @@ def step_phase(name: str):
         raise ValueError(
             f"unknown step phase {name!r}; the registry is closed "
             f"(goodput.scopes.STEP_PHASES): {STEP_PHASES}"
+        )
+    import jax
+
+    return jax.named_scope(name)
+
+
+def model_scope(name: str):
+    """``jax.named_scope(name)`` for a registered part of the model."""
+    if name not in MODEL_SCOPES:
+        raise ValueError(
+            f"unknown model scope {name!r}; the registry is closed "
+            f"(goodput.scopes.MODEL_SCOPES): {MODEL_SCOPES}"
         )
     import jax
 

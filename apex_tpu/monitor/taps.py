@@ -29,6 +29,23 @@ REGISTERED_TAPS = {
         "vector, cross-rank-aggregated) so the divergence bisector can "
         "localize a corruption to the first divergent layer"
     ),
+    "moe_chosen": (
+        "transformer/moe.py MoEMLP: the experts each token chose, (tokens, "
+        "top_k) int32 over ALL the model's experts, held here or not — what "
+        "the benchmark's output check compares with the reference's choices "
+        "(perf/drivers/joyai_pretrain.py)"
+    ),
+    "moe_load": (
+        "transformer/moe.py MoEMLP: rows each HELD expert took this call, "
+        "(experts held,) int32. resilience/replay/targets.py folds the "
+        "layers' loads into the step's MetricBag (moe_rows_here, "
+        "moe_load_max, moe_load_mean, moe_load_max_over_mean)"
+    ),
+    "moe_dropped": (
+        "transformer/moe.py MoEMLP: assignments the capacity rule cut this "
+        "call (0 whenever moe_capacity_factor is None). The MetricBag's "
+        "moe_dropped; the benchmark holds it to 0"
+    ),
 }
 
 __all__ = ["REGISTERED_TAPS"]

@@ -248,6 +248,11 @@ class TransformerDims:
     vocab_size: int
     max_position_embeddings: int
     ffn_hidden_size: Optional[int] = None  # None -> 4*hidden_size
+    #: the exact parameter count of a model whose layers are not all
+    #: GPT-2's (latent attention, expert layers, a multi-token-prediction
+    #: block: ``described_param_elements``); None = ``gpt_param_elements``'
+    #: own arithmetic
+    param_elements: Optional[int] = None
 
     @property
     def ffn(self) -> int:
@@ -269,6 +274,11 @@ class TransformerDims:
             vocab_size=cfg.vocab_size,
             max_position_embeddings=cfg.max_position_embeddings,
             ffn_hidden_size=getattr(cfg, "ffn_hidden_size", None),
+            param_elements=(
+                described_param_elements(cfg)
+                if getattr(cfg, "mlp_layer_kinds", None)
+                or getattr(cfg, "attention_layer_kinds", None)
+                or getattr(cfg, "mtp_num_layers", 0) else None),
         )
 
 
@@ -276,6 +286,62 @@ def _exact_div(n: int, d: int, what: str) -> int:
     if n % d:
         raise ValueError(f"{what}: {n} is not divisible by {d}")
     return n // d
+
+
+def described_param_elements(cfg) -> int:
+    """Parameter ELEMENT count of ``models/gpt.py`` for a
+    ``TransformerConfig`` whose layers say what they are
+    (``cfg.layer_kinds``): latent or multi-head attention, dense MLP or
+    the experts HELD here with their router, its bias and the shared
+    experts, a multi-token-prediction block — the flax tree leaf for leaf,
+    one device, no tensor parallelism."""
+    h, heads = cfg.hidden_size, cfg.num_attention_heads
+    bias = 1 if cfg.add_bias_linear else 0
+    norm = h * (1 if cfg.normalization == "rmsnorm" else 2)
+    gated = lambda on: 2 if on else 1
+
+    def attention(kind):
+        if kind == "latent":
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            return (h * cfg.q_lora_rank + cfg.q_lora_rank
+                    + cfg.q_lora_rank * heads * qk
+                    + h * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                    + cfg.kv_lora_rank
+                    + cfg.kv_lora_rank * heads * (cfg.qk_nope_head_dim
+                                                  + cfg.v_head_dim)
+                    + heads * cfg.v_head_dim * h)
+        q = heads * cfg.kv_channels
+        kv = (cfg.num_query_groups or heads) * cfg.kv_channels
+        return h * (q + 2 * kv) + bias * (q + 2 * kv) + q * h + bias * h
+
+    def mlp(kind):
+        if kind == "dense":
+            wide = cfg.ffn_hidden_size * gated(
+                cfg.activation in ("geglu", "swiglu"))
+            return (h * wide + bias * wide + cfg.ffn_hidden_size * h
+                    + bias * h)
+        e = cfg.num_moe_experts
+        held = e if cfg.moe_experts_held is None else cfg.moe_experts_held
+        ffn = cfg.moe_ffn_hidden_size or cfg.ffn_hidden_size
+        expert = h * ffn * gated(cfg.moe_gated_experts) + ffn * h
+        return (h * e + (e if cfg.moe_router == "sigmoid" else 0)
+                + (held + cfg.moe_shared_experts) * expert)
+
+    def layer(i):
+        a, m = cfg.layer_kinds(i)
+        return 2 * norm + attention(a) + mlp(m)
+
+    total = cfg.vocab_size * h + norm
+    if cfg.position_embedding_type == "learned":
+        total += cfg.max_position_embeddings * h
+    if not cfg.share_embeddings_and_output_weights:
+        total += h * cfg.vocab_size
+    total += sum(layer(i) for i in range(cfg.num_layers))
+    # the MTP block: two norms, the joining projection, a layer of the
+    # last kind, a final norm of its own
+    total += cfg.mtp_num_layers * (
+        3 * norm + 2 * h * h + layer(cfg.num_layers))
+    return total
 
 
 def gpt_param_elements(dims: TransformerDims, tp: int = 1) -> int:
@@ -288,8 +354,13 @@ def gpt_param_elements(dims: TransformerDims, tp: int = 1) -> int:
     layernorms (scale+bias each), column-parallel QKV ``(h, 3h/tp)`` +
     bias ``3h/tp``, row-parallel attention output ``(h/tp, h)`` + full
     bias ``h``, column-parallel ``(h, ffn/tp)`` + bias ``ffn/tp``,
-    row-parallel ``(ffn/tp, h)`` + full bias ``h``.
+    row-parallel ``(ffn/tp, h)`` + full bias ``h``. A described model
+    (``dims.param_elements``) is counted by ``described_param_elements``.
     """
+    if dims.param_elements is not None:
+        if tp != 1:
+            raise ValueError("a described model's count is for tp = 1")
+        return dims.param_elements
     h = dims.hidden_size
     qkv = 3 * h
     tp_qkv = _exact_div(qkv, tp, "qkv out dim / tp")

@@ -56,6 +56,9 @@ def main(argv=None) -> int:
     p.add_argument("--annotation", action="append", default=[],
                    help="a host TraceAnnotation name to put idle gaps down "
                    "to, beside the goodput phases (repeatable)")
+    p.add_argument("--top", type=int, default=12,
+                   help="rows of the by-module and by-op tables (with "
+                        "--hlo); default 12")
     p.add_argument("--schedule", default=None, choices=_SCHEDULE_CHOICES,
                    help="pipeline schedule name for the predicted-bubble "
                    "join")
@@ -106,7 +109,7 @@ def main(argv=None) -> int:
         return 1
     for path in report.files:
         print(f"trace: {path}", flush=True)
-    print(report.summary(), flush=True)
+    print(report.summary(top=args.top), flush=True)
     if args.json:
         from apex_tpu.monitor.router import JsonlSink
 

@@ -651,7 +651,7 @@ class TimelineReport:
                 ))
         return records
 
-    def summary(self) -> str:
+    def summary(self, top: int = 12) -> str:
         """The human-readable breakdown (the ``--profile-analyze``
         printout and the CLI's output)."""
         if not self.steps:
@@ -699,7 +699,7 @@ class TimelineReport:
                 f"matched no HLO instruction / axis — not joined)"
             )
         if self.scopes is not None:
-            lines.extend(_scope_lines(self.scopes))
+            lines.extend(_scope_lines(self.scopes, top))
         if self.predicted_bubble_fraction is not None and self.steps:
             measured = sum(s.bubble_fraction for s in self.steps) / len(
                 self.steps

@@ -80,6 +80,7 @@ UNATTRIBUTED = "(unattributed)"
 #: — are recognised by the parenthesis)
 _JAX_FRAMES = frozenset({
     "cond", "while", "body", "scan", "checkpoint", "remat", "closed_call",
+    "rematted_computation",
     "core_call", "custom_jvp_call", "custom_vjp_call",
     "custom_vjp_call_jaxpr", "pjit", "shard_map", "pallas_call",
 })
@@ -177,12 +178,17 @@ def kernel_of(ins: HloInstruction) -> Optional[str]:
 
 
 def tiles_of(kernel_metadata) -> str:
-    """``"512x256"`` — ``block_q`` x ``block_k`` — from a kernel's
+    """``"512x256"`` — ``block_q`` x ``block_k``, then ``d192/128`` where
+    the kernel's q/k and v differ in width — from a kernel's
     ``kernel_metadata`` (pairs or a dict: the flash kernels choose their
     tiles per call, ``ops/attention.py:_flash_tiles``); "" where the
     kernel names none."""
     meta = dict(kernel_metadata)
-    return "x".join(meta[k] for k in ("block_q", "block_k") if k in meta)
+    tiles = "x".join(meta[k] for k in ("block_q", "block_k") if k in meta)
+    if tiles and meta.get("d_qk") != meta.get("d_v"):
+        # q and k wider than v (latent attention): worth seeing beside
+        tiles += f" d{meta.get('d_qk')}/{meta.get('d_v')}"
+    return tiles
 
 
 def _scope(ins: HloInstruction, op_name: str, how: str) -> Optional[OpScope]:

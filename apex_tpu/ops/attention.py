@@ -288,7 +288,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, sub,
                       has_kpm, window=None):
     kpm_ref = refs[0] if has_kpm else None  # (1, SK/BK, BK), 1 = padded
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]  # the output is as wide as v (d_v), q and k d_qk
     qi = pl.program_id(1)
     num_kv = k_ref.shape[1] // bk
 
@@ -373,12 +373,13 @@ def _kpm_blocks(kpm, bk):
 
 
 @functools.lru_cache(maxsize=128)
-def _flash_calls(bh, sq, sk, d, dtypes, heads, group, scale, causal, interpret,
-                 tile, subs, window, has_kpm):
+def _flash_calls(bh, sq, sk, d, dv, dtypes, heads, group, scale, causal,
+                 interpret, tile, subs, window, has_kpm):
     """The three ``pallas_call``s (forward, dq, dk/dv) of one static
-    configuration. Cached, because a model makes the same call once a
-    layer and JAX traces and lowers a callable it has seen once, not once
-    a layer: 24 layers x 3 kernels were 20 s of GPT-2 345M's set-up."""
+    configuration; q and k are ``d`` wide, v and the output ``dv`` (latent
+    attention: 192 / 128). Cached, because a model makes the same call
+    once a layer and JAX traces and lowers a callable it has seen once, not
+    once a layer: 24 layers x 3 kernels were 20 s of GPT-2 345M's set-up."""
     bq, bk = tile
     sub_q, sub_k = subs
     q_dtype, k_dtype, v_dtype = dtypes
@@ -387,62 +388,68 @@ def _flash_calls(bh, sq, sk, d, dtypes, heads, group, scale, causal, interpret,
                      has_kpm=has_kpm, window=window)
     kpm_spec = [_kpm_spec(heads, sk // bk, bk)] if has_kpm else []
     q_block = pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0))
+    o_block = pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0))
+    tiles = dict(block_q=bq, block_k=bk, d_qk=d, d_v=dv)
     # lse and delta carry a singleton middle dim so their block (1, 1, bq)
     # satisfies the TPU (8, 128) tiling rule on the last two dims
     row_block = pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))
-    full_k = _kv_spec(group, sk, d)
-    q_scratch = [
-        pltpu.VMEM((bq, d), jnp.float32),
-        pltpu.VMEM((bq, lw), jnp.float32),
-        pltpu.VMEM((bq, lw), jnp.float32),
-    ]
+    full_k, full_v = _kv_spec(group, sk, d), _kv_spec(group, sk, dv)
+    stats = [pltpu.VMEM((bq, lw), jnp.float32)] * 2
     fwd = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, sub=sub_q, **kernel_kw),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, d), q_dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q_dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ),
         grid=(bh, sq // bq),
-        in_specs=[q_block, full_k, full_k] + kpm_spec,
-        out_specs=(q_block, row_block),
-        scratch_shapes=q_scratch,
+        in_specs=[q_block, full_k, full_v] + kpm_spec,
+        out_specs=(o_block, row_block),
+        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32)] + stats,
         interpret=interpret,
-        metadata=kernel_metadata("flash_fwd", block_q=bq, block_k=bk),
+        metadata=kernel_metadata("flash_fwd", **tiles),
     )
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sub=sub_q, **kernel_kw),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q_dtype),
         grid=(bh, sq // bq),
         # q block; k, v resident; do block; lse, delta blocks
-        in_specs=[q_block, full_k, full_k, q_block, row_block, row_block]
+        in_specs=[q_block, full_k, full_v, o_block, row_block, row_block]
         + kpm_spec,
         out_specs=q_block,
-        scratch_shapes=q_scratch,
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] + stats,
         interpret=interpret,
-        metadata=kernel_metadata("flash_bwd_dq", block_q=bq, block_k=bk),
+        metadata=kernel_metadata("flash_bwd_dq", **tiles),
     )
-    full_q = pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0))
     row_q = pl.BlockSpec((1, 1, sq), lambda b, j: (b, 0, 0))
-    k_block = pl.BlockSpec((1, bk, d), lambda b, j, g=group: (b // g, j, 0))
-    out_block = pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0))
+
+    def resident_q(w):
+        return pl.BlockSpec((1, sq, w), lambda b, j: (b, 0, 0))
+
+    def kv_in(w):
+        return pl.BlockSpec((1, bk, w), lambda b, j, g=group: (b // g, j, 0))
+
+    def kv_out(w):
+        return pl.BlockSpec((1, bk, w), lambda b, j: (b, j, 0))
+
     # per-Q-HEAD partials: grid still runs over all bh q-head rows, so two
     # q heads sharing a kv head never race on one output block
     dkv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sub=sub_k, **kernel_kw),
         out_shape=(
             jax.ShapeDtypeStruct((bh, sk, d), k_dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v_dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), v_dtype),
         ),
         grid=(bh, sk // bk),
         # q resident; k, v blocks (grouped); do resident; lse, delta rows
-        in_specs=[full_q, k_block, k_block, full_q, row_q, row_q] + kpm_spec,
-        out_specs=(out_block, out_block),
+        in_specs=[resident_q(d), kv_in(d), kv_in(dv), resident_q(dv), row_q,
+                  row_q] + kpm_spec,
+        out_specs=(kv_out(d), kv_out(dv)),
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
-        metadata=kernel_metadata("flash_bwd_dkv", block_q=bq, block_k=bk),
+        metadata=kernel_metadata("flash_bwd_dkv", **tiles),
     )
     return fwd, dq, dkv
 
@@ -452,9 +459,9 @@ def _calls_for(q3, kv3, kpm, heads, group, scale, causal, interpret, tile,
     k3, v3 = kv3
     bh, sq, d = q3.shape
     return _flash_calls(
-        bh, sq, k3.shape[1], d, (q3.dtype, k3.dtype, v3.dtype), heads, group,
-        scale, causal, interpret, tile, tuple(_subtile(t) for t in tile),
-        window, kpm is not None)
+        bh, sq, k3.shape[1], d, v3.shape[2], (q3.dtype, k3.dtype, v3.dtype),
+        heads, group, scale, causal, interpret, tile,
+        tuple(_subtile(t) for t in tile), window, kpm is not None)
 
 
 def _flash_fwd(q3, kv3, kpm, heads, group, scale, causal, interpret, tile,
@@ -616,7 +623,7 @@ def _flash_bwd(heads, group, scale, causal, interpret, tile, window, res, do):
         # q-head row r = b*heads + kv*group + j  ->  sum over j
         bhkv = bh // group
         dk = dk_p.reshape(bhkv, group, sk, d).sum(axis=1).astype(k3.dtype)
-        dv = dv_p.reshape(bhkv, group, sk, d).sum(axis=1).astype(v3.dtype)
+        dv = dv_p.reshape(bhkv, group, sk, -1).sum(axis=1).astype(v3.dtype)
     else:
         dk, dv = dk_p, dv_p
     # kpm is an int mask: no cotangent (None == symbolic zero)
@@ -665,10 +672,12 @@ _TILE_ROOM = (
 )
 
 
-def _kv_vmem_bytes(seq: int, d: int, esize: int) -> int:
-    """VMEM footprint of one (batch, head)'s resident pair (K+V, or Q+dO):
-    the head dim is padded to the 128-lane tile."""
-    return 2 * seq * (-(-d // 128) * 128) * esize
+def _kv_vmem_bytes(seq: int, d: int, esize: int, d_v: int = None) -> int:
+    """VMEM footprint of one (batch, head)'s resident pair (K+V, or Q+dO:
+    one member ``d`` wide, the other ``d_v``, which defaults to ``d``):
+    each head dim is padded to the 128-lane tile."""
+    lanes = lambda w: -(-w // 128) * 128
+    return seq * (lanes(d) + lanes(d if d_v is None else d_v)) * esize
 
 
 def _flash_tiles(sq: int, sk: int, window, resident: int,
@@ -759,8 +768,8 @@ def _blockwise_fwd_res(q5, kv, kpm, scale, causal, window, cq, ck):
     chunks, inner fori over the kv chunks in the band — memory is one
     (cq, ck) score tile per (b, h) instead of (sq, sk)."""
     k, v = kv
-    b, h_kv, g, sq, d = q5.shape
-    sk = k.shape[2]
+    b, h_kv, g, sq, _ = q5.shape
+    sk, d = k.shape[2], v.shape[3]  # the output is as wide as v
     nq, nk = sq // cq, sk // ck
     offs = sk - sq
     has_kpm = kpm is not None
@@ -889,14 +898,14 @@ def _blockwise_bwd(scale, causal, window, cq, ck, res, do):
 
         init = (
             jnp.zeros((b, h_kv, ck, d), jnp.float32),
-            jnp.zeros((b, h_kv, ck, d), jnp.float32),
+            jnp.zeros((b, h_kv, ck, v.shape[3]), jnp.float32),
         )
         dk_j, dv_j = jax.lax.fori_loop(lo, hi, q_step, init)
         return None, (dk_j, dv_j)
 
     _, (dk_chunks, dv_chunks) = jax.lax.scan(dkv_step, None, jnp.arange(nk))
     dk = jnp.moveaxis(dk_chunks, 0, 2).reshape(b, h_kv, sk, d).astype(k.dtype)
-    dv = jnp.moveaxis(dv_chunks, 0, 2).reshape(b, h_kv, sk, d).astype(v.dtype)
+    dv = jnp.moveaxis(dv_chunks, 0, 2).reshape(v.shape).astype(v.dtype)
     return dq.astype(q5.dtype), (dk, dv), None
 
 
@@ -936,7 +945,7 @@ def _attn_blockwise(q, k, v, scale, causal, window, kpm, chunk_q, chunk_k):
     ck = _bw_chunk(sk_p, ck_t)
     q5 = q.reshape(b, h_kv, group, sq_p, d)
     o = _blockwise(q5, (k, v), kpm, scale, causal, window, cq, ck)
-    o = o.reshape(b, h, sq_p, d)
+    o = o.reshape(b, h, sq_p, v.shape[3])
     return o[:, :, pq:, :] if pq else o
 
 
@@ -953,7 +962,9 @@ def flash_attention(
     block_q: int = None,
     block_k: int = None,
 ):
-    """Multi-head attention; q,k,v: (batch, heads, seq, head_dim).
+    """Multi-head attention; q,k: (batch, heads, seq, d_qk), v: (batch,
+    heads, seq, d_v). The output is ``d_v`` wide; ``d_v`` may differ from
+    ``d_qk`` (latent attention's 192 / 128) on every path.
 
     ``key_padding_mask`` ((b, sk) bool, True = padded-out key) stays on the
     Pallas fast path — the reference fmha's variable-seqlen capability
@@ -1005,7 +1016,9 @@ def flash_attention(
     use_pallas, interpret = resolve_impl(impl)
     # the backward's dk/dv kernel holds Q/dO resident the way the others
     # hold K/V, so the longer of the two sequences is what must fit
-    resident = _kv_vmem_bytes(max(sq, sk), d, jnp.dtype(q.dtype).itemsize)
+    d_v = v.shape[3]
+    resident = _kv_vmem_bytes(
+        max(sq, sk), d, jnp.dtype(q.dtype).itemsize, d_v)
     kv_resident = resident <= _KV_RESIDENT_BYTES
     tile = _flash_tiles(sq, sk, window, resident, block_q, block_k)
     pallas_ok = (
@@ -1033,8 +1046,8 @@ def flash_attention(
         return _attn_ref(q, k, v, scale, causal, mask, window)
     q3 = q.reshape(b * h, sq, d)
     k3 = k.reshape(b * h_kv, sk, d)
-    v3 = v.reshape(b * h_kv, sk, d)
+    v3 = v.reshape(b * h_kv, sk, d_v)
     o = _flash(
         q3, (k3, v3), kpm_i, h, group, scale, causal, interpret, tile, window
     )
-    return o.reshape(b, h, sq, d)
+    return o.reshape(b, h, sq, d_v)

@@ -17,19 +17,32 @@ Layout follows the reference: ``t`` is (seq, batch, heads, head_dim) and
 import jax.numpy as jnp
 
 
-def rope_frequencies(dim: int, seq_len: int, base: float = 10000.0, dtype=jnp.float32):
+def rope_frequencies(dim: int, seq_len: int, base: float = 10000.0,
+                     dtype=jnp.float32, interleaved: bool = False):
     """Build the (seq, 1, 1, dim) angle tensor (ref: RotaryEmbedding in
-    testing/standalone_transformer_lm.py; freqs duplicated across halves)."""
+    testing/standalone_transformer_lm.py; freqs duplicated across halves).
+    ``interleaved``: channel pair (2i, 2i+1) carries angle i (the layout
+    ``apply_rotary_pos_emb(..., interleaved=True)`` rotates)."""
     inv_freq = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # (seq, dim/2)
-    emb = jnp.concatenate([freqs, freqs], axis=-1)  # (seq, dim)
+    if interleaved:
+        emb = jnp.repeat(freqs, 2, axis=-1)  # (seq, dim): f0 f0 f1 f1 ...
+    else:
+        emb = jnp.concatenate([freqs, freqs], axis=-1)  # (seq, dim)
     return emb[:, None, None, :].astype(dtype)
 
 
 def _rotate_half(x):
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([-x2, x1], axis=-1)
+
+
+def _rotate_pairs(x):
+    """(x0, x1, x2, x3, ...) -> (-x1, x0, -x3, x2, ...): the partner of
+    each channel in a rotation of consecutive pairs."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    return jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1).reshape(x.shape)
 
 
 def apply_rotary_pos_emb_cached(t, cos_, sin_):
@@ -47,17 +60,21 @@ def apply_rotary_pos_emb_cached(t, cos_, sin_):
     return jnp.concatenate([out, t_pass], axis=-1)
 
 
-def apply_rotary_pos_emb(t, freqs):
+def apply_rotary_pos_emb(t, freqs, interleaved: bool = False):
     """Apply RoPE to the first ``rot_dim`` channels of ``t``.
 
     Matches the reference semantics (fused_rope.py:19-78): channels beyond
     freqs.shape[-1] pass through; math in fp32, output keeps t.dtype.
+    ``interleaved`` rotates consecutive pairs (0,1), (2,3), ... (the
+    DeepSeek / GPT-J layout) instead of channel i with i + rot_dim/2;
+    ``freqs`` then comes from ``rope_frequencies(..., interleaved=True)``.
     """
     rot_dim = freqs.shape[-1]
     t_rot, t_pass = t[..., :rot_dim], t[..., rot_dim:]
     f = freqs.astype(jnp.float32)
     tr = t_rot.astype(jnp.float32)
-    out = tr * jnp.cos(f) + _rotate_half(tr) * jnp.sin(f)
+    partner = _rotate_pairs(tr) if interleaved else _rotate_half(tr)
+    out = tr * jnp.cos(f) + partner * jnp.sin(f)
     out = out.astype(t.dtype)
     if t_pass.shape[-1] == 0:
         return out

@@ -23,12 +23,12 @@ additionally threads the per-layer ``layer_out_rms`` taps
 depth series the divergence bisector localizes a corruption with.
 
 The step signature (``collect_layer_rms`` appends ``layer_rms`` to the
-outputs)::
+outputs, ``collect_expert_choices`` the experts every token chose)::
 
     (params, opt_state, scaler_state, sent_state, bag,
      tokens, labels, inject_nan, lr_scale)
       -> (params, opt_state, scaler_state, sent_state, bag,
-          loss, verdict[, layer_rms])
+          loss, verdict[, layer_rms][, expert_choices])
 
 ``build_gpt_training`` initializes ``parallel_state`` (process-global,
 the example/CLI convention) and returns a :class:`GPTTraining` holding
@@ -97,6 +97,12 @@ class GPTTargetConfig:
     skip_budget: int = 1
     rollback_budget: int = 2
     collect_layer_rms: bool = False
+    #: append the experts every token chose in this step to the step's
+    #: outputs, after ``layer_rms`` where that is on: int32 (dp, microbatches,
+    #: expert layers, tokens, top_k), trunk layers in depth order, then the
+    #: multi-token-prediction block's. What ran is what is read: an output
+    #: check compares them with a reference's choices (PERF.md 2)
+    collect_expert_choices: bool = False
     #: cap the mesh to the first N visible devices (None = all). The
     #: in-process topology changes of the remediation selftest/campaign
     #: build an 8-device and a 4-device training in ONE process (the
@@ -104,9 +110,23 @@ class GPTTargetConfig:
     #: ``devices=``); cross-process runs keep None and size the world
     #: with XLA_FLAGS instead.
     max_devices: Optional[int] = None
+    #: the model's description beyond (layers, hidden, heads, vocab,
+    #: seq_len): ``TransformerConfig`` fields as sorted (name, value)
+    #: pairs (``apex_tpu.models.arch`` makes them from an architecture
+    #: file and the share this program holds). None = the GPT-2-shaped
+    #: model the five integers describe. A dict in JSON.
+    model: Optional[Tuple[Tuple[str, Any], ...]] = None
+
+    def __post_init__(self):
+        if self.model is not None:
+            object.__setattr__(self, "model", _pairs(self.model))
 
     def to_json(self) -> dict:
-        return dataclasses.asdict(self)
+        d = dataclasses.asdict(self)
+        if self.model is not None:
+            d["model"] = {k: list(v) if isinstance(v, tuple) else v
+                          for k, v in self.model}
+        return d
 
     @classmethod
     def from_json(cls, d: dict) -> "GPTTargetConfig":
@@ -114,6 +134,14 @@ class GPTTargetConfig:
         journal must fail on MISSING semantics, not added ones)."""
         names = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in d.items() if k in names})
+
+
+def _pairs(model) -> Tuple[Tuple[str, Any], ...]:
+    """A model description (dict, or pairs) as sorted hashable pairs."""
+    items = model.items() if isinstance(model, dict) else model
+    return tuple(sorted(
+        (str(k), tuple(v) if isinstance(v, (list, tuple)) else v)
+        for k, v in items))
 
 
 @dataclasses.dataclass
@@ -158,7 +186,11 @@ class GPTTraining:
             check_vma=False,
         )
         def init_params(tokens):
-            return self.model.init(jax.random.PRNGKey(cfg.seed), tokens)
+            # a multi-token-prediction block is only traced with labels
+            labels = ({"labels": tokens}
+                      if self.transformer_config.mtp_num_layers else {})
+            return self.model.init(
+                jax.random.PRNGKey(cfg.seed), tokens, **labels)
 
         params = init_params(sample_tokens)
         # optimizer/scaler state is pinned to the SAME mesh-replicated
@@ -237,7 +269,7 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
     from apex_tpu import monitor, resilience
     from apex_tpu.amp import GradScaler
     from apex_tpu.compat import shard_map
-    from apex_tpu.models import GPTModel, gpt_loss_fn
+    from apex_tpu.models import GPTModel, gpt_loss_fn, gpt_mtp_loss_fn
     from apex_tpu.monitor.goodput.scopes import step_phase
     from apex_tpu.optimizers import fused_adam
     from apex_tpu.parallel import parallel_state
@@ -262,19 +294,32 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
         f"micro_batch ({cfg.micro_batch}) x dp ({dp}) microbatches"
     )
 
-    tcfg = TransformerConfig(
-        num_layers=cfg.layers,
-        hidden_size=cfg.hidden,
-        num_attention_heads=cfg.heads,
-        vocab_size=cfg.vocab,
-        max_position_embeddings=cfg.seq_len,
-        hidden_dropout=0.0,
-        attention_dropout=0.0,
-        sequence_parallel=cfg.sequence_parallel and cfg.tp > 1,
-        compute_dtype=jnp.bfloat16,
-        collect_layer_metrics=cfg.collect_layer_rms,
-    )
+    tcfg = TransformerConfig(**dict(
+        dict(
+            num_layers=cfg.layers,
+            hidden_size=cfg.hidden,
+            num_attention_heads=cfg.heads,
+            vocab_size=cfg.vocab,
+            max_position_embeddings=cfg.seq_len,
+            hidden_dropout=0.0,
+            attention_dropout=0.0,
+            sequence_parallel=cfg.sequence_parallel and cfg.tp > 1,
+            compute_dtype=jnp.bfloat16,
+            collect_layer_metrics=cfg.collect_layer_rms,
+        ),
+        **dict(cfg.model or ()),
+    ))
     model = GPTModel(config=tcfg)
+    # a described model (cfg.model) may hold experts and a multi-token-
+    # prediction block: a second loss term, per-layer load counters, and a
+    # router bias that no update may move
+    has_experts = "experts" in {
+        tcfg.layer_kinds(i)[1] for i in range(cfg.layers + 1)}
+    # the experts each token chose leave the forward pass when the step
+    # hands them out or moves the router's bias by them
+    keep_choices = has_experts and (
+        cfg.collect_expert_choices or tcfg.moe_bias_update_speed > 0)
+    n_taps = cfg.layers + (tcfg.mtp_num_layers if cfg.model else 0)
 
     # --zero: the ZeRO-2 optimizer's psum_scatter IS the dp gradient sync
     # (average_grads=True completes the mean), so the explicit dp
@@ -342,10 +387,25 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
         "skipped": "sum",        # updates suppressed this interval
         "anomalies": "last",     # sentinel's running total this run
     }
+    if tcfg.mtp_num_layers:
+        metric_spec.update(
+            loss_main="mean",    # next-token cross entropy alone
+            loss_mtp="mean",     # the multi-token-prediction term, unweighted
+        )
+    if has_experts:
+        metric_spec.update(
+            moe_rows_here="mean",       # assignments on held experts a step
+            moe_load_max="max",         # most rows one held expert took
+            moe_load_mean="mean",       # rows a held expert took, mean
+            moe_load_max_over_mean="mean",  # max / mean a layer, over layers
+            moe_dropped="sum",          # assignments the capacity rule cut
+        )
 
     out_specs = (P(), opt_specs, P(), P(), P(), P(), P())
     if cfg.collect_layer_rms:
         out_specs = out_specs + (P(),)
+    if cfg.collect_expert_choices:
+        out_specs = out_specs + (P("dp"),)
 
     # donated carried state: params/opt/scaler/sentinel buffers are reused
     # in place across the Python step loop instead of double-buffering the
@@ -377,6 +437,8 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
 
         # tokens: (num_micro, micro*dp, seq) -> this dp shard's microbatches
         def micro_loss(p, tok, lab):
+            if cfg.model is not None:
+                return described_loss(p, tok, lab)
             if not cfg.collect_layer_rms:
                 return gpt_loss_fn(model.apply(p, tok, labels=lab)), None
             # per-layer activation-RMS taps (monitor/taps.py
@@ -391,10 +453,43 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
                 inter["intermediates"], cfg.layers
             )
 
+        def described_loss(p, tok, lab):
+            """One microbatch of a described model: (loss, taps) with taps
+            = {"rms": per-layer RMS or None, "terms": (main, mtp) losses or
+            None, "moe": per-layer load statistics or None, "chosen":
+            {expert layer's module path: (tokens, top_k)} or None}."""
+            out, inter = model.apply(
+                p, tok, labels=lab, mutable=["intermediates"])
+            inter = inter.get("intermediates", {})
+            terms = None
+            if tcfg.mtp_num_layers:
+                loss, main, mtp = gpt_mtp_loss_fn(*out, tcfg.mtp_loss_coeff)
+                terms = jnp.stack([main, mtp])
+            else:
+                loss = gpt_loss_fn(out)
+            rms = (_layer_rms_vector(inter, n_taps)
+                   if cfg.collect_layer_rms else None)
+            return loss, {"rms": rms, "terms": terms,
+                          "moe": _moe_load_stats(inter)
+                          if has_experts else None,
+                          "chosen": dict(_sown(inter, "moe_chosen"))
+                          if keep_choices else None}
+
         def scaled_total(p):
-            losses, rms = jax.vmap(
-                lambda t, l: micro_loss(p, t, l)
-            )(tokens, labels)
+            if cfg.model is not None:
+                # one microbatch after another, not vmapped: the experts'
+                # grouped matmul takes its group sizes as scalars
+                each = [micro_loss(p, tokens[i], labels[i])
+                        for i in range(num_micro)]
+                losses = jnp.stack([l for l, _ in each])
+                taps = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *[t for _, t in each])
+                rms = taps.pop("rms")
+            else:
+                taps = None
+                losses, rms = jax.vmap(
+                    lambda t, l: micro_loss(p, t, l)
+                )(tokens, labels)
             # multiplicative NaN poison (chaos harness): both the loss and
             # every grad through it go non-finite, like a real blowup
             scaled = chaos.poison_loss(
@@ -408,6 +503,10 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             aux = (None if rms is None
                    else jnp.mean(jnp.square(rms.astype(jnp.float32)),
                                  axis=0))
+            if taps is not None:
+                # the other taps ride out beside the RMS, stopped: they are
+                # read, never differentiated
+                aux = (aux, jax.lax.stop_gradient(taps))
             return scaled, aux
 
         # comms-ledger weighting: collectives inside the vmapped model
@@ -417,6 +516,9 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             (loss, layer_rms), grads = jax.value_and_grad(
                 scaled_total, has_aux=True
             )(params)
+        taps = None
+        if cfg.model is not None:
+            layer_rms, taps = layer_rms
         if layer_rms is not None:
             # global per-layer RMS: mean-of-squares pmean'ed over both
             # mesh axes (the out_specs claim P() replication, which the
@@ -429,6 +531,13 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
                         monitor.xray.ledger.pmean(layer_rms, "tp"), "dp"
                     )
                 )
+        bias_steps = {}
+        if has_experts and tcfg.moe_bias_update_speed > 0:
+            with step_phase("guard"):
+                bias_steps = _router_bias_steps(
+                    taps["chosen"], tcfg.num_moe_experts,
+                    tcfg.moe_bias_update_speed, cfg.micro_batch,
+                    monitor.xray.ledger.psum)
         new_ef = None
         if not cfg.zero:
             # ZeRO's reduce-scatter inside opt.update replaces this
@@ -472,6 +581,16 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             updates, new_opt = opt.update(grads, opt_state, params)
             # rollback escalation dampens the effective LR through here
             updates = jax.tree_util.tree_map(lambda u: u * lr_scale, updates)
+            if has_experts:
+                # the router's bias is no trained weight: it takes no
+                # gradient (moe.router_sigmoid) and nothing of Adam's, decay
+                # included; it moves by the balancing rule alone, or not
+                updates = jax.tree_util.tree_map_with_path(
+                    lambda path, u: bias_steps.get(
+                        "/".join(p.key for p in path[1:-1]),
+                        jnp.zeros_like(u))
+                    if getattr(path[-1], "key", None) == "router_bias"
+                    else u, updates)
             return optax.apply_updates(params, updates), new_opt
 
         with step_phase("optimizer"):
@@ -515,11 +634,17 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
                 ),
                 skipped=jnp.asarray(gate, jnp.float32),
                 anomalies=jnp.asarray(new_sent_state.anomalies, jnp.float32),
+                **_described_taps(taps, monitor.xray.ledger.pmean),
             )
         out = (new_params, new_opt_state, new_scaler_state, new_sent_state,
                new_bag, unscaled, verdict)
         if cfg.collect_layer_rms:
             out = out + (layer_rms,)
+        if cfg.collect_expert_choices:
+            chosen = taps["chosen"]  # a dict comes back in its keys' order
+            out = out + (jnp.stack(
+                [chosen[p] for p in sorted(chosen, key=_depth_order)],
+                axis=1)[None],)
         return out
 
     return GPTTraining(
@@ -530,6 +655,97 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
         replicated=jax.sharding.NamedSharding(mesh, P()),
         ddp_compressed=ddp_compressed,
     )
+
+
+def _sown(intermediates, name):
+    """[(module path, value)] of every value the expert layers sowed as
+    ``name``, trunk layers in depth order (``layer_10`` after ``layer_9``),
+    then a multi-token-prediction block's; a path is as in the parameters'
+    tree, "transformer/layer_1/mlp"."""
+    found = []
+
+    def visit(node, path):
+        if not isinstance(node, dict):
+            return
+        for k, v in node.items():
+            if k == name:
+                found.extend(("/".join(path), x) for x in (
+                    v if isinstance(v, (tuple, list)) else (v,)))
+            else:
+                visit(v, path + (str(k),))
+
+    visit(intermediates, ())
+    return sorted(found, key=lambda kv: _depth_order(kv[0]))
+
+
+def _depth_order(path: str):
+    """Sort key of a module path: natural order of its digits, a multi-
+    token-prediction block after the trunk."""
+    import re
+
+    return (path.startswith("mtp/"),
+            [int(t) if t.isdigit() else t for t in re.split(r"(\d+)", path)])
+
+
+def _router_bias_steps(chosen, num_experts, speed, micro_batch, psum):
+    """DeepSeek-V3's balancing without an auxiliary loss (arXiv 2408.15664;
+    the ``noaux_tc`` router's bias): after a step, each expert layer's bias
+    goes up by ``speed`` for every expert that took fewer assignments than
+    the mean over the step's whole batch and down for every one that took
+    more. ``chosen``: {module path: (microbatches, tokens, top_k)}, tokens
+    (seq, micro_batch) flattened; of a multi-token-prediction block the
+    last position, which has no target, is not counted. Returns {module
+    path: the bias's step}."""
+    import jax.numpy as jnp
+
+    steps = {}
+    for path, c in chosen.items():
+        if path.startswith("mtp/"):
+            c = c[:, :-micro_batch]
+        counts = psum(jnp.sum(
+            c[..., None] == jnp.arange(num_experts), axis=(0, 1, 2),
+            dtype=jnp.float32), "dp")
+        steps[path] = speed * jnp.sign(jnp.mean(counts) - counts)
+    return steps
+
+
+def _moe_load_stats(intermediates):
+    """The expert layers' sown ``moe_load`` (rows each held expert took)
+    and ``moe_dropped`` as (rows here a step, largest load, mean load,
+    max / mean averaged over the layers, dropped)."""
+    import jax.numpy as jnp
+
+    loads = [v for _, v in _sown(intermediates, "moe_load")]
+    dropped = [v for _, v in _sown(intermediates, "moe_dropped")]
+    load = jnp.stack(loads).astype(jnp.float32)       # (layers, held)
+    mean = jnp.mean(load, axis=1)
+    return jnp.stack([
+        jnp.sum(load), jnp.max(load), jnp.mean(load),
+        jnp.mean(jnp.max(load, axis=1) / jnp.maximum(mean, 1e-9)),
+        jnp.sum(jnp.stack(dropped)).astype(jnp.float32)])
+
+
+def _described_taps(taps, pmean):
+    """MetricBag entries from a described model's taps (stacked over the
+    microbatches), dp-averaged like the loss."""
+    import jax.numpy as jnp
+
+    out = {}
+    if taps is None:
+        return out
+    if taps["terms"] is not None:
+        main, mtp = pmean(jnp.mean(taps["terms"], axis=0), "dp")
+        out.update(loss_main=main, loss_mtp=mtp)
+    if taps["moe"] is not None:
+        m = taps["moe"]
+        out.update(
+            moe_rows_here=pmean(jnp.sum(m[:, 0]), "dp"),
+            moe_load_max=jnp.max(m[:, 1]),
+            moe_load_mean=pmean(jnp.mean(m[:, 2]), "dp"),
+            moe_load_max_over_mean=pmean(jnp.mean(m[:, 3]), "dp"),
+            moe_dropped=pmean(jnp.sum(m[:, 4]), "dp"),
+        )
+    return out
 
 
 def _layer_rms_vector(intermediates, n_layers: int):
@@ -559,7 +775,8 @@ def _layer_rms_vector(intermediates, n_layers: int):
         return [int(t) if t.isdigit() else t
                 for t in re.split(r"(\d+)", key[0])]
 
-    found.sort(key=natural)
+    # a multi-token-prediction block's layer sits after the trunk's
+    found.sort(key=lambda kv: (kv[0].startswith("mtp/"), natural(kv)))
     if len(found) != n_layers:
         raise ValueError(
             f"expected {n_layers} layer_out_rms taps, found {len(found)} "

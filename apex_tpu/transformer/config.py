@@ -9,7 +9,7 @@ axis names, attention impl) alongside the reference's architectural ones.
 """
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -59,6 +59,25 @@ class TransformerConfig:
     position_embedding_type: str = "learned"  # "learned" | "rope" | "none"
     rotary_percent: float = 1.0
     rotary_base: float = 10000.0  # RoPE theta (llama-3 uses 500000)
+    # rotate consecutive channel pairs (0,1), (2,3), ... instead of channel
+    # i with i + rot_dim/2 (the DeepSeek-family layout)
+    rotary_interleaved: bool = False
+
+    # what each layer is (``layer_kinds(i)``). None = every layer alike:
+    # attention "mha", MLP "experts" when num_moe_experts is set, else
+    # "dense". A tuple of one entry a layer says otherwise: attention
+    # "mha" | "latent"; MLP "dense" | "experts" (leading dense layers
+    # before expert layers are ("dense", "experts", "experts", ...)).
+    attention_layer_kinds: Optional[Tuple[str, ...]] = None
+    mlp_layer_kinds: Optional[Tuple[str, ...]] = None
+    # latent attention (DeepSeek-V2/V3 MLA, training form): low-rank q and
+    # kv projections, a rope part all heads share, a no-rope part a head.
+    # q/k are qk_nope_head_dim + qk_rope_head_dim wide, v v_head_dim
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
 
     # parallelism
     sequence_parallel: bool = False
@@ -73,9 +92,37 @@ class TransformerConfig:
     # moe_expert_axis (None = local experts)
     num_moe_experts: Optional[int] = None
     moe_top_k: int = 1
-    moe_capacity_factor: float = 1.25
+    # slots an expert has for each top-k pass, as a multiple of tokens /
+    # experts; assignments beyond them are dropped (Switch/GShard). None =
+    # dropless: every assignment is computed
+    moe_capacity_factor: Optional[float] = 1.25
     moe_expert_axis: Optional[str] = None
     moe_aux_loss_coeff: float = 0.01
+    # "softmax" (Switch/GShard: gates are the chosen probabilities) |
+    # "sigmoid" (DeepSeek-V3 noaux_tc: experts chosen by score + a bias
+    # that takes no gradient, gates from the scores alone)
+    moe_router: str = "softmax"
+    moe_norm_topk_prob: bool = False  # gates divided by their sum
+    moe_routed_scaling_factor: float = 1.0
+    moe_ffn_hidden_size: Optional[int] = None  # None = ffn_hidden_size
+    moe_gated_experts: bool = False  # SwiGLU experts (gate, up, down)
+    moe_shared_experts: int = 0  # always-on experts beside the routed
+    # the share of the routed experts this program holds, outside an
+    # expert axis: [moe_first_expert, moe_first_expert + moe_experts_held)
+    # of num_moe_experts. The router stays num_moe_experts wide; what the
+    # absent experts would add is left out (None = all of them)
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
+    moe_impl: str = "auto"  # the experts' grouped matmul (ops._dispatch)
+    # balancing without an auxiliary loss (the sigmoid router's bias): what
+    # the step builder moves each expert's bias by after a step, up where the
+    # expert took fewer assignments than the mean, down where more; 0 holds
+    # the bias where it is (DeepSeek-V3 trained with 0.001)
+    moe_bias_update_speed: float = 0.0
+    # multi-token prediction (DeepSeek-V3): modules after the trunk, each
+    # one layer of the stack's last kind predicting one token further
+    mtp_num_layers: int = 0
+    mtp_loss_coeff: float = 0.3
     recompute_granularity: Optional[str] = None  # None | "full" | "selective"
 
     # telemetry (apex_tpu.monitor): sow a per-layer output-RMS tap
@@ -107,3 +154,25 @@ class TransformerConfig:
             object.__setattr__(
                 self, "kv_channels", self.hidden_size // self.num_attention_heads
             )
+        for field, known in (("attention_layer_kinds", ("mha", "latent")),
+                             ("mlp_layer_kinds", ("dense", "experts"))):
+            kinds = getattr(self, field)
+            if kinds is None:
+                continue
+            object.__setattr__(self, field, tuple(kinds))
+            if len(kinds) != self.num_layers or set(kinds) - set(known):
+                raise ValueError(
+                    f"layer kinds {kinds!r}: one of {known} for each of "
+                    f"{self.num_layers} layers")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router {self.moe_router!r}")
+
+    def layer_kinds(self, i: int) -> Tuple[str, str]:
+        """(attention kind, MLP kind) of layer ``i``; an index past the
+        stack (a multi-token-prediction block) is of the last layer's."""
+        i = min(i, self.num_layers - 1)
+        attn = (self.attention_layer_kinds[i]
+                if self.attention_layer_kinds is not None else "mha")
+        if self.mlp_layer_kinds is not None:
+            return attn, self.mlp_layer_kinds[i]
+        return attn, "experts" if self.num_moe_experts is not None else "dense"

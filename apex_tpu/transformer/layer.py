@@ -25,6 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.monitor.goodput.scopes import model_scope
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import layer_norm, rms_norm
 from apex_tpu.ops.rope import apply_rotary_pos_emb, rope_frequencies
@@ -385,8 +386,8 @@ class ParallelAttention(nn.Module):
                     q_pos_emb, pos0, q.shape[0], 0)
                 k_pos_emb = jax.lax.dynamic_slice_in_dim(
                     k_pos_emb, pos0, k.shape[0], 0)
-            q = apply_rotary_pos_emb(q, q_pos_emb)
-            k = apply_rotary_pos_emb(k, k_pos_emb)
+            q = apply_rotary_pos_emb(q, q_pos_emb, cfg.rotary_interleaved)
+            k = apply_rotary_pos_emb(k, k_pos_emb, cfg.rotary_interleaved)
 
         # (s, b, np, hn) -> (b, np, s, hn)
         qb = jnp.transpose(q, (1, 2, 0, 3))
@@ -603,6 +604,71 @@ class ParallelAttention(nn.Module):
         return out
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3 MLA) in its training
+    form: queries and keys/values come through low-rank bottlenecks
+    (``q_lora_rank``, ``kv_lora_rank``, each normed), every head has a
+    no-rope part of its own and a rope part whose key side ALL heads share,
+    and the values are narrower than the keys. It runs as plain multi-head
+    attention with ``qk_nope + qk_rope``-wide q/k and ``v_head_dim``-wide v
+    through the flash kernels: no padding of v, no absorbed form, no cache
+    (serving it needs a latent paged cache: ROADMAP R8)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden_states, attention_mask=None,
+                 encoder_output=None, rotary_pos_emb=None,
+                 key_padding_mask=None, deterministic: bool = True):
+        cfg = self.config
+        if attention_mask is not None or encoder_output is not None:
+            raise NotImplementedError(
+                "latent attention is causal self-attention on the flash "
+                "path: no dense mask, no cross attention")
+        if _tp_size(cfg.tensor_axis) > 1 or cfg.context_parallel_mode:
+            raise NotImplementedError("latent attention under tp or cp")
+        s, b, _ = hidden_states.shape
+        heads = cfg.num_attention_heads
+        nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=hidden_states.dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(stddev=0.02))
+
+        with model_scope("mla_project"):
+            c_q = Norm(config=cfg, name="q_a_layernorm")(
+                dense(cfg.q_lora_rank, name="q_a_proj")(hidden_states))
+            q = dense(heads * (nope + rope), name="q_b_proj")(c_q)
+            q = q.reshape(s, b, heads, nope + rope)
+            kv_a = dense(cfg.kv_lora_rank + rope, name="kv_a_proj")(
+                hidden_states)
+            c_kv = Norm(config=cfg, name="kv_a_layernorm")(
+                kv_a[..., : cfg.kv_lora_rank])
+            k_rope = kv_a[..., cfg.kv_lora_rank:].reshape(s, b, 1, rope)
+            kv = dense(heads * (nope + dv), name="kv_b_proj")(c_kv)
+            kv = kv.reshape(s, b, heads, nope + dv)
+            freqs, _ = rotary_pos_emb
+            rotate = functools.partial(
+                apply_rotary_pos_emb, freqs=freqs[:s],
+                interleaved=cfg.rotary_interleaved)
+            q = jnp.concatenate(
+                [q[..., :nope], rotate(q[..., nope:])], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(rotate(k_rope), (s, b, heads, rope))],
+                axis=-1)
+            # (s, b, heads, d) -> (b, heads, s, d)
+            qb, kb, vb = (jnp.transpose(t, (1, 2, 0, 3))
+                          for t in (q, k, kv[..., nope:]))
+        ctx = flash_attention(
+            qb, kb, vb, causal=True, key_padding_mask=key_padding_mask,
+            scale=1.0 / math.sqrt(nope + rope), impl=cfg.attention_impl)
+        with model_scope("mla_project"):
+            ctx = jnp.transpose(ctx, (2, 0, 1, 3)).reshape(s, b, heads * dv)
+            return dense(cfg.hidden_size, name="o_proj")(ctx)
+
+
 class ParallelTransformerLayer(nn.Module):
     """Pre-LN transformer block (ref: ParallelTransformerLayer in
     standalone_transformer_lm.py): LN → attn → residual → LN → MLP → residual,
@@ -611,6 +677,9 @@ class ParallelTransformerLayer(nn.Module):
     config: TransformerConfig
     attn_mask_type: AttnMaskType = AttnMaskType.causal
     has_cross_attention: bool = False
+    #: what this layer is: (attention kind, MLP kind), as
+    #: ``TransformerConfig.layer_kinds`` gives them; None = layer 0's
+    kinds: Optional[tuple] = None
 
     @nn.compact
     def __call__(
@@ -633,9 +702,14 @@ class ParallelTransformerLayer(nn.Module):
             cfg = dataclasses.replace(cfg, sequence_parallel=False)
         rdtype = jnp.float32 if cfg.fp32_residual_connection else hidden_states.dtype
         cache_active = cache_len is not None or decode_step
+        attn_kind, mlp_kind = self.kinds or cfg.layer_kinds(0)
+        latent = attn_kind == "latent"
+        if latent and (cache_active or self.has_cross_attention):
+            raise NotImplementedError(
+                "latent attention has no cache and no cross attention")
 
         ln_out = Norm(config=cfg, name="input_layernorm")(hidden_states)
-        attn_cls = ParallelAttention
+        attn_cls = LatentAttention if latent else ParallelAttention
         if cfg.recompute_granularity == "selective" and not cache_active:
             # recompute only the attention block in backward (ref: Megatron
             # --recompute-granularity selective; core-attention checkpoint).
@@ -643,12 +717,13 @@ class ParallelTransformerLayer(nn.Module):
             # (decode has no backward — remat would only re-trace the cache
             # mutation, so it is skipped in cache mode)
             attn_cls = nn.remat(
-                ParallelAttention, static_argnums=(6,), prevent_cse=False
+                attn_cls, static_argnums=(6,), prevent_cse=False
             )
         attn_out = attn_cls(
             config=cfg,
-            attn_type=AttnType.self_attn,
-            attn_mask_type=self.attn_mask_type,
+            **({} if latent else dict(
+                attn_type=AttnType.self_attn,
+                attn_mask_type=self.attn_mask_type)),
             name="self_attention",
         )(
             ln_out,
@@ -693,7 +768,7 @@ class ParallelTransformerLayer(nn.Module):
             )
 
         ln2 = Norm(config=cfg, name="post_attention_layernorm")(h)
-        if cfg.num_moe_experts is not None:
+        if mlp_kind == "experts":
             from apex_tpu.transformer.moe import MoEMLP
 
             s_, b_, h_ = ln2.shape
@@ -703,6 +778,17 @@ class ParallelTransformerLayer(nn.Module):
                 top_k=cfg.moe_top_k,
                 capacity_factor=cfg.moe_capacity_factor,
                 expert_axis=cfg.moe_expert_axis,
+                router=cfg.moe_router,
+                norm_topk_prob=cfg.moe_norm_topk_prob,
+                routed_scaling_factor=cfg.moe_routed_scaling_factor,
+                gated=cfg.moe_gated_experts,
+                **({"activation": jax.nn.silu} if cfg.moe_gated_experts
+                   else {}),
+                ffn_hidden_size=cfg.moe_ffn_hidden_size,
+                shared_experts=cfg.moe_shared_experts,
+                experts_held=cfg.moe_experts_held,
+                first_expert=cfg.moe_first_expert,
+                impl=cfg.moe_impl,
                 name="mlp",
             )(ln2.reshape(s_ * b_, h_))
             mlp_out = mlp_out.reshape(s_, b_, h_)
@@ -747,6 +833,9 @@ class ParallelTransformer(nn.Module):
     num_layers: Optional[int] = None
     post_layer_norm: bool = True
     attn_mask_type: AttnMaskType = AttnMaskType.causal
+    #: also return the last layer's output before the final norm (what a
+    #: multi-token-prediction block continues from): (normed, before)
+    also_pre_norm: bool = False
 
     @nn.compact
     def __call__(
@@ -773,7 +862,8 @@ class ParallelTransformer(nn.Module):
             )
         for i in range(n):
             hidden_states = layer_cls(
-                config=cfg, attn_mask_type=self.attn_mask_type, name=f"layer_{i}"
+                config=cfg, attn_mask_type=self.attn_mask_type,
+                kinds=cfg.layer_kinds(i), name=f"layer_{i}"
             )(
                 hidden_states,
                 attention_mask,
@@ -788,13 +878,48 @@ class ParallelTransformer(nn.Module):
                     else {}
                 ),
             )
+        before = hidden_states
         if self.post_layer_norm:
             hidden_states = Norm(config=cfg, name="final_layernorm")(hidden_states)
-        return hidden_states
+        return (hidden_states, before) if self.also_pre_norm else hidden_states
+
+
+class MultiTokenPrediction(nn.Module):
+    """DeepSeek-V3's multi-token-prediction module: from the trunk's last
+    hidden state at position i (before the final norm) and the embedding
+    of token i+1, one more layer of the stack's last kind, with norms of
+    its own, whose output the TRUNK's head reads as the logits of token
+    i+2: ``h' = W_eh [norm(h_i) | norm(emb(t_{i+1}))]``, the layer, a final
+    norm. Embedding and head are the caller's (``models.GPTModel``)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden_states, next_embeddings, rotary_pos_emb=None,
+                 key_padding_mask=None, deterministic: bool = True):
+        cfg = self.config
+        joined = jnp.concatenate(
+            [Norm(config=cfg, name="hnorm")(hidden_states),
+             Norm(config=cfg, name="enorm")(next_embeddings)], axis=-1)
+        x = nn.Dense(
+            cfg.hidden_size, use_bias=False, dtype=hidden_states.dtype,
+            param_dtype=cfg.params_dtype,
+            kernel_init=nn.initializers.normal(stddev=0.02), name="eh_proj",
+        )(joined)
+        x = ParallelTransformerLayer(
+            config=cfg, kinds=cfg.layer_kinds(cfg.num_layers), name="layer",
+        )(x, None, None, None, rotary_pos_emb, key_padding_mask,
+          deterministic)
+        return Norm(config=cfg, name="final_layernorm")(x)
 
 
 def rotary_embedding_for(config: TransformerConfig, seq_len: int, dtype=jnp.float32):
-    """Precompute (q_freqs, k_freqs) for ParallelAttention's rotary path."""
-    rot_dim = int(config.kv_channels * config.rotary_percent)
-    f = rope_frequencies(rot_dim, seq_len, base=config.rotary_base, dtype=dtype)
+    """Precompute (q_freqs, k_freqs) for the attention modules' rotary
+    path: over ``kv_channels * rotary_percent`` channels, or over latent
+    attention's ``qk_rope_head_dim``."""
+    rot_dim = (config.qk_rope_head_dim
+               if config.layer_kinds(0)[0] == "latent"
+               else int(config.kv_channels * config.rotary_percent))
+    f = rope_frequencies(rot_dim, seq_len, base=config.rotary_base,
+                         dtype=dtype, interleaved=config.rotary_interleaved)
     return f, f

@@ -2,32 +2,50 @@
 
 No reference counterpart (the reference has no MoE/EP — SURVEY.md §2.5
 lists EP as absent); this is the expert-parallelism extension the TPU
-framework makes first-class, in the Switch/GShard capacity-based style
-that maps cleanly onto static XLA shapes:
+framework makes first-class. One layer, ``MoEMLP``, whose settings cover
+both families in use:
 
-- a router scores tokens against E experts (top-1 "switch" or top-2
-  "gshard" gating) with the standard load-balancing auxiliary loss
-  ``E * Σ_e fraction_e * prob_e``;
-- tokens are packed into a (E, capacity, h) dispatch tensor via the
-  cumsum position trick (overflow tokens are dropped, pass through the
-  residual path);
-- experts are sharded over a mesh axis (``expert_axis``): one
-  ``all_to_all`` ships each rank's per-expert slots to the expert's owner,
-  the expert FFNs run as one batched einsum over the local experts, and a
-  second ``all_to_all`` ships results home — the EP dispatch pattern over
-  ICI;
-- with expert_axis size 1 (or outside shard_map) everything degrades to a
-  local MoE.
+- **Switch / GShard** (``router="softmax"``, top-1/top-2, a
+  ``capacity_factor``): gates are the chosen softmax probabilities, each
+  expert takes ``capacity`` assignments a top-k pass and the rest are
+  dropped (they pass through the residual path), with the load-balancing
+  auxiliary loss ``E * Σ_e fraction_e * prob_e``;
+- **DeepSeek-V3** (``router="sigmoid"``, ``capacity_factor=None``): experts
+  are chosen by ``sigmoid score + bias`` (the bias takes no gradient), the
+  gates are the chosen scores, normalised and scaled; SwiGLU experts, a
+  shared expert beside them, and no assignment is ever dropped.
+
+One dispatch serves both (``_expert_rows``): the kept assignments are
+sorted by expert once, the rows are gathered in that order, the experts
+run as ONE grouped matmul whose work follows the rows present (the Pallas
+``megablox`` kernels of ``jax.experimental``; an einsum over a one-hot on
+``impl="xla"``), and each token takes its rows back weighted by its gates.
+The buffer is sized for the worst case (every assignment lands here), so
+nothing is dropped that the capacity rule did not drop.
+
+Which experts a program holds: with ``expert_axis`` each rank of the mesh
+axis holds ``num_experts / ep`` and the layer exchanges rows once each way
+(one ``all_to_all`` pair a layer); without one, ``experts_held`` /
+``first_expert`` name the share held here, the router still scores all
+``num_experts``, and what the absent experts would add is left out —
+one chip's part of an expert-parallel job, with nothing standing in for
+the other chips.
 """
 
+import functools
 from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.monitor.goodput.scopes import model_scope
 from apex_tpu.monitor.xray import ledger as xlax
+from apex_tpu.ops._dispatch import resolve_impl
 from apex_tpu.transformer.config import TransformerConfig
+
+#: rows of a grouped-matmul tile; the buffer of rows is a multiple of it
+_GMM_ROWS = 512
 
 
 def _axis_size_or_1(axis_name: Optional[str]) -> int:
@@ -46,6 +64,22 @@ def router_probs(logits, num_experts: int, top_k: int):
     return probs, gate_vals, expert_idx
 
 
+def router_sigmoid(logits, bias, top_k: int, norm_topk_prob: bool,
+                   scaling_factor: float):
+    """DeepSeek-V3's ``noaux_tc`` router without group limits: scores
+    ``s = sigmoid(logits)`` in fp32, experts chosen as the top-k of
+    ``s + bias`` (``bias`` takes no gradient), gates ``s`` of the chosen,
+    divided by their sum under ``norm_topk_prob``, times
+    ``scaling_factor``. Returns (scores, gates, expert_idx)."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(
+        s + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    gates = jnp.take_along_axis(s, expert_idx, axis=-1)
+    if norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return s, gates * scaling_factor, expert_idx
+
+
 def total_moe_aux_loss(intermediates, config) -> jnp.ndarray:
     """Sum every sown ``moe_aux_loss`` scaled by
     ``config.moe_aux_loss_coeff`` — add this to the training loss:
@@ -54,16 +88,14 @@ def total_moe_aux_loss(intermediates, config) -> jnp.ndarray:
         loss = task_loss + total_moe_aux_loss(inter, cfg)
     """
     total = jnp.asarray(0.0, jnp.float32)
-    count = 0
 
     def visit(node):
-        nonlocal total, count
+        nonlocal total
         if isinstance(node, dict):
             for k, v in node.items():
                 if k == "moe_aux_loss":
                     for leaf in jax.tree_util.tree_leaves(v):
                         total = total + leaf
-                        count += 1
                 else:
                     visit(v)
 
@@ -83,7 +115,8 @@ def load_balancing_loss(probs, expert_idx, num_experts: int):
 
 def _dispatch_indices(expert_idx, num_experts: int, capacity: int):
     """Position of each token inside its expert's capacity buffer (cumsum
-    trick); tokens beyond capacity get position -1 (dropped)."""
+    trick); tokens beyond capacity get position -1 (dropped). The capacity
+    RULE of the Switch/GShard settings: which assignments are kept."""
     onehot = jax.nn.one_hot(expert_idx, num_experts, dtype=jnp.int32)
     pos = jnp.cumsum(onehot, axis=0) * onehot  # 1-based within expert
     pos_in_expert = jnp.sum(pos, axis=-1) - 1
@@ -91,105 +124,298 @@ def _dispatch_indices(expert_idx, num_experts: int, capacity: int):
     return jnp.where(keep, pos_in_expert, -1)
 
 
+@jax.custom_vjp
+def _take_rows(src, idx, back):
+    """``src[idx]`` with rows of zeros where ``idx < 0``; ``idx`` of any
+    shape. Its transpose is a gather too: ``back`` (one row per row of
+    ``src``, any number of columns) lists the flat positions in the output
+    that read that row (-1 = none), so no scatter-add runs on the device."""
+    rows = jnp.take(src, jnp.maximum(idx, 0), axis=0)
+    return jnp.where((idx >= 0)[..., None], rows, jnp.zeros((), src.dtype))
+
+
+def _take_rows_fwd(src, idx, back):
+    return _take_rows(src, idx, back), (idx, back)
+
+
+def _take_rows_bwd(res, g):
+    idx, back = res
+    flat = g.reshape((-1, g.shape[-1]))
+    picked = _take_rows(flat, back, idx.reshape(-1, 1))
+    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype), \
+        None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _grouped_matmul(x, w, group_sizes, interpret):
+    """Rows of ``x`` (sorted by group, ``group_sizes`` rows each) times
+    their group's matrix of ``w`` (groups, k, n): the megablox kernels,
+    which visit the tiles that hold rows and no others. Rows past the
+    groups' total come back undefined."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    r, (k, n) = x.shape[0], w.shape[1:]
+    rows = min(_GMM_ROWS, -(-r // 128) * 128)
+    pad = -r % rows
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    out = megablox.gmm(x, w, group_sizes, x.dtype,
+                       (rows, min(512, k), min(512, n)), None, None, False,
+                       interpret)
+    return out[:r] if pad else out
+
+
+def _expert_rows(rows, row_expert, w_in, w_out, activate, impl):
+    """The held experts' FFN over ``rows`` (r, h): row i goes through
+    expert ``row_expert[i]`` (0-based among the ``w_in.shape[0]`` held;
+    -1 = no expert here). ``rows(order, place)`` gathers the rows in the
+    sorted order (``order[i]`` = the caller's row at sorted position i, -1
+    past the last row with an expert; ``place`` its inverse, -1 for rows
+    with no expert), so they are read once, already sorted. Returns the
+    results in sorted order, ``order`` and ``place`` so masked, and the
+    rows each held expert took."""
+    held = w_in.shape[0]
+    r = row_expert.shape[0]
+    here = row_expert >= 0
+    with model_scope("moe_dispatch"):
+        key = jnp.where(here, row_expert, held)
+        order = jnp.argsort(key, stable=True)          # rows sorted by expert
+        place = jnp.zeros((r,), jnp.int32).at[order].set(
+            jnp.arange(r, dtype=jnp.int32))            # where each row went
+        load = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        filled = jnp.arange(r) < jnp.sum(load)
+        order = jnp.where(filled, order, -1)
+        place = jnp.where(here, place, -1)
+        x = rows(order, place)
+    use_pallas, interpret = resolve_impl(impl)
+    with model_scope("moe_experts"):
+        if use_pallas:
+            hdn = activate(_grouped_matmul(x, w_in.astype(x.dtype), load,
+                                           interpret))
+            # rows past the last group are undefined: keep them finite
+            hdn = jnp.where(filled[:, None], hdn, jnp.zeros((), hdn.dtype))
+            y = _grouped_matmul(hdn, w_out.astype(x.dtype), load, interpret)
+            y = jnp.where(filled[:, None], y, jnp.zeros((), y.dtype))
+        else:
+            # plain XLA: every row through every held expert, one kept
+            onehot = (jnp.sort(key)[:, None] == jnp.arange(held)[None, :])
+            onehot = onehot & filled[:, None]
+            hdn = activate(jnp.einsum(
+                "rh,ehf->erf", x, w_in.astype(x.dtype),
+                preferred_element_type=jnp.float32).astype(x.dtype))
+            y = jnp.einsum("erf,efh->erh", hdn, w_out.astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+            y = jnp.einsum("erh,re->rh", y, onehot.astype(y.dtype)
+                           ).astype(x.dtype)
+    return y, order, place, load
+
+
+def _routed_experts(x, w_in, w_out, gate_vals, chosen, first, *, k, ep,
+                    local_e, expert_axis, activate, impl):
+    """What the experts held here add for ``x`` (tokens, h): ``chosen``
+    (tokens, k) names each token's experts (-1 = dropped by the capacity
+    rule), ``gate_vals`` their gates. Without an expert axis (``ep`` 1) the
+    rows of experts ``[first, first + local_e)`` are computed and the rest
+    left out; with one, rows are exchanged once each way. Returns the sum
+    (tokens, h) in fp32 and the rows each held expert took."""
+    tokens, h = x.shape
+    # one row an assignment, token-major: row a = (token a // k, pass a % k)
+    a_expert = chosen.reshape(-1)
+    a_token = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
+    if ep == 1:
+        local = a_expert - first
+        row_expert = jnp.where(
+            (a_expert >= 0) & (local >= 0) & (local < local_e), local, -1)
+
+        def rows(order, place):
+            # row i of the sorted buffer is token a_token[order[i]];
+            # token t's rows sit at place[t*k : (t+1)*k]
+            return _take_rows(
+                x, jnp.where(order >= 0, a_token[jnp.maximum(order, 0)], -1),
+                place.reshape(tokens, k))
+
+        y, order, place, load = _expert_rows(
+            rows, row_expert, w_in, w_out, activate, impl)
+        with model_scope("moe_combine"):
+            mine = _take_rows(y, place.reshape(tokens, k), order[:, None])
+    else:
+        # exchange once each way: every rank sends each peer the rows its
+        # experts take, in a buffer sized for the worst case (every token
+        # sends a peer min(k, local_e) rows)
+        cap = tokens * min(k, local_e)
+        with model_scope("moe_dispatch"):
+            dest = jnp.where(a_expert >= 0, a_expert // local_e, ep)
+            by_dest = jnp.argsort(dest, stable=True)
+            start = jnp.searchsorted(dest[by_dest], jnp.arange(ep + 1))
+            count = start[1:] - start[:-1]
+            col = jnp.arange(cap, dtype=jnp.int32)
+            # send[p, c] = the c-th assignment bound for peer p, or -1
+            send = jnp.where(
+                col[None, :] < count[:, None],
+                by_dest[jnp.minimum(start[:-1, None] + col[None, :],
+                                    tokens * k - 1)], -1)
+            # where each assignment sits in the send buffer (flat)
+            rank_of = jnp.zeros((tokens * k,), jnp.int32).at[by_dest].set(
+                jnp.arange(tokens * k, dtype=jnp.int32))
+            peer = jnp.minimum(dest, ep - 1)
+            sent_at = jnp.where(
+                dest < ep, peer * cap + rank_of - start[peer], -1)
+            send_x = _take_rows(
+                x, jnp.where(send >= 0, a_token[jnp.maximum(send, 0)], -1),
+                sent_at.reshape(tokens, k))
+            send_e = jnp.where(
+                send >= 0, a_expert[jnp.maximum(send, 0)] % local_e, -1)
+            recv_x = xlax.all_to_all(
+                send_x, expert_axis, split_axis=0, concat_axis=0,
+                tiled=False).reshape(ep * cap, h)
+            recv_e = xlax.all_to_all(
+                send_e, expert_axis, split_axis=0, concat_axis=0,
+                tiled=False).reshape(ep * cap)
+
+        def rows(order, place):
+            return _take_rows(recv_x, order, place[:, None])
+
+        y, order, place, load = _expert_rows(
+            rows, recv_e, w_in, w_out, activate, impl)
+        with model_scope("moe_combine"):
+            back = _take_rows(y, place, order[:, None])
+            back = xlax.all_to_all(
+                back.reshape(ep, cap, h), expert_axis, split_axis=0,
+                concat_axis=0, tiled=False).reshape(ep * cap, h)
+            # assignment a's row sits at sent_at[a]; the transpose reads
+            # flat slot send[p, c] for buffer row (p, c)
+            mine = _take_rows(back, sent_at.reshape(tokens, k),
+                              send.reshape(-1, 1))
+    with model_scope("moe_combine"):
+        out = jnp.sum(mine.astype(jnp.float32) * gate_vals[..., None], axis=1)
+    return out, load
+
+
 class MoEMLP(nn.Module):
-    """Expert-parallel MoE FFN block (Switch top-1 / GShard top-2).
+    """Expert-parallel MoE FFN block (module docstring).
 
     Input (tokens, hidden) — callers flatten (s, b). ``num_experts`` is the
-    GLOBAL expert count and must divide by the expert-axis size; each rank
-    owns ``num_experts / ep`` experts. Returns (output, aux_loss).
+    GLOBAL expert count. With ``expert_axis`` it must divide by the axis
+    size and each rank owns ``num_experts / ep`` experts; without one the
+    layer holds ``experts_held`` experts from ``first_expert`` (default:
+    all). Returns (output, aux_loss), and sows ``moe_chosen`` (the experts each
+    token chose), ``moe_load`` (rows each held expert took) and
+    ``moe_dropped`` for whoever collects ``intermediates``.
     """
 
     config: TransformerConfig
     num_experts: int = 8
     top_k: int = 1
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25
     expert_axis: Optional[str] = "dp"
     activation: Callable = jax.nn.gelu
+    router: str = "softmax"
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    gated: bool = False
+    ffn_hidden_size: Optional[int] = None
+    shared_experts: int = 0
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    impl: str = "auto"  # the grouped matmul: "auto" | "pallas" | "xla"
 
     @nn.compact
     def __call__(self, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
         cfg = self.config
         tokens, h = x.shape
-        e = self.num_experts
+        e, k = self.num_experts, self.top_k
         ep = _axis_size_or_1(self.expert_axis)
-        assert e % ep == 0, f"num_experts ({e}) not divisible by ep ({ep})"
-        local_e = e // ep
-        ffn = cfg.ffn_hidden_size
-        # per-assignment-pass capacity: each of the top_k passes dispatches
-        # one assignment per token, so per-pass slots are cf*tokens/e and
-        # TOTAL slots per expert are cf*tokens*top_k/e — the GShard
-        # convention for the capacity_factor knob
-        capacity = max(1, int(self.capacity_factor * tokens / e))
+        if ep > 1:
+            assert e % ep == 0, f"num_experts ({e}) not divisible by ep ({ep})"
+            local_e = e // ep
+            first = jax.lax.axis_index(self.expert_axis) * local_e
+        else:
+            local_e = e if self.experts_held is None else self.experts_held
+            first = self.first_expert
+            assert 0 <= first and first + local_e <= e, (first, local_e, e)
+        ffn = self.ffn_hidden_size or cfg.ffn_hidden_size
+        width = ffn * (2 if self.gated else 1)
 
-        gate_w = self.param(
-            "router", nn.initializers.normal(stddev=0.02), (h, e),
-            cfg.params_dtype,
-        )
-        # router math in fp32 (standard MoE stability practice)
-        logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-        probs, gate_vals, expert_idx = router_probs(logits, e, self.top_k)
-        aux = load_balancing_loss(probs, expert_idx, e)
+        def activate(hdn):
+            if not self.gated:
+                return self.activation(hdn)
+            gate, up = jnp.split(hdn, 2, axis=-1)
+            return self.activation(gate) * up
 
-        # per-rank experts: (local_e, h, ffn) / (local_e, ffn, h)
+        with model_scope("moe_route"):
+            gate_w = self.param(
+                "router", nn.initializers.normal(stddev=0.02), (h, e),
+                cfg.params_dtype,
+            )
+            # router math in fp32 (standard MoE stability practice)
+            logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+            if self.router == "sigmoid":
+                bias = self.param("router_bias", nn.initializers.zeros_init(),
+                                  (e,), jnp.float32)
+                _, gate_vals, expert_idx = router_sigmoid(
+                    logits, bias, k, self.norm_topk_prob,
+                    self.routed_scaling_factor)
+                aux = jnp.zeros((), jnp.float32)
+            else:
+                probs, gate_vals, expert_idx = router_probs(logits, e, k)
+                gate_vals = gate_vals * self.routed_scaling_factor
+                aux = load_balancing_loss(probs, expert_idx, e)
+            keep = jnp.ones((tokens, k), bool)
+            if self.capacity_factor is not None:
+                # per-assignment-pass capacity: each of the top_k passes
+                # dispatches one assignment per token, so per-pass slots are
+                # cf*tokens/e and TOTAL slots per expert are cf*tokens*top_k/e
+                # — the GShard convention for the capacity_factor knob
+                capacity = int(self.capacity_factor * tokens / e)
+                if capacity < 1:
+                    raise ValueError(
+                        f"capacity_factor {self.capacity_factor} leaves no "
+                        f"slot an expert at {tokens} tokens over {e} experts")
+                keep = jnp.stack([
+                    _dispatch_indices(expert_idx[:, j], e, capacity) >= 0
+                    for j in range(k)], axis=1)
+            dropped = jnp.sum(~keep)
+
+        # per-rank experts: (local_e, h, width) / (local_e, ffn, h)
         w_in = self.param(
-            "w_in",
-            nn.initializers.lecun_normal(batch_axis=(0,)),
-            (local_e, h, ffn),
-            cfg.params_dtype,
+            "w_in", nn.initializers.lecun_normal(batch_axis=(0,)),
+            (local_e, h, width), cfg.params_dtype,
         )
         w_out = self.param(
-            "w_out",
-            nn.initializers.lecun_normal(batch_axis=(0,)),
-            (local_e, ffn, h),
-            cfg.params_dtype,
+            "w_out", nn.initializers.lecun_normal(batch_axis=(0,)),
+            (local_e, ffn, h), cfg.params_dtype,
         )
 
-        out = jnp.zeros((tokens, h), jnp.float32)
-        for k in range(self.top_k):
-            idx_k = expert_idx[:, k]
-            gate_k = gate_vals[:, k]
-            pos = _dispatch_indices(idx_k, e, capacity)
-            keep = pos >= 0
-            # dispatch: (E, C, h) — scatter each kept token into its slot
-            dispatch = jnp.zeros((e, capacity, h), x.dtype)
-            dispatch = dispatch.at[
-                jnp.where(keep, idx_k, 0),
-                jnp.where(keep, pos, 0),
-            ].add(jnp.where(keep[:, None], x, 0))
+        # the routed part is recomputed in the backward pass: its buffers
+        # are sized for the worst case (every assignment lands here), and
+        # five layers of them would not fit beside the optimizer's state;
+        # what it costs again is a sort, two gathers and the experts' own
+        # arithmetic
+        routed = jax.checkpoint(functools.partial(
+            _routed_experts, k=k, ep=ep, local_e=local_e,
+            expert_axis=self.expert_axis, activate=activate, impl=self.impl))
+        out, load = routed(x, w_in, w_out, gate_vals,
+                           jnp.where(keep, expert_idx, -1), first)
 
-            if ep > 1:
-                # (E, C, h) -> (ep, local_e, C, h); all_to_all swaps the ep
-                # shards so each rank receives ITS experts' slots from all
-                # ranks: result (ep_src, local_e, C, h)
-                d = dispatch.reshape(ep, local_e, capacity, h)
-                d = xlax.all_to_all(
-                    d, self.expert_axis, split_axis=0, concat_axis=0,
-                    tiled=False,
-                )
-            else:
-                d = dispatch.reshape(1, local_e, capacity, h)
-
-            # expert FFN over (src, local_e, C, h)
-            hdn = jnp.einsum(
-                "slch,lhf->slcf", d, w_in.astype(d.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            hdn = self.activation(hdn)
-            y = jnp.einsum(
-                "slcf,lfh->slch", hdn.astype(d.dtype), w_out.astype(d.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(x.dtype)
-
-            if ep > 1:
-                y = xlax.all_to_all(
-                    y, self.expert_axis, split_axis=0, concat_axis=0,
-                    tiled=False,
-                )
-            y = y.reshape(e, capacity, h)
-
-            # combine: gather each token's slot, weight by its gate
-            gathered = y[jnp.where(keep, idx_k, 0), jnp.where(keep, pos, 0)]
-            out = out + jnp.where(
-                keep[:, None], gate_k[:, None] * gathered.astype(jnp.float32), 0.0
-            )
+        if self.shared_experts:
+            with model_scope("moe_shared"):
+                s_ffn = ffn * self.shared_experts
+                s_in = self.param(
+                    "shared_w_in", nn.initializers.lecun_normal(),
+                    (h, s_ffn * (2 if self.gated else 1)), cfg.params_dtype)
+                s_out = self.param(
+                    "shared_w_out", nn.initializers.lecun_normal(),
+                    (s_ffn, h), cfg.params_dtype)
+                hdn = activate(jnp.dot(
+                    x, s_in.astype(x.dtype),
+                    preferred_element_type=jnp.float32).astype(x.dtype))
+                out = out + jnp.dot(hdn, s_out.astype(x.dtype),
+                                    preferred_element_type=jnp.float32)
+        self.sow("intermediates", "moe_chosen", expert_idx)
+        self.sow("intermediates", "moe_load", load)
+        self.sow("intermediates", "moe_dropped", dropped)
         return out.astype(x.dtype), aux

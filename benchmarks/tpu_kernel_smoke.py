@@ -183,12 +183,27 @@ def build_checks():
         g_x = jax.jit(jax.grad(loss("xla"), argnums=(0, 1, 2)))
         return check(name, g_p(q, k_, v), g_x(q, k_, v), 1e-1)
 
+    def mla_bwd(name="flash_attention bwd bf16 causal s=4096 d=192/128"):
+        # latent attention's call (joyai_llm_flash.pretrain): q and k 192
+        # wide, v and the output 128, four heads of it (the XLA side holds
+        # their (s, s) scores whole)
+        ks = [jax.random.fold_in(key, n) for n in (30, 31, 32)]
+        q = jax.random.normal(ks[0], (1, 4, 4096, 192), jnp.bfloat16)
+        k_ = jax.random.normal(ks[1], (1, 4, 4096, 192), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (1, 4, 4096, 128), jnp.bfloat16)
+        loss = lambda impl: lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, causal=True, impl=impl).astype(jnp.float32)))
+        g_p = jax.jit(jax.grad(loss("pallas"), argnums=(0, 1, 2)))
+        g_x = jax.jit(jax.grad(loss("xla"), argnums=(0, 1, 2)))
+        return check(name, g_p(q, k_, v), g_x(q, k_, v), 1e-1)
+
     yield "flash_attention GQA fwd", gqa_fwd
     yield "flash_attention GQA bwd", gqa_bwd
     yield "flash_attention window fwd", window_fwd
     yield "flash_attention kpm fwd", kpm_fwd
     yield "flash_attention kpm bwd", kpm_bwd
     yield "flash_attention bwd bf16 causal s=1024 d=64", gpt2_bwd
+    yield "flash_attention bwd bf16 causal s=4096 d=192/128", mla_bwd
 
     # ---- blockwise long-context + decode-shaped attention (compiled) ----
     # The blockwise path is the single-chip long-context engine

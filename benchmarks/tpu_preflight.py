@@ -11,6 +11,8 @@ is a measurement.
     python benchmarks/tpu_preflight.py              # one chip: trainer + server
     python benchmarks/tpu_preflight.py --chips 4    # + dp2xtp2(+SP), dp4 ZeRO
     python benchmarks/tpu_preflight.py --attention  # the K/V residency edge
+    python benchmarks/tpu_preflight.py --cell joyai_llm_flash.pretrain
+                                                    # a benchmark cell's step
 
 Exit code 0 only when every program compiled. One at a time: libtpu holds a
 machine-wide lock, so two of these cannot run side by side.
@@ -118,7 +120,7 @@ def attention_edge(device):
     sharding = SingleDeviceSharding(device)
 
     def compile_attn(b, h, h_kv, sq, sk, d, dtype, causal, bwd,
-                     kpm=False, window=None):
+                     kpm=False, window=None, d_v=None):
         def sds(shape, dt):
             return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
@@ -132,8 +134,9 @@ def attention_edge(device):
 
         f = jax.grad(loss, argnums=(0, 1, 2)) if bwd else fwd
         k = sds((b, h_kv, sk, d), dtype)
+        v = k if d_v is None else sds((b, h_kv, sk, d_v), dtype)
         return jax.jit(f).lower(
-            sds((b, h, sq, d), dtype), k, k, sds((b, sk), jnp.bool_)
+            sds((b, h, sq, d), dtype), k, v, sds((b, sk), jnp.bool_)
         ).compile()
 
     # the benchmark cell's call: far from the edge, so the tiles are the
@@ -143,6 +146,13 @@ def attention_edge(device):
         f"tiles {A._flash_tiles(1024, 1024, None, A._kv_vmem_bytes(1024, 64, 2))}",
         lambda: compile_attn(8, 16, 16, 1024, 1024, 64, jnp.bfloat16,
                              True, True))
+    # latent attention's call: q and k 192 wide, v and the output 128
+    ok &= attempt(
+        "attention joyai_llm_flash.pretrain (2, 32, 4096, 192/128) bf16 "
+        "causal fwd+bwd, tiles "
+        f"{A._flash_tiles(4096, 4096, None, A._kv_vmem_bytes(4096, 192, 2, 128))}",
+        lambda: compile_attn(2, 32, 32, 4096, 4096, 192, jnp.bfloat16,
+                             True, True, d_v=128))
     for dtype in (jnp.bfloat16, jnp.float32):
         for d in (64, 128, 256):
             per_key = A._kv_vmem_bytes(1, d, jnp.dtype(dtype).itemsize)
@@ -171,13 +181,50 @@ def attention_edge(device):
     return ok
 
 
+def cell_files(name):
+    """(cell, config) of a benchmark cell: its ``perf/workloads/`` file and
+    its configuration's."""
+    import json
+
+    def load(*path):
+        with open(os.path.join(ROOT, *path)) as f:
+            return json.load(f)
+
+    bench = load("BENCHMARK.json")
+    entry = next(w for w in bench["workloads"] if w["name"] == name)
+    cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    return load("perf", "workloads", name + ".json"), load(cfg["file"])
+
+
+def cell_program(cell, config, hlo_out=None):
+    """A benchmark cell's compiled step, built by the cell's own driver
+    (``perf/drivers/<driver>.py:build``) for the chips the cell asks for;
+    ``hlo_out`` keeps the compiled module's text."""
+    from perf import run as perf_run
+
+    driver = perf_run.load_module(ROOT, "drivers", cell["driver"])
+    step = driver.build(cell, config).step
+    if hlo_out:
+        with open(hlo_out, "w") as f:
+            f.write(step.as_text())
+    return step
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
     parser.add_argument("--attention", action="store_true",
                         help="sweep the flash-attention K/V residency edge "
                              "instead of chip_smoke's programs")
+    parser.add_argument("--cell", default=None,
+                        help="compile this benchmark cell's training step "
+                             "(for the chips it asks for) instead")
+    parser.add_argument("--hlo-out", default=None,
+                        help="with --cell: write the compiled step's text")
     args = parser.parse_args()
+    if args.cell:
+        cell, config = cell_files(args.cell)
+        args.chips = cell["chips"]
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
@@ -186,7 +233,10 @@ def main():
           f"compiling for {len(devices)} chip(s)", flush=True)
     pretend_tpu(devices)
 
-    if args.attention:
+    if args.cell:
+        ok = attempt(f"cell {args.cell}, {args.chips} chip(s)",
+                     lambda: cell_program(cell, config, args.hlo_out))
+    elif args.attention:
         ok = attention_edge(devices[0])
     else:
         ok = attempt(f"trainer {len(devices)} chip(s)",

@@ -73,6 +73,29 @@ def parse_args(argv=None):
     p.add_argument("--layers", type=int, default=4)
     p.add_argument("--hidden", type=int, default=256)
     p.add_argument("--heads", type=int, default=8)
+    p.add_argument("--arch-file", default=None,
+                   help="a published config.json naming the model "
+                        "(apex_tpu/models/arch.py; docs/models.md): its "
+                        "widths replace --layers/--hidden/--heads/--vocab")
+    p.add_argument("--layers-kept", type=int, default=None,
+                   help="with --arch-file: the first N layers (this "
+                        "program's pipeline share); default all")
+    p.add_argument("--experts-held", type=int, default=None,
+                   help="with --arch-file: routed experts of each layer "
+                        "held here (this program's expert-parallel share); "
+                        "default all")
+    p.add_argument("--first-expert", type=int, default=0,
+                   help="with --experts-held: the first expert held")
+    p.add_argument("--vocab-rows", type=int, default=None,
+                   help="with --arch-file: rows of the embedding and the "
+                        "head held here; default the published vocabulary")
+    p.add_argument("--mtp-loss-coeff", type=float, default=0.3,
+                   help="weight of the multi-token-prediction loss")
+    p.add_argument("--router-bias-update-speed", type=float, default=0.0,
+                   help="with --arch-file: what each expert's router bias "
+                        "moves by after a step, towards an even load "
+                        "(balancing without an auxiliary loss; DeepSeek-V3 "
+                        "trained with 0.001); 0 holds the bias fixed")
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--sequence-parallel", action=argparse.BooleanOptionalAction,
                    default=True, help="Megatron SP over tp (--no-sequence-parallel to disable)")
@@ -294,9 +317,23 @@ def target_config(args, journal_on: bool):
     compiled step depends on (resilience/replay/targets.py)."""
     from apex_tpu.resilience.replay.targets import GPTTargetConfig
 
+    sizes = dict(vocab=args.vocab, layers=args.layers, hidden=args.hidden,
+                 heads=args.heads)
+    model = None
+    if args.arch_file:
+        import json
+
+        from apex_tpu.models.arch import described_model
+
+        with open(args.arch_file) as f:
+            sizes, model = described_model(
+                json.load(f), layers_kept=args.layers_kept,
+                experts_held=args.experts_held,
+                first_expert=args.first_expert, vocab_rows=args.vocab_rows,
+                mtp_loss_coeff=args.mtp_loss_coeff,
+                router_bias_update_speed=args.router_bias_update_speed)
     return GPTTargetConfig(
-        vocab=args.vocab, seq_len=args.seq_len, layers=args.layers,
-        hidden=args.hidden, heads=args.heads, tp=args.tp,
+        **sizes, model=model, seq_len=args.seq_len, tp=args.tp,
         sequence_parallel=args.sequence_parallel,
         micro_batch=args.micro_batch, global_batch=args.global_batch,
         lr=args.lr, seed=args.seed, zero=args.zero,
@@ -409,7 +446,7 @@ def main(argv=None):
     ddp_compressed = training.ddp_compressed
     print(f"mesh: dp={dp} tp={args.tp} devices={len(jax.devices())}")
 
-    prefix = args.corpus or synthetic_corpus(args.vocab)
+    prefix = args.corpus or synthetic_corpus(tcfg.vocab)
     lm = LMDataset(IndexedTokenDataset(prefix), seq_len=args.seq_len)
 
     recorder = None
@@ -424,7 +461,7 @@ def main(argv=None):
             run_id, "gpt", config=tcfg.to_json(),
             corpus={"prefix": prefix,
                     **({} if args.corpus
-                       else {"synthetic": {"vocab": args.vocab,
+                       else {"synthetic": {"vocab": tcfg.vocab,
                                            "n_tokens": 200_000}})},
             devices=len(jax.devices()), steps=args.steps, **guard_flags,
         )
