@@ -46,6 +46,12 @@ REGISTERED_TAPS = {
         "call (0 whenever moe_capacity_factor is None). The MetricBag's "
         "moe_dropped; the benchmark holds it to 0"
     ),
+    "moe_compact": (
+        "transformer/moe.py MoEMLP: whether this call's rows went through "
+        "the short sorted buffer (bool; False on the worst-case fallback and "
+        "in a layer that holds all its experts). The MetricBag's "
+        "moe_compact_share is its mean over the expert layers and microbatches"
+    ),
 }
 
 __all__ = ["REGISTERED_TAPS"]

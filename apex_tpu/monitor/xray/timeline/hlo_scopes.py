@@ -55,7 +55,12 @@ from apex_tpu.analysis.hlo.parser import (
     HloModule,
     parse_hlo_module,
 )
-from apex_tpu.monitor.goodput.scopes import KERNEL_KEY, KERNELS, STEP_PHASES
+from apex_tpu.monitor.goodput.scopes import (
+    KERNEL_KEY,
+    KERNELS,
+    MODEL_SCOPES,
+    STEP_PHASES,
+)
 
 __all__ = [
     "OpScope",
@@ -85,6 +90,8 @@ _JAX_FRAMES = frozenset({
     "custom_vjp_call_jaxpr", "pjit", "shard_map", "pallas_call",
 })
 _BRANCH = re.compile(r"^branch_\d+_fun$")
+#: a scope opened while a transform traced it: ``transpose(jvp(moe_experts))``
+_WRAPPED = re.compile(r"^(?:\w+\()+(\w+)\)+$")
 _LAYER = re.compile(r"^(.*?_)\d+$")
 
 #: opcodes that occupy no device time of their own and take no scope
@@ -144,7 +151,10 @@ def classify_path(op_name: str) -> Tuple[str, Optional[str], str]:
     ``transpose(<the scope>)``), else ``forward``. The module is what
     lies between the phase and the final primitive once JAX's own frames
     are dropped: transforms (anything with parentheses), control flow,
-    ``pallas_call``, a kernel's name; ``layer_7`` reads ``layer_*``."""
+    ``pallas_call``, a kernel's name; ``layer_7`` reads ``layer_*``. A
+    model scope opened under a transform (a ``custom_vjp`` rule that
+    differentiates its own forward prints ``jvp(moe_experts)`` and
+    ``transpose(jvp(moe_experts))``) is that scope."""
     parts = split_path(op_name)
     phase_at = next(
         (i for i, p in enumerate(parts) if p in STEP_PHASES), None
@@ -159,6 +169,9 @@ def classify_path(op_name: str) -> Tuple[str, Optional[str], str]:
         ) else FORWARD
     module = []
     for p in parts[phase_at + 1:-1]:
+        wrapped = _WRAPPED.match(p)
+        if wrapped and wrapped.group(1) in MODEL_SCOPES:
+            p = wrapped.group(1)
         if ("(" in p or p in _JAX_FRAMES or p in KERNELS
                 or p in STEP_PHASES or _BRANCH.match(p)):
             continue

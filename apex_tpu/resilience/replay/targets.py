@@ -399,6 +399,7 @@ def build_gpt_training(cfg: GPTTargetConfig) -> GPTTraining:
             moe_load_mean="mean",       # rows a held expert took, mean
             moe_load_max_over_mean="mean",  # max / mean a layer, over layers
             moe_dropped="sum",          # assignments the capacity rule cut
+            moe_compact_share="mean",   # calls that took the short buffer
         )
 
     out_specs = (P(), opt_specs, P(), P(), P(), P(), P())
@@ -710,19 +711,22 @@ def _router_bias_steps(chosen, num_experts, speed, micro_batch, psum):
 
 
 def _moe_load_stats(intermediates):
-    """The expert layers' sown ``moe_load`` (rows each held expert took)
-    and ``moe_dropped`` as (rows here a step, largest load, mean load,
-    max / mean averaged over the layers, dropped)."""
+    """The expert layers' sown ``moe_load`` (rows each held expert took),
+    ``moe_dropped`` and ``moe_compact`` as (rows here a step, largest load,
+    mean load, max / mean averaged over the layers, dropped, the share of
+    the layers whose rows went through the short buffer)."""
     import jax.numpy as jnp
 
     loads = [v for _, v in _sown(intermediates, "moe_load")]
     dropped = [v for _, v in _sown(intermediates, "moe_dropped")]
+    compact = [v for _, v in _sown(intermediates, "moe_compact")]
     load = jnp.stack(loads).astype(jnp.float32)       # (layers, held)
     mean = jnp.mean(load, axis=1)
     return jnp.stack([
         jnp.sum(load), jnp.max(load), jnp.mean(load),
         jnp.mean(jnp.max(load, axis=1) / jnp.maximum(mean, 1e-9)),
-        jnp.sum(jnp.stack(dropped)).astype(jnp.float32)])
+        jnp.sum(jnp.stack(dropped)).astype(jnp.float32),
+        jnp.mean(jnp.stack(compact).astype(jnp.float32))])
 
 
 def _described_taps(taps, pmean):
@@ -744,6 +748,7 @@ def _described_taps(taps, pmean):
             moe_load_mean=pmean(jnp.mean(m[:, 2]), "dp"),
             moe_load_max_over_mean=pmean(jnp.mean(m[:, 3]), "dp"),
             moe_dropped=pmean(jnp.sum(m[:, 4]), "dp"),
+            moe_compact_share=pmean(jnp.mean(m[:, 5]), "dp"),
         )
     return out
 

@@ -20,8 +20,15 @@ sorted by expert once, the rows are gathered in that order, the experts
 run as ONE grouped matmul whose work follows the rows present (the Pallas
 ``megablox`` kernels of ``jax.experimental``; an einsum over a one-hot on
 ``impl="xla"``), and each token takes its rows back weighted by its gates.
-The buffer is sized for the worst case (every assignment lands here), so
-nothing is dropped that the capacity rule did not drop.
+The sorted buffer holds ``_ROWS_OVER_EVEN`` times the rows an even router
+would send the experts held here; a call that counts more rows than that
+takes the worst-case buffer instead (every assignment lands here), chosen
+on the device by ``lax.cond``, so nothing is dropped that the capacity rule
+did not drop. A layer that holds all its experts has the worst-case buffer
+alone, and no ``cond``. What stays worst-case either way: the sort over
+every assignment (run once a call, before the choice, and kept for the
+backward pass), the two gathers shaped (tokens, top_k), and the expert
+axis's send buffers.
 
 Which experts a program holds: with ``expert_axis`` each rank of the mesh
 axis holds ``num_experts / ep`` and the layer exchanges rows once each way
@@ -46,6 +53,10 @@ from apex_tpu.transformer.config import TransformerConfig
 
 #: rows of a grouped-matmul tile; the buffer of rows is a multiple of it
 _GMM_ROWS = 512
+#: the sorted buffer's rows over what an even router sends the experts held
+#: (in the JoyAI cell 16,384 rows of 65,536: one expert that every token
+#: chooses, 8,192, and the other fifteen at twice their even share)
+_ROWS_OVER_EVEN = 4
 
 
 def _axis_size_or_1(axis_name: Optional[str]) -> int:
@@ -167,16 +178,12 @@ def _grouped_matmul(x, w, group_sizes, interpret):
     return out[:r] if pad else out
 
 
-def _expert_rows(rows, row_expert, w_in, w_out, activate, impl):
-    """The held experts' FFN over ``rows`` (r, h): row i goes through
-    expert ``row_expert[i]`` (0-based among the ``w_in.shape[0]`` held;
-    -1 = no expert here). ``rows(order, place)`` gathers the rows in the
-    sorted order (``order[i]`` = the caller's row at sorted position i, -1
-    past the last row with an expert; ``place`` its inverse, -1 for rows
-    with no expert), so they are read once, already sorted. Returns the
-    results in sorted order, ``order`` and ``place`` so masked, and the
+def _sort_by_expert(row_expert, held):
+    """Rows sorted by their expert: ``row_expert`` (r,) names each row's,
+    0-based among the ``held`` here (-1 = no expert here). Returns
+    ``order`` (r,: the row at sorted position i; rows with no expert
+    last), ``place`` (r,: its inverse, -1 for rows with no expert) and the
     rows each held expert took."""
-    held = w_in.shape[0]
     r = row_expert.shape[0]
     here = row_expert >= 0
     with model_scope("moe_dispatch"):
@@ -187,10 +194,21 @@ def _expert_rows(rows, row_expert, w_in, w_out, activate, impl):
         load = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32)
-        filled = jnp.arange(r) < jnp.sum(load)
-        order = jnp.where(filled, order, -1)
-        place = jnp.where(here, place, -1)
-        x = rows(order, place)
+        return order, jnp.where(here, place, -1), load
+
+
+def _expert_rows(rows, order, load, w_in, w_out, activate, impl, capacity):
+    """The held experts' FFN over the rows ``_sort_by_expert`` sorted, in a
+    buffer of ``capacity`` rows, which the caller knows to hold every row
+    with an expert here. ``rows(order)`` gathers the rows in the sorted
+    order (``order[i]`` = the caller's row at sorted position i < capacity,
+    -1 past the last row with an expert), so they are read once, already
+    sorted. Returns the results in sorted order, and ``order`` so masked."""
+    held = w_in.shape[0]
+    with model_scope("moe_dispatch"):
+        filled = jnp.arange(capacity) < jnp.sum(load)
+        order = jnp.where(filled, order[:capacity], -1)
+        x = rows(order)
     use_pallas, interpret = resolve_impl(impl)
     with model_scope("moe_experts"):
         if use_pallas:
@@ -201,8 +219,11 @@ def _expert_rows(rows, row_expert, w_in, w_out, activate, impl):
             y = _grouped_matmul(hdn, w_out.astype(x.dtype), load, interpret)
             y = jnp.where(filled[:, None], y, jnp.zeros((), y.dtype))
         else:
-            # plain XLA: every row through every held expert, one kept
-            onehot = (jnp.sort(key)[:, None] == jnp.arange(held)[None, :])
+            # plain XLA: every row through every held expert, one kept (a
+            # sorted position is the expert's whose rows end past it)
+            expert = jnp.searchsorted(
+                jnp.cumsum(load), jnp.arange(capacity), side="right")
+            onehot = (expert[:, None] == jnp.arange(held)[None, :])
             onehot = onehot & filled[:, None]
             hdn = activate(jnp.einsum(
                 "rh,ehf->erf", x, w_in.astype(x.dtype),
@@ -211,41 +232,111 @@ def _expert_rows(rows, row_expert, w_in, w_out, activate, impl):
                            preferred_element_type=jnp.float32)
             y = jnp.einsum("erh,re->rh", y, onehot.astype(y.dtype)
                            ).astype(x.dtype)
-    return y, order, place, load
+    return y, order
 
 
-def _routed_experts(x, w_in, w_out, gate_vals, chosen, first, *, k, ep,
+def _on_counted_rows(part, floats, row_expert, held, expected, remat):
+    """The held experts' part for the rows ``row_expert`` (r,) names (-1 =
+    no expert here): ``part(*floats, order, place, load, capacity)`` ->
+    a float array, through a sorted buffer of ``capacity`` rows
+    (``_sort_by_expert``, ``_expert_rows``). Runs it with the buffer the
+    shapes promise (``_ROWS_OVER_EVEN`` times ``expected``, the rows an
+    even router sends here, in whole tiles) when the rows counted fit it,
+    and with the worst case ``r`` when they do not, so no row is dropped
+    either way. Returns (the array, the rows each held expert took,
+    whether the short buffer ran). The sort is the same for both buffers:
+    it runs once, before the choice, and is kept for the backward pass
+    (two int32 a row) rather than run again.
+
+    ``jax.grad`` through a plain ``cond`` keeps BOTH branches' residuals,
+    the untaken one's as zeros: the worst-case buffers again, allocated and
+    filled. So the pair is one ``custom_vjp`` that keeps its inputs alone,
+    as ``jax.checkpoint`` does, and whose backward is a ``cond`` too: the
+    branch that ran recomputes its own forward and transposes it. Under
+    ``vmap`` a batched count turns both ``cond``s into selects that run
+    both branches: right, and slower than the worst case alone
+    (``build_gpt_training`` runs a described model one microbatch after
+    another, not vmapped).
+
+    Where the promised buffer IS the worst case (all experts held) there
+    is one path and no ``cond``: under ``jax.checkpoint`` when ``remat``,
+    bare when the caller recomputes it already."""
+    r = row_expert.shape[0]
+    capacity = min(r, -(-_ROWS_OVER_EVEN * expected // _GMM_ROWS) * _GMM_ROWS)
+    ints = _sort_by_expert(row_expert, held)
+    load = ints[2]
+    full = functools.partial(part, capacity=r)
+    if capacity == r:
+        out = (jax.checkpoint(full) if remat else full)(*floats, *ints)
+        return out, load, jnp.zeros((), bool)
+    compact = functools.partial(part, capacity=capacity)
+
+    @jax.custom_vjp
+    def either(fits, floats, ints):
+        return jax.lax.cond(fits, compact, full, *floats, *ints)
+
+    def forward(fits, floats, ints):
+        return either(fits, floats, ints), (fits, floats, ints)
+
+    def pull(branch, floats, ints, g):
+        return jax.vjp(lambda *f: branch(*f, *ints), *floats)[1](g)
+
+    def backward(res, g):
+        fits, floats, ints = res
+        return None, jax.lax.cond(
+            fits, functools.partial(pull, compact),
+            functools.partial(pull, full), floats, ints, g), None
+
+    either.defvjp(forward, backward)
+    fits = jnp.sum(load) <= capacity
+    return either(fits, floats, ints), load, fits
+
+
+def _routed_experts(x, w_in, w_out, gate_vals, chosen, first, *, e, k, ep,
                     local_e, expert_axis, activate, impl):
     """What the experts held here add for ``x`` (tokens, h): ``chosen``
-    (tokens, k) names each token's experts (-1 = dropped by the capacity
-    rule), ``gate_vals`` their gates. Without an expert axis (``ep`` 1) the
-    rows of experts ``[first, first + local_e)`` are computed and the rest
-    left out; with one, rows are exchanged once each way. Returns the sum
-    (tokens, h) in fp32 and the rows each held expert took."""
+    (tokens, k) names each token's experts among all ``e`` (-1 = dropped by
+    the capacity rule), ``gate_vals`` their gates. Without an expert axis
+    (``ep`` 1) the rows of experts ``[first, first + local_e)`` are
+    computed and the rest left out; with one, rows are exchanged once each
+    way. Returns the sum (tokens, h) in fp32, the rows each held expert
+    took and whether they went through the short buffer
+    (``_on_counted_rows``)."""
     tokens, h = x.shape
+    expected = -(-tokens * k * local_e * ep // e)
     # one row an assignment, token-major: row a = (token a // k, pass a % k)
     a_expert = chosen.reshape(-1)
-    a_token = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
     if ep == 1:
         local = a_expert - first
         row_expert = jnp.where(
             (a_expert >= 0) & (local >= 0) & (local < local_e), local, -1)
 
-        def rows(order, place):
-            # row i of the sorted buffer is token a_token[order[i]];
-            # token t's rows sit at place[t*k : (t+1)*k]
-            return _take_rows(
-                x, jnp.where(order >= 0, a_token[jnp.maximum(order, 0)], -1),
-                place.reshape(tokens, k))
+        # recomputed in the backward pass with its gates: the (tokens, k,
+        # h) rows the tokens take back would not fit five layers of them
+        # beside the optimizer's state
+        def part(x, w_in, w_out, gate_vals, order, place, load, capacity):
+            def rows(order):
+                # row i of the sorted buffer is token order[i] // k; token
+                # t's rows sit at place[t*k : (t+1)*k]
+                return _take_rows(
+                    x, jnp.where(order >= 0, order // k, -1),
+                    place.reshape(tokens, k))
 
-        y, order, place, load = _expert_rows(
-            rows, row_expert, w_in, w_out, activate, impl)
-        with model_scope("moe_combine"):
-            mine = _take_rows(y, place.reshape(tokens, k), order[:, None])
+            y, order = _expert_rows(
+                rows, order, load, w_in, w_out, activate, impl, capacity)
+            with model_scope("moe_combine"):
+                mine = _take_rows(y, place.reshape(tokens, k), order[:, None])
+                return jnp.sum(mine.astype(jnp.float32)
+                               * gate_vals[..., None], axis=1)
+
+        out, load, compact = _on_counted_rows(
+            part, (x, w_in, w_out, gate_vals), row_expert, local_e, expected,
+            remat=True)
     else:
         # exchange once each way: every rank sends each peer the rows its
         # experts take, in a buffer sized for the worst case (every token
         # sends a peer min(k, local_e) rows)
+        a_token = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
         cap = tokens * min(k, local_e)
         with model_scope("moe_dispatch"):
             dest = jnp.where(a_expert >= 0, a_expert // local_e, ep)
@@ -276,13 +367,20 @@ def _routed_experts(x, w_in, w_out, gate_vals, chosen, first, *, k, ep,
                 send_e, expert_axis, split_axis=0, concat_axis=0,
                 tiled=False).reshape(ep * cap)
 
-        def rows(order, place):
-            return _take_rows(recv_x, order, place[:, None])
+        # no collective in here: the ranks may take different buffers
+        def part(recv_x, w_in, w_out, order, place, load, capacity):
+            def rows(order):
+                return _take_rows(recv_x, order, place[:, None])
 
-        y, order, place, load = _expert_rows(
-            rows, recv_e, w_in, w_out, activate, impl)
+            y, order = _expert_rows(
+                rows, order, load, w_in, w_out, activate, impl, capacity)
+            with model_scope("moe_combine"):
+                return _take_rows(y, place, order[:, None])
+
+        back, load, compact = _on_counted_rows(
+            part, (recv_x, w_in, w_out), recv_e, local_e, expected,
+            remat=False)
         with model_scope("moe_combine"):
-            back = _take_rows(y, place, order[:, None])
             back = xlax.all_to_all(
                 back.reshape(ep, cap, h), expert_axis, split_axis=0,
                 concat_axis=0, tiled=False).reshape(ep * cap, h)
@@ -290,9 +388,9 @@ def _routed_experts(x, w_in, w_out, gate_vals, chosen, first, *, k, ep,
             # flat slot send[p, c] for buffer row (p, c)
             mine = _take_rows(back, sent_at.reshape(tokens, k),
                               send.reshape(-1, 1))
-    with model_scope("moe_combine"):
-        out = jnp.sum(mine.astype(jnp.float32) * gate_vals[..., None], axis=1)
-    return out, load
+            out = jnp.sum(mine.astype(jnp.float32) * gate_vals[..., None],
+                          axis=1)
+    return out, load, compact
 
 
 class MoEMLP(nn.Module):
@@ -303,8 +401,9 @@ class MoEMLP(nn.Module):
     size and each rank owns ``num_experts / ep`` experts; without one the
     layer holds ``experts_held`` experts from ``first_expert`` (default:
     all). Returns (output, aux_loss), and sows ``moe_chosen`` (the experts each
-    token chose), ``moe_load`` (rows each held expert took) and
-    ``moe_dropped`` for whoever collects ``intermediates``.
+    token chose), ``moe_load`` (rows each held expert took), ``moe_dropped``
+    and ``moe_compact`` (1 when the rows went through the short buffer of
+    ``_on_counted_rows``) for whoever collects ``intermediates``.
     """
 
     config: TransformerConfig
@@ -390,16 +489,15 @@ class MoEMLP(nn.Module):
             (local_e, ffn, h), cfg.params_dtype,
         )
 
-        # the routed part is recomputed in the backward pass: its buffers
-        # are sized for the worst case (every assignment lands here), and
-        # five layers of them would not fit beside the optimizer's state;
-        # what it costs again is a sort, two gathers and the experts' own
-        # arithmetic
-        routed = jax.checkpoint(functools.partial(
-            _routed_experts, k=k, ep=ep, local_e=local_e,
-            expert_axis=self.expert_axis, activate=activate, impl=self.impl))
-        out, load = routed(x, w_in, w_out, gate_vals,
-                           jnp.where(keep, expert_idx, -1), first)
+        routed = functools.partial(
+            _routed_experts, e=e, k=k, ep=ep, local_e=local_e,
+            expert_axis=self.expert_axis, activate=activate, impl=self.impl)
+        if ep > 1:
+            # the exchange is recomputed in the backward pass with the
+            # experts' part (which _on_counted_rows recomputes by itself)
+            routed = jax.checkpoint(routed)
+        out, load, compact = routed(x, w_in, w_out, gate_vals,
+                                    jnp.where(keep, expert_idx, -1), first)
 
         if self.shared_experts:
             with model_scope("moe_shared"):
@@ -418,4 +516,5 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_chosen", expert_idx)
         self.sow("intermediates", "moe_load", load)
         self.sow("intermediates", "moe_dropped", dropped)
+        self.sow("intermediates", "moe_compact", compact)
         return out.astype(x.dtype), aux

@@ -160,29 +160,30 @@ def test_latent_attention_matches_the_reference_forward_and_gradients():
 TOK, H, FFN, E, K = 24, 16, 8, 8, 3
 
 
-def moe(held=None, first=0, axis=None, impl="xla", **kw):
+def moe(held=None, first=0, axis=None, impl="xla", experts=E, top_k=K,
+        **kw):
     cfg = TransformerConfig(
         num_layers=1, hidden_size=H, num_attention_heads=2, vocab_size=8,
         max_position_embeddings=8, ffn_hidden_size=FFN,
         compute_dtype=jnp.float32)
     return MoEMLP(
-        config=cfg, num_experts=E, top_k=K, capacity_factor=None,
+        config=cfg, num_experts=experts, top_k=top_k, capacity_factor=None,
         expert_axis=axis, activation=jax.nn.silu, router="sigmoid",
         norm_topk_prob=True, routed_scaling_factor=2.5, gated=True,
         shared_experts=1, experts_held=held, first_expert=first, impl=impl,
         **kw)
 
 
-def moe_params(seed=0, held=E):
+def moe_params(seed=0, held=E, experts=E):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     n = lambda k, *shape: 0.3 * jax.random.normal(k, shape)
-    return {"router": n(ks[0], H, E), "router_bias": n(ks[1], E),
+    return {"router": n(ks[0], H, experts), "router_bias": n(ks[1], experts),
             "w_in": n(ks[2], held, H, 2 * FFN), "w_out": n(ks[3], held, FFN, H),
             "shared_w_in": n(ks[4], H, 2 * FFN),
             "shared_w_out": n(ks[5], FFN, H)}
 
 
-def naive_moe(p, x, first=0, held=E, shared=True):
+def naive_moe(p, x, first=0, held=E, shared=True, top_k=K):
     """One token at a time: choose by s + b, weigh by s, scale, add the
     shared expert."""
     p = {k: np.asarray(v, np.float64) for k, v in p.items()}
@@ -193,7 +194,7 @@ def naive_moe(p, x, first=0, held=E, shared=True):
     out = np.zeros_like(x)
     for t in range(x.shape[0]):
         s = 1 / (1 + np.exp(-(x[t] @ p["router"])))
-        chosen = np.argsort(-(s + p["router_bias"]), kind="stable")[:K]
+        chosen = np.argsort(-(s + p["router_bias"]), kind="stable")[:top_k]
         gates = s[chosen] / s[chosen].sum() * 2.5
         for e, g in zip(chosen, gates):
             if first <= e < first + held:
@@ -272,39 +273,136 @@ def test_all_tokens_on_one_held_expert_loses_none(impl):
     close(y, naive_moe(p, x, first=4, held=2), 1e-4)
 
 
-def test_the_layer_over_a_four_device_expert_axis_is_the_local_layer():
-    p = moe_params(4)
-    tok = 4 * TOK
-    x = jax.random.normal(jax.random.PRNGKey(12), (tok, H))
-    probe = jax.random.normal(jax.random.PRNGKey(13), (tok, H))
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("ep",))
+# 2 of 32 experts held, top-4: an even router sends 128 of the 2048
+# assignments here, the short buffer holds 512, the worst case 2048
+SHARE = dict(held=2, first=6, experts=32, top_k=4)
+SHARE_TOK = 512
+
+
+def _share(seed, crowd):
+    """Parameters and tokens of the 2-of-32 share; ``crowd``: every token
+    chooses held expert 7 (a bias no score can beat), 512 rows and the
+    other's few, which the short buffer cannot hold."""
+    p = moe_params(seed, held=2, experts=32)
+    if crowd:
+        p["router_bias"] = p["router_bias"].at[7].set(10.0)
+    return p, jax.random.normal(jax.random.PRNGKey(seed + 20), (SHARE_TOK, H))
+
+
+@pytest.mark.parametrize("crowd", [False, True])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_short_buffer_and_its_fallback_match_a_per_token_loop(impl, crowd):
+    """The counted rows choose the buffer on the device: the short one when
+    they fit it, the worst case when they do not; neither loses a row, and
+    both are the per-token loop, forward and transposed."""
+    p, x = _share(5, crowd)
+    layer = moe(impl=impl, **SHARE)
+    (y, _), inter = layer.apply({"params": p}, x, mutable=["intermediates"])
+    inter = inter["intermediates"]
+    want_kw = dict(first=6, held=2, top_k=4)
+    close(y, naive_moe(p, x, **want_kw), 1e-4)
+    chosen = np.asarray(inter["moe_chosen"][0])
+    rows = int(((chosen >= 6) & (chosen < 8)).sum())
+    assert (rows > 512) == crowd and rows < SHARE_TOK * 4
+    assert bool(inter["moe_compact"][0]) == (not crowd)
+    assert int(inter["moe_dropped"][0]) == 0
+    assert int(np.sum(inter["moe_load"][0])) == rows
+    # gradients: against finite differences of the loop, through x and
+    # through one entry each of the experts' and the router's weights
+    probe = np.asarray(
+        jax.random.normal(jax.random.PRNGKey(9), (SHARE_TOK, H)))
+    g, gx = jax.grad(lambda p, x: jnp.sum(
+        layer.apply({"params": p}, x)[0] * probe), (0, 1))(p, x)
+    assert float(jnp.max(jnp.abs(g["router_bias"]))) == 0.0
+    t = int(np.argmax((chosen == 7).any(-1)))   # a token with a row here
+    eps = 1e-4
+
+    def loop(p, x):
+        return np.sum(naive_moe(p, x, **want_kw) * probe)
+
+    def nudged(name, at, by):
+        if name == "x":
+            return p, np.asarray(x, np.float64) + by * _one_hot(x.shape, at)
+        return dict(p, **{name: np.asarray(p[name], np.float64)
+                          + by * _one_hot(p[name].shape, at)}), x
+
+    for name, at in (("x", (t, 0)), ("x", (t, H - 1)), ("w_in", (1, 3, 5)),
+                     ("w_out", (1, 2, 7)), ("router", (4, 7))):
+        num = (loop(*nudged(name, at, eps)) - loop(*nudged(name, at, -eps))
+               ) / (2 * eps)
+        got = float((gx if name == "x" else g[name])[at])
+        assert abs(num) > 1e-3, (name, at)
+        assert abs(got - num) < 2e-3 * max(1.0, abs(num)), (name, at)
+
+
+def _one_hot(shape, at):
+    d = np.zeros(shape)
+    d[at] = 1.0
+    return d
+
+
+def test_the_branch_exists_only_where_a_share_is_held():
+    """A layer that holds all its experts has the worst-case buffer alone:
+    no conditional in its program, forward or backward."""
+    def lowered(p, x, **kw):
+        return jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
+            moe(**kw).apply({"params": p}, x)[0]))).lower(p, x).as_text()
+
+    branch = ("stablehlo.case", "stablehlo.if", "conditional")
+    whole = lowered(moe_params(6, held=32, experts=32),
+                    jnp.zeros((SHARE_TOK, H)), experts=32, top_k=4)
+    assert not any(b in whole for b in branch)
+    share = lowered(*_share(6, False), **SHARE)  # one forward, one backward
+    assert share.count("stablehlo.case") + share.count("stablehlo.if") == 2
+
+
+@pytest.mark.parametrize("devices, experts, top_k, tok, crowd", [
+    (4, 8, 3, 24, False),   # the received rows ARE four times the even share
+    (8, 16, 2, 64, False),  # 1024 rows can arrive, the short buffer holds 512
+    (8, 16, 2, 64, True),   # every token chooses expert 0: rank 0 alone
+])                          # takes the worst case, and no collective waits
+def test_the_layer_over_an_expert_axis_is_the_local_layer(
+        devices, experts, top_k, tok, crowd):
+    layer = functools.partial(moe, experts=experts, top_k=top_k)
+    p = moe_params(4, held=experts, experts=experts)
+    if crowd:
+        p["router_bias"] = p["router_bias"].at[0].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(12), (devices * tok, H))
+    probe = jax.random.normal(jax.random.PRNGKey(13), (devices * tok, H))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:devices]), ("ep",))
     sharded = dict.fromkeys(p, P())
     sharded.update(w_in=P("ep"), w_out=P("ep"))
 
     @jax.jit
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(sharded, P("ep"), P("ep")),
-        out_specs=(P("ep"), P()), check_vma=False)
+        out_specs=(P("ep"), P(), P("ep")), check_vma=False)
     def over_axis(p, x, probe):
         def loss(p, x):
-            y = moe(axis="ep").apply({"params": p}, x)[0]
+            (y, _), inter = layer(axis="ep").apply(
+                {"params": p}, x, mutable=["intermediates"])
             # this rank's tokens' part of the loss: the exchange's own
             # transpose carries the other ranks' cotangents to the experts
-            return jnp.sum(y * probe), y
+            return jnp.sum(y * probe), (
+                y, inter["intermediates"]["moe_compact"][0])
 
-        (_, y), g = jax.value_and_grad(loss, has_aux=True)(p, x)
+        (_, (y, compact)), g = jax.value_and_grad(loss, has_aux=True)(p, x)
         # replicated leaves: every rank holds a partial sum
         g = {k: v if k in ("w_in", "w_out") else jax.lax.psum(v, "ep")
              for k, v in g.items()}
         return y, {k: jax.lax.all_gather(v, "ep", tiled=True)
-                   if k in ("w_in", "w_out") else v for k, v in g.items()}
+                   if k in ("w_in", "w_out") else v
+                   for k, v in g.items()}, compact[None]
 
-    y, g = over_axis(p, x, probe)
+    y, g, compact = over_axis(p, x, probe)
     want, want_g = jax.value_and_grad(
-        lambda p: jnp.sum(moe().apply({"params": p}, x)[0] * probe))(p)
-    close(y, moe().apply({"params": p}, x)[0], 1e-4)
+        lambda p: jnp.sum(layer().apply({"params": p}, x)[0] * probe))(p)
+    close(y, layer().apply({"params": p}, x)[0], 1e-4)
     for name in p:
         close(g[name], want_g[name], 1e-4)
+    short = devices == 8
+    assert list(compact) == [short and not (crowd and rank == 0)
+                             for rank in range(devices)]
 
 
 # -- multi-token prediction and the whole model -------------------------------
@@ -390,6 +488,8 @@ def test_the_model_trains_through_build_gpt_training_like_the_reference():
     assert abs(bag["loss_main"] - float(main)) < 2e-3 * float(main)
     assert abs(bag["loss_mtp"] - float(second)) < 2e-3 * float(second)
     assert bag["moe_dropped"] == 0 and bag["moe_rows_here"] > 0
+    # 4 of 16 experts held: four times their even share is every row
+    assert bag["moe_compact_share"] == 0.0
     assert 1.0 <= bag["moe_load_max_over_mean"] < 4.0
     m1 = joyai_tree.stacked(out[1].exp_avg, 3)
     want, got = ref.leaf_norms(g), ref.leaf_norms(
@@ -402,6 +502,32 @@ def test_the_model_trains_through_build_gpt_training_like_the_reference():
     moved = joyai_tree.stacked(out[0], 3)
     assert float(jnp.max(jnp.abs(moved["router_b"] - w["router_b"]))) == 0.0
     assert float(jnp.max(jnp.abs(moved["router"] - w["router"]))) > 0.0
+
+
+def test_the_gpt2_step_lowers_to_what_it_was_before_the_described_model():
+    """What the described model brought (PR 27) and how its expert layer
+    sizes its buffers (PR 28) leave the GPT-2-shaped step as it was: the
+    StableHLO text of the tiny step, hashed on the tree before each. A
+    change that means to move GPT-2's step moves this hash with it."""
+    import hashlib
+
+    from apex_tpu.parallel import parallel_state
+    from apex_tpu.resilience.replay.targets import (
+        GPTTargetConfig, build_gpt_training)
+
+    try:
+        tr = build_gpt_training(GPTTargetConfig(
+            vocab=128, layers=2, hidden=64, heads=4, seq_len=SEQ,
+            micro_batch=1, global_batch=2, max_devices=1))
+        state, bag = jax.eval_shape(tr.init_state), jax.eval_shape(tr.init_bag)
+        scalar = jax.ShapeDtypeStruct((), jnp.float32)
+        text = tr.train_step.lower(
+            *state, bag, tr.batch_struct(), tr.batch_struct(), scalar,
+            scalar).as_text()
+    finally:
+        parallel_state.destroy_model_parallel()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "265319a6949302f9d066fbe2bd96ea59927ac80c1dbe00d2c54b51558f8dab00")
 
 
 @pytest.mark.parametrize("devices", [1, 2])
@@ -503,3 +629,11 @@ def test_model_scopes_are_closed_and_the_reader_shows_them():
         "checkpoint/rematted_computation/moe_dispatch/gather") == (
             "forward_backward", "forward",
             "transformer/layer_*/mlp/moe_dispatch")
+    # a rule that differentiates its own forward (the expert layer's two
+    # buffers) opens its scopes under the transforms
+    assert classify_path(
+        "jit(train_step)/forward_backward/transpose(forward_backward)/"
+        "jvp(GPTModel)/transformer/layer_2/mlp/cond/branch_1_fun/"
+        "transpose(jvp(moe_experts))/mul") == (
+            "forward_backward", "backward",
+            "transformer/layer_*/mlp/moe_experts")
