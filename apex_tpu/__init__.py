@@ -20,6 +20,10 @@ Subpackage map (reference parity noted per module):
 - ``apex_tpu.contrib``      — contrib zoo parity (ref: apex/contrib)
 - ``apex_tpu.models``       — flagship models (GPT, BERT, ResNet) used by the
                               examples / benchmarks (ref: apex/examples, testing/standalone_*)
+- ``apex_tpu.training``     — the GPT training step, built one way
+                              (``build_gpt_training``): what the benchmark's
+                              cells, ``chip_smoke.py``, the GPT example and
+                              the replayer all run
 - ``apex_tpu.resilience``   — training resilience: anomaly sentinel, in-memory
                               rollback, checkpoint integrity manifests, fault
                               injection (no reference equivalent; the recovery

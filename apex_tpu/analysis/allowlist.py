@@ -509,9 +509,9 @@ _LINT = [
     ),
     AllowlistEntry(
         rule="lint.jit-donate",
-        match="apex_tpu/resilience/replay/targets.py",
+        match="apex_tpu/training/gpt_step.py",
         reason=(
-            "audited entrypoint: the GPT example's train_step is now "
+            "audited entrypoint: the GPT example's train_step is "
             "BUILT here (the one shared home the replayer rebuilds "
             "bit-identical steps from); its donation is verified by the "
             "donation auditor (--audit-donation and the example test)"

@@ -7,9 +7,8 @@ TPU mapping:
 - ``FusedAdamSWA`` is a full port (``apex_tpu.optimizers.fused_adam_swa``).
 - ``LayerNormSmallShapeOptImpl`` and the small-MHA tier map onto the
   generic Pallas/XLA kernels; whether those need a small-shape-tuned path
-  is a MEASURED question — ``benchmarks/bench_small_shapes.py`` runs the
-  openfold evoformer shapes (LN hidden 64/128, MHA seq<=256 head_dim
-  8/16); the decision is open until they have run on the chip.
+  at the openfold evoformer shapes (LN hidden 64/128, MHA seq<=256 head_dim
+  8/16) is not measured on the chip: no benchmark cell runs such shapes.
 """
 
 from apex_tpu.normalization import FusedLayerNorm as LayerNormSmallShapeOptImpl

@@ -3,7 +3,7 @@
 ``examples/gpt/pretrain_gpt.py --arch-file <config.json>`` names a model by
 its public ``config.json`` (Hugging Face keys). This module reads the
 families the library can train and returns what
-``resilience.replay.targets.GPTTargetConfig`` carries: the five integers
+``apex_tpu.training.GPTTargetConfig`` carries: the five integers
 every model has and the ``model`` description (``TransformerConfig``
 fields).
 

@@ -15,8 +15,7 @@ profiling subsystem (PAPERS.md). Four cooperating pieces:
   TensorBoard-if-importable, in-memory. ``Timers.write``, the resilience
   anomaly log, and the examples all emit through it.
 - ``flops``    — analytic model-FLOPs counters for the GPT/BERT testing
-  models and the MFU / tokens-per-second arithmetic, built on the
-  slope-based timing primitives in utils/benchmarking.py.
+  models and the MFU / tokens-per-second arithmetic.
 - ``watchdog`` — :class:`StallWatchdog` (heartbeat thread flagging a step
   that exceeds its deadline; complements the SIGTERM-driven resilience
   path, which only helps when the cluster TELLS us something died — its

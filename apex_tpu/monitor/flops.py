@@ -8,10 +8,10 @@ for recomputation — activation checkpointing re-spends hardware FLOPs
 without doing more model math, so MFU honestly drops when remat is on.
 
 The per-second numerator must come from a clock that waits for the device
-(a barrier-synced interval timer, or the slope-based timing primitives in
-utils/benchmarking.py): a wall clock around an un-awaited dispatch measures
-the enqueue, and an MFU computed from it is fiction. ``chip_smoke.py``'s
-clock phase checks that ``block_until_ready`` waits on the machine at hand.
+(a barrier-synced interval timer): a wall clock around an un-awaited
+dispatch measures the enqueue, and an MFU computed from it is fiction.
+``chip_smoke.py``'s clock phase checks that ``block_until_ready`` waits on
+the machine at hand.
 
 Counters are exact closed forms over TransformerConfig so tests can check
 them against hand-counted tiny configs digit for digit.

@@ -6,7 +6,7 @@ profiler capture speaks the program's vocabulary instead of XLA's
 (``fusion.895``, ``copy.12``):
 
 - :data:`STEP_PHASES` — the parts of ``train_step``
-  (``resilience/replay/targets.py``), opened with :func:`step_phase`, a
+  (``apex_tpu/training/gpt_step.py``), opened with :func:`step_phase`, a
   ``jax.named_scope``. The scope lands in every traced op's ``op_name``
   path (``jit(train_step)/.../forward_backward/...``), which the compiled
   HLO keeps in each instruction's ``metadata``. JAX itself marks the

@@ -25,7 +25,7 @@ REGISTERED_TAPS = {
         "layer's output hidden states — the per-layer activation-scale "
         "series that makes divergence onsets attributable to a depth. "
         "Consumed per-step by the replay flight recorder "
-        "(resilience/replay/targets.py stacks the sows into a (layers,) "
+        "(training/gpt_step.py stacks the sows into a (layers,) "
         "vector, cross-rank-aggregated) so the divergence bisector can "
         "localize a corruption to the first divergent layer"
     ),
@@ -37,7 +37,7 @@ REGISTERED_TAPS = {
     ),
     "moe_load": (
         "transformer/moe.py MoEMLP: rows each HELD expert took this call, "
-        "(experts held,) int32. resilience/replay/targets.py folds the "
+        "(experts held,) int32. training/gpt_step.py folds the "
         "layers' loads into the step's MetricBag (moe_rows_here, "
         "moe_load_max, moe_load_mean, moe_load_max_over_mean)"
     ),

@@ -10,7 +10,7 @@ keeps the ``op_name`` path it was traced under —
         transformer/layer_3/mlp/dense_h_to_4h/dot_general
 
 — the step's phase (``goodput.scopes.STEP_PHASES``, a ``jax.named_scope``
-in ``resilience/replay/targets.py``), JAX's own ``transpose(...)`` on
+in ``apex_tpu/training/gpt_step.py``), JAX's own ``transpose(...)`` on
 every op of the backward pass, the flax module path, the primitive. And a
 Pallas kernel's custom-call carries its registered name in
 ``kernel_metadata`` (``goodput.scopes.KERNELS``). :func:`scope_map` reads

@@ -10,10 +10,10 @@ reference's per-chunk remainder handling.
 
 The jnp twin (`_adam_flat_ref`) is bit-identical math used for the
 impl="xla" path and CPU tests; `fused_adam(fuse="flat")` in fused_adam.py
-plugs either into the optax interface. benchmarks/bench_optimizers.py
-compares flat with tree; on a CPU tree Adam wins (flatten round-trip
-overhead) and flat l2norm wins 1.7x on already-flat buffers, which is why
-the ZeRO optimizers use it. Neither is measured on the chip.
+plugs either into the optax interface. Tree Adam is the default (no
+flatten round-trip); the flat l2norm serves buffers that are flat already,
+which is why the ZeRO optimizers use it. Neither comparison is measured on
+the chip.
 """
 
 import functools
@@ -175,9 +175,9 @@ def sumsq_flat(x_flat, impl: str = "auto"):
 def l2norm_flat(x_flat, impl: str = "auto"):
     """Global L2 norm of a flat buffer (padding zeros contribute 0).
 
-    1.7x faster than the tree-based ``multi_tensor_l2norm`` on already-flat
-    buffers on CPU/XLA (bench_optimizers.py) — the flat path is the
-    default wherever the data already lives in one buffer (ZeRO shards in
-    distributed_fused_lamb; fused_adam's flat engine).
+    The flat path is the default wherever the data already lives in one
+    buffer (ZeRO shards in distributed_fused_lamb; fused_adam's flat
+    engine); against the tree-based ``multi_tensor_l2norm`` it is not
+    measured on the chip.
     """
     return jnp.sqrt(sumsq_flat(x_flat, impl=impl))

@@ -4,11 +4,9 @@ Reference parity: apex.contrib.clip_grad.clip_grad_norm_
 (contrib/clip_grad/clip_grad.py:16) — global-norm clip using
 multi_tensor_l2norm + multi_tensor_scale.
 
-Engine choice (benchmarks/bench_optimizers.py, CPU runs only — not
-measured on the chip): the tree-based norm stays because the
-input here is a pytree — the flat reduction only wins when the data already
-lives in one buffer (flatten round-trips cost more than they save; see the
-adam tree-vs-flat row). ZeRO optimizers, whose shards ARE flat, use
+Engine choice (not measured on the chip): the tree-based norm stays because
+the input here is a pytree — a flat reduction would first copy every leaf
+into one buffer. ZeRO optimizers, whose shards ARE flat, use
 ``_fused_kernels.sumsq_flat`` instead.
 """
 

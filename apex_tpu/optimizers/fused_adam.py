@@ -34,9 +34,9 @@ def fused_adam(
 
     ``fuse`` selects the update engine:
     - ``"tree"``: per-leaf tree_map math, fused by XLA inside the caller's
-      jit. The default: on a CPU it runs 1.6x faster than flat (the
-      flatten/unflatten round-trip dominates; bench_optimizers.py). The
-      compiled-Mosaic comparison on the chip is not measured;
+      jit. The default, and what every benchmark cell runs: it has no
+      flatten/unflatten round-trip. The comparison with ``"flat"`` on the
+      chip is not measured;
     - ``"flat"``: the reference's multi_tensor design — moments live in one
       CHUNK_SIZE-padded fp32 buffer and a single Pallas kernel
       (``_fused_kernels.adam_flat``) updates everything per step.

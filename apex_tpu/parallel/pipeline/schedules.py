@@ -260,10 +260,8 @@ def pipeline_forward_interleaved(
         )
     def chunk_fn(chunks, v, x):
         # the chunk gather lives INSIDE the rematerialized body: saved as a
-        # residual it would cost one full chunk's params PER TICK (133 MiB
-        # vs 2 MiB of compiled temporaries at M=128 on the toy config of
-        # benchmarks/bench_pipeline_memory.py); rematerialized it costs
-        # nothing extra
+        # residual it would cost one full chunk's params PER TICK;
+        # rematerialized it costs nothing extra
         pv = jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_index_in_dim(a, v, 0, keepdims=False),
             chunks,

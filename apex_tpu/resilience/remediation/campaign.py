@@ -132,7 +132,7 @@ def random_sequence(seed: int, steps: int = 8,
 #: anchors, sentinel, escalation, elastic reshard) is the production
 #: code path. global_batch=8 divides every dp in {8, 4, 2, 1}.
 def campaign_config(**overrides):
-    from apex_tpu.resilience.replay.targets import GPTTargetConfig
+    from apex_tpu.training import GPTTargetConfig
 
     base = dict(
         vocab=64, seq_len=16, layers=2, hidden=32, heads=4, tp=1,
@@ -156,9 +156,7 @@ class TrainingCache:
     def get(self, device_count: int):
         """(cfg, training) for ``device_count`` devices."""
         if device_count not in self._built:
-            from apex_tpu.resilience.replay.targets import (
-                build_gpt_training,
-            )
+            from apex_tpu.training import build_gpt_training
 
             cfg = dataclasses.replace(
                 self.base_cfg, max_devices=device_count

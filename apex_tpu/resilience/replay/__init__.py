@@ -15,7 +15,7 @@ jsonl. Three pieces (docs/resilience.md "Replay & forensics"):
   state fingerprint. jax-free.
 - ``replayer`` — checkpoint-anchored re-execution: rebuild the EXACT
   step from the journal header's target config
-  (``targets.build_gpt_training`` — the same builder the GPT example
+  (``apex_tpu.training.build_gpt_training`` — the same builder the GPT example
   trains through), restore a verified anchor, re-run the journaled
   segment, compare fingerprints bitwise on a matching platform
   (tolerance-banded otherwise); ``determinism_guard`` is the one home
@@ -74,8 +74,8 @@ _LAZY = {
     "ReplayReport": "apex_tpu.resilience.replay.replayer",
     "bisect_divergence": "apex_tpu.resilience.replay.bisect",
     "format_divergence": "apex_tpu.resilience.replay.bisect",
-    "GPTTargetConfig": "apex_tpu.resilience.replay.targets",
-    "build_gpt_training": "apex_tpu.resilience.replay.targets",
+    "GPTTargetConfig": "apex_tpu.training",
+    "build_gpt_training": "apex_tpu.training",
     "synthetic_corpus": "apex_tpu.resilience.replay.targets",
 }
 

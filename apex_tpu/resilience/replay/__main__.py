@@ -132,9 +132,8 @@ def selftest(directory=None) -> int:
     from apex_tpu.resilience.replay.replayer import (
         build_context, compare_journals, determinism_guard, replay_segment,
     )
-    from apex_tpu.resilience.replay.targets import (
-        GPTTargetConfig, build_gpt_training, synthetic_corpus,
-    )
+    from apex_tpu.resilience.replay.targets import synthetic_corpus
+    from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
     directory = directory or tempfile.mkdtemp(prefix="apex_tpu_replay_")
     failures = []

@@ -9,9 +9,9 @@ recorded — bitwise on a matching platform, tolerance-banded otherwise.
 What "bitwise" rests on, in order:
 
 1. **the same computation** — the step is rebuilt from the journal
-   header's :class:`~apex_tpu.resilience.replay.targets.GPTTargetConfig`
+   header's :class:`~apex_tpu.training.GPTTargetConfig`
    through the SAME builder the recording run used
-   (``targets.build_gpt_training``), so recorder and replayer compile
+   (``apex_tpu.training.build_gpt_training``), so recorder and replayer compile
    identical programs;
 2. **the same numerics flags** — :func:`determinism_guard` pins
    ``jax_default_matmul_precision`` and ``jax_enable_x64`` to the
@@ -54,11 +54,8 @@ import numpy as np
 
 from apex_tpu.monitor.goodput.spans import span as _goodput_span
 from apex_tpu.resilience.replay.journal import Journal, batch_crc
-from apex_tpu.resilience.replay.targets import (
-    GPTTargetConfig,
-    build_gpt_training,
-    synthetic_corpus,
-)
+from apex_tpu.resilience.replay.targets import synthetic_corpus
+from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
 logger = logging.getLogger("apex_tpu.resilience.replay")
 

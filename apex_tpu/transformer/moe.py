@@ -255,8 +255,8 @@ def _on_counted_rows(part, floats, row_expert, held, expected, remat):
     branch that ran recomputes its own forward and transposes it. Under
     ``vmap`` a batched count turns both ``cond``s into selects that run
     both branches: right, and slower than the worst case alone
-    (``build_gpt_training`` runs a described model one microbatch after
-    another, not vmapped).
+    (``training/gpt_step.py`` runs a model with expert layers one
+    microbatch after another, not vmapped).
 
     Where the promised buffer IS the worst case (all experts held) there
     is one path and no ``cond``: under ``jax.checkpoint`` when ``remat``,

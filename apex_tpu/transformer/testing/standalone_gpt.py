@@ -212,8 +212,7 @@ def run_gpt(args=None, log=print):
                 router.metrics(i, loss=float(l))
         # the whole run is ONE jitted scan, so per-step device time is not
         # separable here; the throughput record is honest about covering
-        # compile + dispatch + all steps (slope-based per-step
-        # timing lives in utils/benchmarking.py)
+        # compile + dispatch + all steps
         # num_micro may be rounded UP to a pp multiple above — count the
         # tokens the scan actually processed, not the nominal global batch
         tokens_per_step = num_micro * mb * dp * seq

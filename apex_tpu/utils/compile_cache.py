@@ -7,8 +7,7 @@ where the machine's owner put it. Otherwise the cache is
 location and nothing else: the directory is part of what a cached program
 is found by, so a temporary name, a pid or a time would never hit.
 
-Entry points (``chip_smoke.py``, ``bench.py``, the GPT and serving
-examples) call :func:`enable_compile_cache` before their first compile.
+Entry points (``chip_smoke.py``, the GPT and serving examples) call :func:`enable_compile_cache` before their first compile.
 Libraries and tests do not: a test process that enabled it would write
 every later compile to disk.
 """

@@ -221,7 +221,7 @@ def lower_train_step(gpt, argv):
     object with no chip attached."""
     import jax
 
-    from apex_tpu.resilience.replay.targets import build_gpt_training
+    from apex_tpu.training import build_gpt_training
 
     args = gpt.parse_args(argv)
     training = build_gpt_training(gpt.target_config(args, journal_on=False))

@@ -30,7 +30,7 @@ command line:
 Replay & forensics (apex_tpu.resilience.replay, docs/resilience.md
 "Replay & forensics"): with ``--save`` the run journals by default — the
 training step itself is built by the ONE shared builder
-(``resilience.replay.targets.build_gpt_training``, recorded in the
+(``apex_tpu.training.build_gpt_training``, recorded in the
 journal header), every step's batch ids/crc + chaos arms + lr_scale +
 loss/verdict/layer_rms fingerprints land as ``kind="journal"`` records
 plus the ``<save>/replay-journal.jsonl`` sidecar, and every checkpoint
@@ -314,8 +314,8 @@ def parse_args(argv=None):
 
 def target_config(args, journal_on: bool):
     """The shared builder's recipe for these arguments — everything the
-    compiled step depends on (resilience/replay/targets.py)."""
-    from apex_tpu.resilience.replay.targets import GPTTargetConfig
+    compiled step depends on (apex_tpu/training/gpt_step.py)."""
+    from apex_tpu.training import GPTTargetConfig
 
     sizes = dict(vocab=args.vocab, layers=args.layers, hidden=args.hidden,
                  heads=args.heads)
@@ -367,9 +367,8 @@ def main(argv=None):
         FlightRecorder, batch_crc, journal_path,
     )
     from apex_tpu.resilience.replay.replayer import determinism_guard
-    from apex_tpu.resilience.replay.targets import (
-        build_gpt_training, synthetic_corpus,
-    )
+    from apex_tpu.resilience.replay.targets import synthetic_corpus
+    from apex_tpu.training import build_gpt_training
 
     # host half of the telemetry, FIRST: one router, every producer
     # (metric bag, timers, anomaly stream, goodput spans) emits the same
@@ -436,7 +435,7 @@ def main(argv=None):
                    if journal_on else {})
 
     # the training step itself comes from the ONE shared builder the
-    # replayer also uses (resilience/replay/targets.py): identical
+    # replayer also uses (apex_tpu/training/gpt_step.py): identical
     # compiled computation by construction, not by code duplication
     tcfg = target_config(args, journal_on)
     training = build_gpt_training(tcfg)
@@ -467,9 +466,9 @@ def main(argv=None):
         )
 
     # model/optimizer/scaler/sentinel and the donated train_step all come
-    # from the shared builder above (resilience/replay/targets.py — the
-    # --zero / --compression / sentinel semantics live there now, next to
-    # the replayer that must rebuild them identically)
+    # from the shared builder above (apex_tpu/training/gpt_step.py — the
+    # --zero / --compression / sentinel semantics live there, where the
+    # replayer rebuilds them identically)
     params, opt_state, scaler_state, sent_state = training.init_state()
     bag = training.init_bag()
 
@@ -940,7 +939,7 @@ def main(argv=None):
                     jnp.asarray(lr_scale_now, jnp.float32),
                 )
                 # journaling mode appends the per-layer rms vector to the
-                # step outputs (targets.build_gpt_training)
+                # step outputs (training.build_gpt_training)
                 if journal_on:
                     (params, opt_state, scaler_state, sent_state, bag,
                      loss, verdict, layer_rms) = out

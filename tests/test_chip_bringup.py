@@ -8,7 +8,7 @@ that path a CPU can still pin:
   block-shape rules of the TPU lowering fire here, long before Mosaic;
 - the flash-attention dispatch at its K/V residency edge;
 - no fallback that hides the device: ``on_tpu()`` raises when the backend
-  does, ``bench.py`` and the kernel smoke fail without a chip;
+  does, the kernel smoke fails without a chip;
 - the compile cache is placed from outside;
 - ``chip_smoke.py``'s device gate fails here, and its trainer and server
   phases pass at toy width.
@@ -235,11 +235,6 @@ class TestNoHiddenFallback:
         # ... and off one, only an explicit impl="pallas" interprets
         assert _dispatch.resolve_impl("auto") == (False, False)
         assert _dispatch.resolve_impl("pallas") == (True, True)
-
-    def test_bench_fails_without_a_chip(self, no_disk_cache, capsys):
-        bench = _load("bench.py", "_bench_under_test")
-        assert bench.main() == 1
-        assert capsys.readouterr().out == ""  # no record of any kind
 
     def test_kernel_smoke_fails_without_a_chip(self, capsys):
         smoke = _load("benchmarks/tpu_kernel_smoke.py", "_kernel_smoke")

@@ -461,8 +461,7 @@ def test_the_model_matches_the_reference_leaf_for_leaf(impl):
 
 def test_the_model_trains_through_build_gpt_training_like_the_reference():
     from apex_tpu.monitor.metrics import read_bag
-    from apex_tpu.resilience.replay.targets import (
-        GPTTargetConfig, build_gpt_training)
+    from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
     sizes, model = described_model(ARCH, layers_kept=3, experts_held=4,
                                    first_expert=4, vocab_rows=128)
@@ -504,40 +503,13 @@ def test_the_model_trains_through_build_gpt_training_like_the_reference():
     assert float(jnp.max(jnp.abs(moved["router"] - w["router"]))) > 0.0
 
 
-def test_the_gpt2_step_lowers_to_what_it_was_before_the_described_model():
-    """What the described model brought (PR 27) and how its expert layer
-    sizes its buffers (PR 28) leave the GPT-2-shaped step as it was: the
-    StableHLO text of the tiny step, hashed on the tree before each. A
-    change that means to move GPT-2's step moves this hash with it."""
-    import hashlib
-
-    from apex_tpu.parallel import parallel_state
-    from apex_tpu.resilience.replay.targets import (
-        GPTTargetConfig, build_gpt_training)
-
-    try:
-        tr = build_gpt_training(GPTTargetConfig(
-            vocab=128, layers=2, hidden=64, heads=4, seq_len=SEQ,
-            micro_batch=1, global_batch=2, max_devices=1))
-        state, bag = jax.eval_shape(tr.init_state), jax.eval_shape(tr.init_bag)
-        scalar = jax.ShapeDtypeStruct((), jnp.float32)
-        text = tr.train_step.lower(
-            *state, bag, tr.batch_struct(), tr.batch_struct(), scalar,
-            scalar).as_text()
-    finally:
-        parallel_state.destroy_model_parallel()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "265319a6949302f9d066fbe2bd96ea59927ac80c1dbe00d2c54b51558f8dab00")
-
-
 @pytest.mark.parametrize("devices", [1, 2])
 def test_the_step_hands_out_its_choices_and_moves_the_bias_by_them(devices):
     """``collect_expert_choices``: the compiled step's own routing, which is
     the reference's but for near-ties; ``moe_bias_update_speed``: the bias
     goes up where an expert took fewer assignments than the mean over the
     whole batch (all chips'), down where more, and by nothing else."""
-    from apex_tpu.resilience.replay.targets import (
-        GPTTargetConfig, build_gpt_training)
+    from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
     sizes, model = described_model(
         ARCH, layers_kept=3, experts_held=4, first_expert=4, vocab_rows=128,

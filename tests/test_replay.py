@@ -410,8 +410,8 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from apex_tpu.data import IndexedTokenDataset, LMDataset
 from apex_tpu.resilience.replay.replayer import determinism_guard
-from apex_tpu.resilience.replay.targets import (
-    GPTTargetConfig, build_gpt_training, synthetic_corpus)
+from apex_tpu.resilience.replay.targets import synthetic_corpus
+from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
 determinism_guard()
 cfg = GPTTargetConfig(vocab=64, seq_len=16, layers=2, hidden=32, heads=4,

@@ -215,10 +215,7 @@ def tiny_step_scopes():
     mesh (dp over the virtual devices, so the gradient all-reduce is
     there)."""
     from apex_tpu.parallel import parallel_state
-    from apex_tpu.resilience.replay.targets import (
-        GPTTargetConfig,
-        build_gpt_training,
-    )
+    from apex_tpu.training import GPTTargetConfig, build_gpt_training
 
     cfg = GPTTargetConfig(vocab=128, layers=2, hidden=64, heads=4,
                           seq_len=32, micro_batch=1, global_batch=16)
