@@ -90,11 +90,13 @@ MODEL_SCOPES = (
 #: Every Pallas kernel of the tree: ops/attention.py (flash forward, dq,
 #: dk/dv), ops/layer_norm.py (LayerNorm and RMSNorm, forward and
 #: backward), optimizers/_fused_kernels.py (flat Adam, flat sum of
-#: squares).
+#: squares); ``mla_rope`` is ops/attention.py's rotation of latent
+#: attention's rope queries in the projection's own layout.
 KERNELS = (
     "flash_fwd",
     "flash_bwd_dq",
     "flash_bwd_dkv",
+    "mla_rope",
     "ln_fwd",
     "ln_bwd",
     "rms_fwd",

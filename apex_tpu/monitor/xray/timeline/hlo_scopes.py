@@ -191,14 +191,18 @@ def kernel_of(ins: HloInstruction) -> Optional[str]:
 
 
 def tiles_of(kernel_metadata) -> str:
-    """``"512x256"`` — ``block_q`` x ``block_k``, then ``d192/128`` where
-    the kernel's q/k and v differ in width — from a kernel's
-    ``kernel_metadata`` (pairs or a dict: the flash kernels choose their
-    tiles per call, ``ops/attention.py:_flash_tiles``); "" where the
-    kernel names none."""
+    """``"512x256"`` — ``block_q`` x ``block_k``, then the head's widths
+    where q/k and v differ: ``d192/128`` (``d_qk`` / ``d_v``), or
+    ``d128+64/128`` where the score is a sum over parts (``d_nope`` +
+    ``d_rope`` / ``d_v``: latent attention's kernels on the projections'
+    own outputs) — from a kernel's ``kernel_metadata`` (pairs or a dict:
+    the flash kernels choose their tiles per call,
+    ``ops/attention.py:_flash_tiles``); "" where the kernel names none."""
     meta = dict(kernel_metadata)
     tiles = "x".join(meta[k] for k in ("block_q", "block_k") if k in meta)
-    if tiles and meta.get("d_qk") != meta.get("d_v"):
+    if tiles and "d_rope" in meta:
+        tiles += f" d{meta.get('d_nope')}+{meta['d_rope']}/{meta.get('d_v')}"
+    elif tiles and meta.get("d_qk") != meta.get("d_v"):
         # q and k wider than v (latent attention): worth seeing beside
         tiles += f" d{meta.get('d_qk')}/{meta.get('d_v')}"
     return tiles

@@ -30,6 +30,14 @@ rows they arrive as. ``block_q`` / ``block_k`` force one tile everywhere
 (the tests' small tiles). The tiles ride in each call's
 ``kernel_metadata`` beside the kernel's name.
 
+Latent attention (``latent_flash_attention``): the same three kernels on
+the operands its projections write, batch-major rows with (head, d) in the
+lanes. A head is a 128-lane column range of those arrays, not a leading
+index of a transposed copy, and the score is a sum over parts (no-rope,
+rope): the kernels' bodies loop over a list of (q part, k part) that has
+one member for ``flash_attention`` (``_operands``). One more kernel,
+``mla_rope``, rotates the queries' rope part where it lies.
+
 Single-chip long context: K/V residency caps the kernel at
 ``_KV_RESIDENT_BYTES`` (below 14k bf16 / 7k fp32 keys at head_dim <= 128).
 Beyond it — or when the XLA fallback's full (sq, sk) score tensor would blow
@@ -45,14 +53,16 @@ cp ring with this same online-softmax structure per visiting chunk.
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.monitor.goodput.scopes import kernel_metadata
+from apex_tpu.monitor.goodput.scopes import kernel_metadata, model_scope
 from apex_tpu.ops._dispatch import resolve_impl
+from apex_tpu.ops.rope import apply_rotary_pos_emb
 
 _NEG_INF = -1e30
 # rows of a tile that one unrolled step of a kernel's loop handles
@@ -284,13 +294,88 @@ def _sweep_q_tile(visit, qi, bq, bk, sub, num_kv, causal, window):
         _sweep(_kv_spans(qi, bq, bk, num_kv, causal, window), edge, full)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, sub,
-                      has_kpm, window=None):
-    kpm_ref = refs[0] if has_kpm else None  # (1, SK/BK, BK), 1 = padded
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
-    d = v_ref.shape[2]  # the output is as wide as v (d_v), q and k d_qk
+def _head_lanes(shape, rope: int, g):
+    """Keep-mask of head ``g``'s lanes in a block that packs the ``rope``
+    wide parts of ``shape[1] // rope`` consecutive heads side by side."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jax.lax.div(lane, rope) == jax.lax.rem(g, shape[1] // rope)
+
+
+def _operands(refs, latent, q_side: bool):
+    """A kernel's leading refs as reads of rows: ``(q_parts, k_parts, v,
+    rest)``. The score of a block pair is the sum over the parts of
+    ``q_part . k_part^T``; a part and ``v`` are ``rows -> (n, width)``
+    reads, ``rest`` the refs that follow.
+
+    ``latent`` None: ``q, k, v`` blocks of (batch * heads, seq, d) arrays,
+    one part. ``latent = (nope, rope)``: ``q_nope, q_rope, kv, k_rope``
+    blocks of what latent attention's projections wrote
+    (``latent_flash_attention``), two parts: head h's no-rope key and
+    value are the two lane ranges of ONE ``kv`` block, and the rope parts
+    come ``128 // rope`` heads to a block: the query's of neighbouring
+    heads, the one shared key's repeated. So a contraction over the whole
+    block is this head's alone once the other heads' lanes are zero on one
+    side: the side this program holds a block of (``q_side``: the query in
+    forward and dq, the key in dk/dv), zeroed once into the trailing
+    scratch ref."""
+    if latent is None:
+        q_ref, k_ref, v_ref = refs[:3]
+        return ([lambda r: q_ref[0, r, :]], [lambda r: k_ref[0, r, :]],
+                lambda r: v_ref[0, r, :], refs[3:])
+    nope, rope = latent
+    qn_ref, qr_ref, kv_ref, kr_ref = refs[:4]
+    held = refs[-1]
+    block = (qr_ref if q_side else kr_ref)[0]
+    held[...] = jnp.where(
+        _head_lanes(block.shape, rope, pl.program_id(0)), block,
+        jnp.zeros_like(block))
+    q_rope = (lambda r: held[r, :]) if q_side else (lambda r: qr_ref[0, r, :])
+    k_rope = (lambda r: kr_ref[0, r, :]) if q_side else (lambda r: held[r, :])
+    return ([lambda r: qn_ref[0, r, :], q_rope],
+            [lambda r: kv_ref[0, r, :nope], k_rope],
+            lambda r: kv_ref[0, r, nope:], refs[4:-1])
+
+
+def _score(a_parts, b_parts):
+    """sum over the parts of ``a . b^T``, fp32."""
+    return functools.reduce(
+        operator.add, [_dot(a, b, _NT) for a, b in zip(a_parts, b_parts)])
+
+
+def _store_row(row_ref, col, n: int):
+    """A per-row statistic of ``n`` rows, (n, 128) lane-dense or (n, 1),
+    into its lane-major (1, 1, n) block."""
+    if col.shape[1] == 128 and n % 128 == 0:
+        # rows -> lanes through the XLU: every row of a lane-dense
+        # square's transpose is the column as a row
+        for c in range(n // 128):
+            at = slice(c * 128, (c + 1) * 128)
+            row_ref[0, :, at] = col[at, :].T[:1, :]
+    else:
+        row_ref[0, 0, :] = col[:, 0]
+
+
+def _load_row(row_ref, col_ref, n: int):
+    """``_store_row``'s way back: a lane-major (1, 1, n) block into the
+    (n, 128) lane-dense or (n, 1) scratch a kernel's loop reads."""
+    if col_ref.shape[1] == 128 and n % 128 == 0:
+        for c in range(n // 128):  # lanes -> rows through the XLU
+            at = slice(c * 128, (c + 1) * 128)
+            col_ref[at, :] = jnp.broadcast_to(
+                row_ref[0, :, at], (128, 128)).T
+    else:
+        col_ref[...] = jnp.broadcast_to(
+            row_ref[0, 0, :][:, None], col_ref.shape)
+
+
+def _flash_fwd_kernel(*refs, scale, causal, bq, bk, sub, has_kpm,
+                      window=None, latent=None):
+    q_parts, k_parts, value, rest = _operands(refs, latent, q_side=True)
+    kpm_ref = rest[0] if has_kpm else None  # (1, SK/BK, BK), 1 = padded
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest[-5:]
+    d = acc_ref.shape[1]  # the output is as wide as v (d_v), q and k d_qk
     qi = pl.program_id(1)
-    num_kv = k_ref.shape[1] // bk
+    num_kv = refs[2].shape[1] // bk  # k (latent: kv) is resident
 
     # running statistics live in VMEM scratch, lane-dense (see _lanes)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -301,9 +386,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, sub,
         """q sub-tile ``r`` against keys [j*bk + off, j*bk + off + w)."""
         rows = pl.ds(r * sub, sub)
         keys = pl.ds(j * bk + off, w)
-        kb = k_ref[0, keys, :]
-        vb = v_ref[0, keys, :]
-        s = _dot(q_ref[0, rows, :], kb, _NT) * scale  # (sub, w), fp32
+        vb = value(keys)
+        s = _score([q(rows) for q in q_parts],
+                   [k(keys) for k in k_parts]) * scale  # (sub, w), fp32
         if keep is not None:
             s = jnp.where(keep, s, _NEG_INF)
         if has_kpm:
@@ -333,15 +418,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, sub,
     o_ref[0] = jnp.where(
         _lanes(m, d) <= _NEG_INF * 0.5, 0.0, acc_ref[...] / _lanes(l, d)
     ).astype(o_ref.dtype)
-    lse = jnp.where(m <= _NEG_INF * 0.5, -_NEG_INF, m + jnp.log(l))
-    if lse.shape[1] == 128 and bq % 128 == 0:
-        # rows -> lanes through the XLU: every row of a lane-dense
-        # square's transpose is the column as a row
-        for c in range(bq // 128):
-            at = slice(c * 128, (c + 1) * 128)
-            lse_ref[0, :, at] = lse[at, :].T[:1, :]
-    else:
-        lse_ref[0, 0, :] = lse[:, 0]
+    _store_row(lse_ref,
+               jnp.where(m <= _NEG_INF * 0.5, -_NEG_INF, m + jnp.log(l)), bq)
 
 
 def _kpm_spec(heads, num_kv, bk):
@@ -358,14 +436,6 @@ def _kpm_spec(heads, num_kv, bk):
     )
 
 
-def _kv_spec(group, sk, d):
-    """K/V block for GQA: q-head row bh maps to kv row bh // group (group =
-    h // h_kv, static). group == 1 recovers plain MHA indexing."""
-    return pl.BlockSpec(
-        (1, sk, d), lambda b_h, i, group=group: (b_h // group, 0, 0)
-    )
-
-
 def _kpm_blocks(kpm, bk):
     """The key-padding operand of a call, if any: (b, sk) int32 ->
     [(b, sk/bk, bk)], one row per kv block (_kpm_spec)."""
@@ -374,80 +444,125 @@ def _kpm_blocks(kpm, bk):
 
 @functools.lru_cache(maxsize=128)
 def _flash_calls(bh, sq, sk, d, dv, dtypes, heads, group, scale, causal,
-                 interpret, tile, subs, window, has_kpm):
+                 interpret, tile, subs, window, has_kpm, rope=0):
     """The three ``pallas_call``s (forward, dq, dk/dv) of one static
-    configuration; q and k are ``d`` wide, v and the output ``dv`` (latent
-    attention: 192 / 128). Cached, because a model makes the same call
-    once a layer and JAX traces and lowers a callable it has seen once, not
-    once a layer: 24 layers x 3 kernels were 20 s of GPT-2 345M's set-up."""
+    configuration. Cached, because a model makes the same call once a
+    layer and JAX traces and lowers a callable it has seen once, not once a
+    layer: 24 layers x 3 kernels were 20 s of GPT-2 345M's set-up.
+
+    ``rope`` 0: q and k are (bh, seq, ``d``) arrays, v and the output
+    ``dv`` wide, a head a leading index. ``rope`` > 0, latent attention's
+    operands as its projections wrote them (``_operands``), a head a lane
+    range: q_nope (b, sq, heads * d), q_rope (b, sq, heads * rope), kv
+    (b, sk, heads * (d + dv)) and the shared rope key 128 lanes wide
+    (b, sk, 128); the output, dq_nope and dkv in the same layouts, and per
+    head 128 lanes of dq_rope (every group of ``rope`` lanes holds it) and
+    of the shared key's gradient (this head's part in its own lanes, zero
+    beside them); dq computes delta from o and hands it to dk/dv."""
     bq, bk = tile
     sub_q, sub_k = subs
     q_dtype, k_dtype, v_dtype = dtypes
     lw = _stat_lanes(sub_q, bk)
     kernel_kw = dict(scale=scale, causal=causal, bq=bq, bk=bk,
-                     has_kpm=has_kpm, window=window)
+                     has_kpm=has_kpm, window=window,
+                     latent=(d, rope) if rope else None)
     kpm_spec = [_kpm_spec(heads, sk // bk, bk)] if has_kpm else []
-    q_block = pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0))
-    o_block = pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0))
-    tiles = dict(block_q=bq, block_k=bk, d_qk=d, d_v=dv)
     # lse and delta carry a singleton middle dim so their block (1, 1, bq)
     # satisfies the TPU (8, 128) tiling rule on the last two dims
     row_block = pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))
-    full_k, full_v = _kv_spec(group, sk, d), _kv_spec(group, sk, dv)
+    row_q = pl.BlockSpec((1, 1, sq), lambda b, j: (b, 0, 0))
+    rows = jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32)
     stats = [pltpu.VMEM((bq, lw), jnp.float32)] * 2
+
+    def spec(n, w, where, along=True):
+        """(1, n, w) blocks of a (batch-like, seq, lanes) array: program
+        ``g`` reads batch row and column block ``where(g)``, sequence block
+        ``i`` (``along``) or the whole sequence as one resident block."""
+        return pl.BlockSpec((1, n, w), lambda g, i: (
+            where(g)[0], i if along else 0, where(g)[1]))
+
+    if rope:
+        b, rw = bh // heads, 128
+        per = rw // rope  # heads to a block of rope parts
+        tiles = dict(block_q=bq, block_k=bk, d_nope=d, d_rope=rope, d_v=dv)
+        # a head's lanes are a column block: its own, the one its rope part
+        # shares with its neighbours, the one shared rope key's
+        own = lambda g: (g // heads, g % heads)
+        pair = lambda g: (g // heads, g % heads // per)
+        shared = lambda g: (g // heads, 0)
+        q_in = [spec(bq, d, own), spec(bq, rw, pair)]
+        k_res = [spec(sk, d + dv, own, False), spec(sk, rw, shared, False)]
+        q_res = [spec(sq, d, own, False), spec(sq, rw, pair, False)]
+        k_in = [spec(bk, d + dv, own), spec(bk, rw, shared)]
+        wide = lambda s: jax.ShapeDtypeStruct((b, s, heads * rw), q_dtype)
+        o_shape = jax.ShapeDtypeStruct((b, sq, heads * dv), q_dtype)
+        # dq's third row operand is o (delta is computed from it and handed
+        # on); dq_nope, every rope group of dq_rope, delta
+        dq_third, dq_accs = spec(bq, dv, own), [(bq, d), (bq, rw)]
+        dq_out = (spec(bq, d, own), spec(bq, rw, own), row_block)
+        dq_shape = (jax.ShapeDtypeStruct((b, sq, heads * d), q_dtype),
+                    wide(sq), rows)
+        # dk_nope and dv as the two lane ranges of kv's gradient; this
+        # head's part of the shared rope key's
+        dkv_out = (spec(bk, d + dv, own), spec(bk, rw, own))
+        dkv_shape = (jax.ShapeDtypeStruct((b, sk, heads * (d + dv)), k_dtype),
+                     wide(sk))
+        dkv_accs = [(bk, d), (bk, rw), (bk, dv)]
+        held = lambda n: [pltpu.VMEM((n, rw), q_dtype)]
+    else:
+        tiles = dict(block_q=bq, block_k=bk, d_qk=d, d_v=dv)
+        own = lambda g: (g, 0)
+        # GQA: q-head row bh maps to kv row bh // group (group = h // h_kv,
+        # static). group == 1 recovers plain MHA indexing
+        grouped = lambda g: (g // group, 0)
+        q_in = [spec(bq, d, own)]
+        k_res = [spec(sk, d, grouped, False), spec(sk, dv, grouped, False)]
+        q_res = [spec(sq, d, own, False)]
+        k_in = [spec(bk, d, grouped), spec(bk, dv, grouped)]
+        o_shape = jax.ShapeDtypeStruct((bh, sq, dv), q_dtype)
+        dq_third, dq_accs = row_block, [(bq, d)]
+        dq_out = spec(bq, d, own)
+        dq_shape = jax.ShapeDtypeStruct((bh, sq, d), q_dtype)
+        # per-Q-HEAD partials: grid still runs over all bh q-head rows, so
+        # two q heads sharing a kv head never race on one output block
+        dkv_out = (spec(bk, d, own), spec(bk, dv, own))
+        dkv_shape = (jax.ShapeDtypeStruct((bh, sk, d), k_dtype),
+                     jax.ShapeDtypeStruct((bh, sk, dv), v_dtype))
+        dkv_accs = [(bk, d), (bk, dv)]
+        held = lambda n: []
+    o_block, do_res = spec(bq, dv, own), spec(sq, dv, own, False)
+
+    vmem = lambda shapes: [pltpu.VMEM(s, jnp.float32) for s in shapes]
     fwd = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, sub=sub_q, **kernel_kw),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, dv), q_dtype),
-            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
-        ),
+        out_shape=(o_shape, rows),
         grid=(bh, sq // bq),
-        in_specs=[q_block, full_k, full_v] + kpm_spec,
+        in_specs=q_in + k_res + kpm_spec,
         out_specs=(o_block, row_block),
-        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32)] + stats,
+        scratch_shapes=vmem([(bq, dv)]) + stats + held(bq),
         interpret=interpret,
         metadata=kernel_metadata("flash_fwd", **tiles),
     )
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sub=sub_q, **kernel_kw),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q_dtype),
+        out_shape=dq_shape,
         grid=(bh, sq // bq),
-        # q block; k, v resident; do block; lse, delta blocks
-        in_specs=[q_block, full_k, full_v, o_block, row_block, row_block]
-        + kpm_spec,
-        out_specs=q_block,
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] + stats,
+        # q block; k, v resident; do block; lse block; delta block (latent:
+        # o block)
+        in_specs=q_in + k_res + [o_block, row_block, dq_third] + kpm_spec,
+        out_specs=dq_out,
+        scratch_shapes=vmem(dq_accs) + stats + held(bq),
         interpret=interpret,
         metadata=kernel_metadata("flash_bwd_dq", **tiles),
     )
-    row_q = pl.BlockSpec((1, 1, sq), lambda b, j: (b, 0, 0))
-
-    def resident_q(w):
-        return pl.BlockSpec((1, sq, w), lambda b, j: (b, 0, 0))
-
-    def kv_in(w):
-        return pl.BlockSpec((1, bk, w), lambda b, j, g=group: (b // g, j, 0))
-
-    def kv_out(w):
-        return pl.BlockSpec((1, bk, w), lambda b, j: (b, j, 0))
-
-    # per-Q-HEAD partials: grid still runs over all bh q-head rows, so two
-    # q heads sharing a kv head never race on one output block
     dkv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sub=sub_k, **kernel_kw),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, sk, d), k_dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv), v_dtype),
-        ),
+        out_shape=dkv_shape,
         grid=(bh, sk // bk),
         # q resident; k, v blocks (grouped); do resident; lse, delta rows
-        in_specs=[resident_q(d), kv_in(d), kv_in(dv), resident_q(dv), row_q,
-                  row_q] + kpm_spec,
-        out_specs=(kv_out(d), kv_out(dv)),
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
-        ],
+        in_specs=q_res + k_in + [do_res, row_q, row_q] + kpm_spec,
+        out_specs=dkv_out,
+        scratch_shapes=vmem(dkv_accs) + held(bk),
         interpret=interpret,
         metadata=kernel_metadata("flash_bwd_dkv", **tiles),
     )
@@ -488,35 +603,41 @@ def _flash_fwd_res(q3, kv3, kpm, heads, group, scale, causal, interpret, tile,
     return o, (q3, kv3, kpm, o, lse)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *refs, scale, causal, bq, bk, sub, has_kpm,
-                         window=None):
+def _flash_bwd_dq_kernel(*refs, scale, causal, bq, bk, sub, has_kpm,
+                         window=None, latent=None):
     """dq for one q block: loop over participating kv blocks (the exact
     recompute-from-lse strategy of the standard flash backward)."""
-    kpm_ref = refs[0] if has_kpm else None
-    dq_ref, acc_ref, lse_c, delta_c = refs[-4:]
+    q_parts, k_parts, value, rest = _operands(refs, latent, q_side=True)
+    # do, lse, then delta's row (latent: the o block delta is made from)
+    do_ref, lse_ref, third = rest[:3]
+    kpm_ref = rest[3] if has_kpm else None
+    n = len(k_parts)  # one dq and one accumulator a part
+    outs, acc_refs, (lse_c, delta_c) = (
+        rest[3 + has_kpm:-(n + 2)], rest[-(n + 2):-2], rest[-2:])
     qi = pl.program_id(1)
-    num_kv = k_ref.shape[1] // bk
+    num_kv = refs[2].shape[1] // bk
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for acc_ref in acc_refs:
+        acc_ref[...] = jnp.zeros_like(acc_ref)
     # lse and delta arrive lane-major; one move to rows a program, not one
     # an iteration
-    for row_ref, col_ref in ((lse_ref, lse_c), (delta_ref, delta_c)):
-        if col_ref.shape[1] == 128 and bq % 128 == 0:
-            for c in range(bq // 128):  # lanes -> rows through the XLU
-                at = slice(c * 128, (c + 1) * 128)
-                col_ref[at, :] = jnp.broadcast_to(
-                    row_ref[0, :, at], (128, 128)).T
-        else:
-            col_ref[...] = jnp.broadcast_to(
-                row_ref[0, 0, :][:, None], col_ref.shape)
+    _load_row(lse_ref, lse_c, bq)
+    if latent is None:
+        _load_row(third, delta_c, bq)
+    else:
+        # delta = rowsum(dO * O) from this program's own blocks, kept for
+        # the loop and handed on to the dk/dv kernel as a row
+        delta_c[...] = jnp.broadcast_to(jnp.sum(
+            do_ref[0].astype(jnp.float32) * third[0].astype(jnp.float32),
+            axis=1, keepdims=True), delta_c.shape)
+        _store_row(outs[n], delta_c[...], bq)
 
     def visit(r, j, off, w, keep):
         rows = pl.ds(r * sub, sub)
         keys = pl.ds(j * bk + off, w)
-        kb = k_ref[0, keys, :]
-        vb = v_ref[0, keys, :]
-        s = _dot(q_ref[0, rows, :], kb, _NT) * scale
+        kbs = [k(keys) for k in k_parts]
+        vb = value(keys)
+        s = _score([q(rows) for q in q_parts], kbs) * scale
         p = jnp.exp(s - _lanes(lse_c[rows, :], w))
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
@@ -525,43 +646,48 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p = jnp.where(pad == 0, p, 0.0)
         dp = _dot(do_ref[0, rows, :], vb, _NT)
         ds = p * (dp - _lanes(delta_c[rows, :], w)) * scale
-        acc_ref[rows, :] += _dot(ds.astype(kb.dtype), kb, _NN)
+        for acc_ref, kb in zip(acc_refs, kbs):
+            acc_ref[rows, :] += _dot(ds.astype(kb.dtype), kb, _NN)
 
     _sweep_q_tile(visit, qi, bq, bk, sub, num_kv, causal, window)
-    dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+    for dq_ref, acc_ref in zip(outs, acc_refs):
+        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *refs, scale, causal, bq, bk, sub, has_kpm,
-                          window=None):
+def _flash_bwd_dkv_kernel(*refs, scale, causal, bq, bk, sub, has_kpm,
+                          window=None, latent=None):
     """dk/dv for one kv block: loop over participating q blocks, on the
     TRANSPOSED scores s_t = k · q^T (keys along sublanes, queries along
     lanes): dv += p_t · dO and dk += ds_t · q are plain products, and the
     lane-major lse and delta rows broadcast over the keys as they are."""
-    kpm_ref = refs[0] if has_kpm else None
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
+    q_parts, k_parts, value, rest = _operands(refs, latent, q_side=False)
+    do_ref, lse_ref, delta_ref = rest[:3]
+    kpm_ref = rest[3] if has_kpm else None
+    n = len(k_parts)
+    outs, accs = rest[3 + has_kpm:-(n + 1)], rest[-(n + 1):]
+    dk_accs, dv_acc = accs[:n], accs[n]
     kj = pl.program_id(1)
-    num_q = q_ref.shape[1] // bq
+    num_q = refs[0].shape[1] // bq  # q is resident
 
-    dk_acc[...] = jnp.zeros_like(dk_acc)
-    dv_acc[...] = jnp.zeros_like(dv_acc)
+    for acc_ref in accs:
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def visit(r, i, off, w, keep):
         """kv sub-tile ``r`` against queries [i*bq + off, i*bq + off + w)."""
         keys = pl.ds(r * sub, sub)
         rows = pl.ds(i * bq + off, w)
-        kb = k_ref[0, keys, :]
-        vb = v_ref[0, keys, :]
-        qb = q_ref[0, rows, :]
+        qbs = [q(rows) for q in q_parts]
+        vb = value(keys)
         dob = do_ref[0, rows, :]
-        s_t = _dot(kb, qb, _NT) * scale  # (sub, w)
+        s_t = _score([k(keys) for k in k_parts], qbs) * scale  # (sub, w)
         p_t = jnp.exp(s_t - lse_ref[0, :, rows])
         if keep is not None:
             p_t = jnp.where(keep, p_t, 0.0)
         dv_acc[keys, :] += _dot(p_t.astype(dob.dtype), dob, _NN)
         dp_t = _dot(vb, dob, _NT)
         ds_t = p_t * (dp_t - delta_ref[0, :, rows]) * scale
-        dk_acc[keys, :] += _dot(ds_t.astype(qb.dtype), qb, _NN)
+        for dk_acc, qb in zip(dk_accs, qbs):
+            dk_acc[keys, :] += _dot(ds_t.astype(qb.dtype), qb, _NN)
 
     def edge(i):
         for r in range(bk // sub):
@@ -588,16 +714,26 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                diag_first=True)
     else:
         _sweep(_q_spans(kj, bq, bk, num_q, causal, window), edge, full)
-    dk, dv = dk_acc[...], dv_acc[...]
+    grads = [acc_ref[...] for acc_ref in accs]
     if has_kpm:
         # a key's dk and dv rows depend on that key's scores alone, so the
         # padded keys of THIS block are zeroed once here, not masked out
         # of every product above
         pad = kpm_ref[0, kj, :].astype(jnp.float32)[:, None]  # (bk, 1)
-        dk = jnp.where(pad == 0.0, dk, 0.0)
-        dv = jnp.where(pad == 0.0, dv, 0.0)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        grads = [jnp.where(pad == 0.0, g, 0.0) for g in grads]
+    if latent is None:
+        for out_ref, g in zip(outs, grads):
+            out_ref[0] = g.astype(out_ref.dtype)
+        return
+    # the products with the UNMASKED rope queries left other heads' lanes
+    # in the shared key's gradient: keep this head's
+    (dk_nope, dk_rope, dv), (dkv_ref, dkr_ref) = grads, outs
+    nope = latent[0]
+    dkv_ref[0, :, :nope] = dk_nope.astype(dkv_ref.dtype)
+    dkv_ref[0, :, nope:] = dv.astype(dkv_ref.dtype)
+    dkr_ref[0] = jnp.where(
+        _head_lanes(dk_rope.shape, latent[1], pl.program_id(0)), dk_rope, 0.0
+    ).astype(dkr_ref.dtype)
 
 
 def _flash_bwd(heads, group, scale, causal, interpret, tile, window, res, do):
@@ -631,6 +767,133 @@ def _flash_bwd(heads, group, scale, causal, interpret, tile, window, res, do):
 
 
 _flash.defvjp(_flash_fwd_res, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: the kernels on the projections' own outputs
+# ---------------------------------------------------------------------------
+
+
+def _rope_kernel(x_ref, cos_ref, lo_ref, hi_ref, o_ref, *, shift, rope,
+                 gather):
+    """Rotate every ``rope``-wide group of lanes of a (1, rows, lanes)
+    block by its row's angles: lane j of a pair takes ``x[j] * cos[j] +
+    x[j + shift] * lo[j] + x[j - shift] * hi[j]`` (``lo`` is -sin on a
+    pair's first lane and 0 on its second, ``hi`` sin on the second: the
+    rolls go through the XLU, nothing is sliced or concatenated).
+    ``gather``: the input holds 128 lanes a head with the head's values in
+    every group (the dq kernel's), and output block c takes the lanes of
+    its own heads from their blocks first."""
+    cos, lo, hi = cos_ref[...], lo_ref[...], hi_ref[...]
+    per = 128 // rope
+    lane_head = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1), rope)
+    for c in range(o_ref.shape[2] // 128):
+        if gather:
+            x = x_ref[0, :, c * per * 128:(c * per + 1) * 128].astype(
+                jnp.float32)
+            for j in range(1, per):
+                at = (c * per + j) * 128
+                x = jnp.where(lane_head == j, x_ref[0, :, at:at + 128].astype(
+                    jnp.float32), x)
+        else:
+            x = x_ref[0, :, c * 128:(c + 1) * 128].astype(jnp.float32)
+        y = (x * cos + pltpu.roll(x, 128 - shift, 1) * lo
+             + pltpu.roll(x, shift, 1) * hi)
+        o_ref[0, :, c * 128:(c + 1) * 128] = y.astype(o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=32)
+def _rope_call(b, s, lanes, dtype, rope, shift, gather, interpret):
+    """The rotation of a (b, s, ``lanes``) array of ``rope``-wide groups
+    (``_rope_kernel``), tables (s, 128) fp32; with ``gather`` the input is
+    (b, s, lanes * 128 // rope). Named: the module's nameless Mosaic calls
+    are the three flash kernels, and the benchmark counts them."""
+    rows = next((t for t in (256, 128, 64, 32, 16, 8) if s % t == 0), s)
+    table = pl.BlockSpec((rows, 128), lambda n, i: (i, 0))
+    block = lambda w: pl.BlockSpec((1, rows, w), lambda n, i: (n, i, 0))
+    return pl.pallas_call(
+        functools.partial(_rope_kernel, shift=shift, rope=rope,
+                          gather=gather),
+        out_shape=jax.ShapeDtypeStruct((b, s, lanes), dtype),
+        grid=(b, s // rows),
+        in_specs=[block(lanes * 128 // rope if gather else lanes)]
+        + [table] * 3,
+        out_specs=block(lanes),
+        interpret=interpret,
+        name="mla_rope",
+        metadata=kernel_metadata("mla_rope"),
+    )
+
+
+def _rope_tables(freqs, rope: int, interleaved: bool):
+    """(cos, lo, hi), each (s, 128) fp32, of ``_rope_kernel`` from the
+    (s, rope) angles: 128 // rope groups side by side."""
+    reps = (1, 128 // rope)
+    cos, sin = jnp.tile(jnp.cos(freqs), reps), jnp.tile(jnp.sin(freqs), reps)
+    lane = jnp.arange(128) % rope
+    first = (lane % 2 == 0) if interleaved else (lane < rope // 2)
+    return cos, jnp.where(first, -sin, 0.0), jnp.where(first, 0.0, sin)
+
+
+def _latent_calls(q_nope, kv, rope, kpm, heads, scale, interpret, tile):
+    b, s, width = q_nope.shape
+    nope = width // heads
+    return _flash_calls(
+        b * heads, s, s, nope, kv.shape[2] // heads - nope,
+        (q_nope.dtype, kv.dtype, kv.dtype), heads, 1, scale, True, interpret,
+        tile, tuple(_subtile(t) for t in tile), None, kpm is not None, rope)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _latent(q_nope, q_rope, kv, k_rope, tables, kpm, heads, scale, shift,
+            interpret, tile):
+    o, _ = _latent_fwd_res(q_nope, q_rope, kv, k_rope, tables, kpm, heads,
+                           scale, shift, interpret, tile)
+    return o
+
+
+def _latent_fwd_res(q_nope, q_rope, kv, k_rope, tables, kpm, heads, scale,
+                    shift, interpret, tile):
+    """``k_rope`` arrives rotated (it is 1/heads of q's), ``q_rope`` not:
+    its rotated copy is an operand of the kernels alone."""
+    b, s, lanes = q_rope.shape
+    rope = k_rope.shape[2]
+    with model_scope("mla_project"):
+        q_rot = _rope_call(b, s, lanes, q_rope.dtype, rope, shift, False,
+                           interpret)(q_rope, *tables)
+        k_rep = jnp.tile(k_rope, (1, 1, 128 // rope))
+    fwd, _, _ = _latent_calls(
+        q_nope, kv, rope, kpm, heads, scale, interpret, tile)
+    o, lse = fwd(q_nope, q_rot, kv, k_rep, *_kpm_blocks(kpm, tile[1]))
+    return o, (q_nope, q_rot, kv, k_rep, tables, kpm, o, lse)
+
+
+def _latent_bwd(heads, scale, shift, interpret, tile, res, do):
+    q_nope, q_rot, kv, k_rep, (cos, lo, hi), kpm, o, lse = res
+    b, s, lanes = q_rot.shape
+    rope = lanes // heads
+    _, dq_call, dkv_call = _latent_calls(
+        q_nope, kv, rope, kpm, heads, scale, interpret, tile)
+    blocks = _kpm_blocks(kpm, tile[1])
+    dq_nope, dq_wide, delta = dq_call(
+        q_nope, q_rot, kv, k_rep, do, lse, o, *blocks)
+    dkv, dk_parts = dkv_call(
+        q_nope, q_rot, kv, k_rep, do, lse, delta, *blocks)
+    with model_scope("mla_project"):
+        # the rotation's transpose is the rotation by the negated angles
+        dq_rope = _rope_call(b, s, lanes, q_rot.dtype, rope, shift, True,
+                             interpret)(dq_wide, cos, -lo, -hi)
+        # the shared key's gradient: the heads' parts (each in its own lanes
+        # of its 128) summed group on group, as one product with 0 / 1
+        fold = (jnp.arange(dk_parts.shape[2])[:, None] % rope
+                == jnp.arange(rope)[None, :]).astype(dk_parts.dtype)
+        dk_rope = jnp.dot(dk_parts, fold, preferred_element_type=jnp.float32)
+    # the tables and the int mask take no cotangent (None == symbolic zero)
+    return dq_nope, dq_rope, dkv, dk_rope.astype(k_rep.dtype), None, None
+
+
+_latent.defvjp(_latent_fwd_res, _latent_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1051,3 +1314,78 @@ def flash_attention(
         q3, (k3, v3), kpm_i, h, group, scale, causal, interpret, tile, window
     )
     return o.reshape(b, h, sq, d_v)
+
+
+def latent_flash_attention(
+    q_nope,
+    q_rope,
+    kv,
+    k_rope,
+    freqs,
+    *,
+    heads: int,
+    interleaved: bool = False,
+    scale: float = None,
+    key_padding_mask=None,
+    impl: str = "auto",
+    block_q: int = None,
+    block_k: int = None,
+):
+    """Causal multi-head latent attention on what its projections wrote,
+    batch-major: ``q_nope`` (b, s, heads * nope) and ``q_rope`` (b, s,
+    heads * rope), the no-rope and rope columns of the query projection;
+    ``kv`` (b, s, heads * (nope + d_v)), head h's no-rope key and value
+    side by side as the kv up-projection writes them; ``k_rope`` (b, s,
+    rope), the ONE rope key all heads share. ``q_rope`` and ``k_rope``
+    arrive unrotated with ``freqs`` ((s, 1, 1, rope) angles,
+    ``rope_frequencies``). Returns the context (b, s, heads * d_v), which
+    the output projection reads as it is.
+
+    The same mathematics as ``flash_attention`` on q = [q_nope | rot
+    q_rope], k = [k_nope | rot k_rope for every head], v: the score is the
+    sum of the two parts' products, fp32. The kernels (``_flash_calls``
+    with ``rope``) read a head as a 128-lane column range of these arrays,
+    so no (tokens x heads x d) array is sliced, concatenated, broadcast or
+    transposed on either side of them, forward or backward; the one such
+    array written is q's rotated rope part. That needs whole lane tiles:
+    nope and d_v multiples of 128, rope a divisor of 128 with heads a
+    multiple of 128 // rope, and the resident operands within the VMEM
+    budget. Any other call (and ``impl="xla"``) assembles q, k and v and
+    goes through ``flash_attention``."""
+    b, s, width = q_nope.shape
+    nope, rope = width // heads, k_rope.shape[2]
+    d_v = kv.shape[2] // heads - nope
+    if scale is None:
+        scale = 1.0 / math.sqrt(nope + rope)
+    angles = freqs[:s].reshape(1, s, 1, rope)
+    rotate = functools.partial(
+        apply_rotary_pos_emb, freqs=angles, interleaved=interleaved)
+    k_rot = rotate(k_rope[:, :, None, :])  # (b, s, 1, rope): XLA's, it is small
+    use_pallas, interpret = resolve_impl(impl)
+    resident = _kv_vmem_bytes(s, nope + 128, jnp.dtype(kv.dtype).itemsize, d_v)
+    tile = _flash_tiles(s, s, None, resident, block_q, block_k)
+    whole_lanes = (
+        nope % 128 == 0 and d_v % 128 == 0 and 128 % rope == 0
+        and heads % (128 // rope) == 0
+    )
+    if (use_pallas and whole_lanes and tile is not None
+            and resident <= _KV_RESIDENT_BYTES):
+        kpm_i = (None if key_padding_mask is None
+                 else key_padding_mask.astype(jnp.int32))
+        return _latent(
+            q_nope, q_rope, kv, k_rot[:, :, 0], _rope_tables(
+                angles.reshape(s, rope).astype(jnp.float32), rope,
+                interleaved),
+            kpm_i, heads, scale, 1 if interleaved else rope // 2, interpret,
+            tile)
+    heads_of = lambda t: t.reshape(b, s, heads, -1)
+    kv4 = heads_of(kv)
+    q = jnp.concatenate([heads_of(q_nope), rotate(heads_of(q_rope))], axis=-1)
+    k = jnp.concatenate(
+        [kv4[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rope))],
+        axis=-1)
+    o = flash_attention(
+        *(jnp.swapaxes(t, 1, 2) for t in (q, k, kv4[..., nope:])),
+        causal=True, scale=scale, key_padding_mask=key_padding_mask,
+        impl=impl, block_q=block_q, block_k=block_k)
+    return jnp.swapaxes(o, 1, 2).reshape(b, s, heads * d_v)

@@ -42,7 +42,7 @@ def _rotate_pairs(x):
     """(x0, x1, x2, x3, ...) -> (-x1, x0, -x3, x2, ...): the partner of
     each channel in a rotation of consecutive pairs."""
     pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    return jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1).reshape(x.shape)
+    return (pairs[..., ::-1] * jnp.array([-1, 1], x.dtype)).reshape(x.shape)
 
 
 def apply_rotary_pos_emb_cached(t, cos_, sin_):

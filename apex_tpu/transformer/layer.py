@@ -19,14 +19,14 @@ on the MXU via ``preferred_element_type``.
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.monitor.goodput.scopes import model_scope
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.attention import flash_attention, latent_flash_attention
 from apex_tpu.ops.layer_norm import layer_norm, rms_norm
 from apex_tpu.ops.rope import apply_rotary_pos_emb, rope_frequencies
 from apex_tpu.ops.softmax import fused_scale_mask_softmax
@@ -604,15 +604,45 @@ class ParallelAttention(nn.Module):
         return out
 
 
+class _HeadColumnsDense(nn.Module):
+    """A bias-free ``nn.Dense`` whose output columns are ``heads`` groups
+    of ``sum(widths)``, returned as one (..., heads * width) output for
+    each of ``widths``: the same parameter as the Dense it stands for
+    (``kernel``, (in, heads * sum(widths))), read as its column sets. The
+    WEIGHT is sliced, where the cast to the compute dtype reads it anyway,
+    so the activation is never sliced."""
+
+    heads: int
+    widths: tuple
+    dtype: Any
+    param_dtype: Any
+    kernel_init: Callable
+
+    @nn.compact
+    def __call__(self, x):
+        rows, per_head = x.shape[-1], sum(self.widths)
+        kernel = self.param("kernel", self.kernel_init,
+                            (rows, self.heads * per_head), self.param_dtype)
+        kernel = kernel.astype(self.dtype).reshape(rows, self.heads, per_head)
+        outs, lo = [], 0
+        for w in self.widths:
+            outs.append(jnp.dot(
+                x, kernel[:, :, lo:lo + w].reshape(rows, self.heads * w)))
+            lo += w
+        return outs
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2/V3 MLA) in its training
     form: queries and keys/values come through low-rank bottlenecks
     (``q_lora_rank``, ``kv_lora_rank``, each normed), every head has a
     no-rope part of its own and a rope part whose key side ALL heads share,
-    and the values are narrower than the keys. It runs as plain multi-head
-    attention with ``qk_nope + qk_rope``-wide q/k and ``v_head_dim``-wide v
-    through the flash kernels: no padding of v, no absorbed form, no cache
-    (serving it needs a latent paged cache: ROADMAP R8)."""
+    and the values are narrower than the keys. The flash kernels read the
+    projections' outputs as they are written (batch-major rows, a head a
+    column range: ``ops.attention.latent_flash_attention``) and write the
+    context where ``o_proj`` reads it: nothing of tokens x heads x d size
+    is sliced, concatenated, broadcast or transposed. No absorbed form, no
+    cache (serving it needs a latent paged cache: ROADMAP R8)."""
 
     config: TransformerConfig
 
@@ -627,46 +657,33 @@ class LatentAttention(nn.Module):
                 "path: no dense mask, no cross attention")
         if _tp_size(cfg.tensor_axis) > 1 or cfg.context_parallel_mode:
             raise NotImplementedError("latent attention under tp or cp")
-        s, b, _ = hidden_states.shape
         heads = cfg.num_attention_heads
         nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-        dense = functools.partial(
-            nn.Dense, use_bias=False, dtype=hidden_states.dtype,
-            param_dtype=cfg.params_dtype,
-            kernel_init=nn.initializers.normal(stddev=0.02))
-
+        init = dict(dtype=hidden_states.dtype, param_dtype=cfg.params_dtype,
+                    kernel_init=nn.initializers.normal(stddev=0.02))
+        dense = functools.partial(nn.Dense, use_bias=False, **init)
+        # (s, b, h) -> (b, s, h): the kernels' row blocks are runs of one
+        # batch row's tokens
+        x = jnp.swapaxes(hidden_states, 0, 1)
         with model_scope("mla_project"):
             c_q = Norm(config=cfg, name="q_a_layernorm")(
-                dense(cfg.q_lora_rank, name="q_a_proj")(hidden_states))
-            q = dense(heads * (nope + rope), name="q_b_proj")(c_q)
-            q = q.reshape(s, b, heads, nope + rope)
-            kv_a = dense(cfg.kv_lora_rank + rope, name="kv_a_proj")(
-                hidden_states)
+                dense(cfg.q_lora_rank, name="q_a_proj")(x))
+            q_nope, q_rope = _HeadColumnsDense(
+                heads=heads, widths=(nope, rope), name="q_b_proj", **init)(c_q)
+            kv_a = dense(cfg.kv_lora_rank + rope, name="kv_a_proj")(x)
             c_kv = Norm(config=cfg, name="kv_a_layernorm")(
                 kv_a[..., : cfg.kv_lora_rank])
-            k_rope = kv_a[..., cfg.kv_lora_rank:].reshape(s, b, 1, rope)
             kv = dense(heads * (nope + dv), name="kv_b_proj")(c_kv)
-            kv = kv.reshape(s, b, heads, nope + dv)
-            freqs, _ = rotary_pos_emb
-            rotate = functools.partial(
-                apply_rotary_pos_emb, freqs=freqs[:s],
-                interleaved=cfg.rotary_interleaved)
-            q = jnp.concatenate(
-                [q[..., :nope], rotate(q[..., nope:])], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :nope],
-                 jnp.broadcast_to(rotate(k_rope), (s, b, heads, rope))],
-                axis=-1)
-            # (s, b, heads, d) -> (b, heads, s, d)
-            qb, kb, vb = (jnp.transpose(t, (1, 2, 0, 3))
-                          for t in (q, k, kv[..., nope:]))
-        ctx = flash_attention(
-            qb, kb, vb, causal=True, key_padding_mask=key_padding_mask,
-            scale=1.0 / math.sqrt(nope + rope), impl=cfg.attention_impl)
+        ctx = latent_flash_attention(
+            q_nope, q_rope, kv, kv_a[..., cfg.kv_lora_rank:],
+            rotary_pos_emb[0], heads=heads,
+            interleaved=cfg.rotary_interleaved,
+            scale=1.0 / math.sqrt(nope + rope),
+            key_padding_mask=key_padding_mask, impl=cfg.attention_impl)
         with model_scope("mla_project"):
-            ctx = jnp.transpose(ctx, (2, 0, 1, 3)).reshape(s, b, heads * dv)
-            return dense(cfg.hidden_size, name="o_proj")(ctx)
+            out = dense(cfg.hidden_size, name="o_proj")(ctx)
+        return jnp.swapaxes(out, 0, 1)
 
 
 class ParallelTransformerLayer(nn.Module):

@@ -197,6 +197,29 @@ def build_checks():
         g_x = jax.jit(jax.grad(loss("xla"), argnums=(0, 1, 2)))
         return check(name, g_p(q, k_, v), g_x(q, k_, v), 1e-1)
 
+    def latent_bwd(name="latent_flash_attention bwd bf16 s=4096 "
+                        "d=128+64/128 kpm"):
+        # the same call as the projections write it (batch-major rows, a
+        # head a column range, ONE rope key, both rope parts unrotated):
+        # two batch rows of four heads, forward and five gradients, the
+        # compiled kernels against the assembled XLA path
+        from apex_tpu.ops import rope_frequencies
+        from apex_tpu.ops.attention import latent_flash_attention
+
+        ks = [jax.random.fold_in(key, n) for n in range(40, 45)]
+        shapes = ((2, 4096, 4 * 128), (2, 4096, 4 * 64), (2, 4096, 4 * 256),
+                  (2, 4096, 64))
+        args = [jax.random.normal(k, sh, jnp.bfloat16)
+                for k, sh in zip(ks, shapes)]
+        freqs = rope_frequencies(64, 4096, interleaved=True)
+        pad = jnp.zeros((2, 4096), bool).at[1, 1000:1100].set(True)
+        loss = lambda impl: lambda *a: jnp.sum(jnp.sin(latent_flash_attention(
+            *a, freqs, heads=4, interleaved=True, key_padding_mask=pad,
+            impl=impl).astype(jnp.float32)))
+        both = lambda impl: jax.jit(jax.value_and_grad(
+            loss(impl), argnums=(0, 1, 2, 3)))(*args)
+        return check(name, both("pallas"), both("xla"), 1e-1)
+
     yield "flash_attention GQA fwd", gqa_fwd
     yield "flash_attention GQA bwd", gqa_bwd
     yield "flash_attention window fwd", window_fwd
@@ -204,6 +227,7 @@ def build_checks():
     yield "flash_attention kpm bwd", kpm_bwd
     yield "flash_attention bwd bf16 causal s=1024 d=64", gpt2_bwd
     yield "flash_attention bwd bf16 causal s=4096 d=192/128", mla_bwd
+    yield "latent_flash_attention bwd bf16 s=4096 d=128+64/128 kpm", latent_bwd
 
     # ---- blockwise long-context + decode-shaped attention (compiled) ----
     # The blockwise path is the single-chip long-context engine

@@ -146,13 +146,34 @@ def attention_edge(device):
         f"tiles {A._flash_tiles(1024, 1024, None, A._kv_vmem_bytes(1024, 64, 2))}",
         lambda: compile_attn(8, 16, 16, 1024, 1024, 64, jnp.bfloat16,
                              True, True))
-    # latent attention's call: q and k 192 wide, v and the output 128
+    # q and k wider than v: what latent attention's entry assembles where
+    # its own kernels do not apply (head sizes that are no whole lane tiles)
     ok &= attempt(
-        "attention joyai_llm_flash.pretrain (2, 32, 4096, 192/128) bf16 "
-        "causal fwd+bwd, tiles "
+        "attention (2, 32, 4096, 192/128) bf16 causal fwd+bwd, tiles "
         f"{A._flash_tiles(4096, 4096, None, A._kv_vmem_bytes(4096, 192, 2, 128))}",
         lambda: compile_attn(2, 32, 32, 4096, 4096, 192, jnp.bfloat16,
                              True, True, d_v=128))
+    # joyai_llm_flash.pretrain's call: the projections' own outputs
+
+    def compile_latent():
+        def sds(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+        def loss(*a):
+            return A.latent_flash_attention(
+                *a, heads=32, interleaved=True).astype(jnp.float32).sum()
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+            sds(2, 4096, 32 * 128), sds(2, 4096, 32 * 64),
+            sds(2, 4096, 32 * 256), sds(2, 4096, 64),
+            jax.ShapeDtypeStruct((4096, 1, 1, 64), jnp.float32,
+                                 sharding=sharding)).compile()
+
+    ok &= attempt(
+        "latent attention joyai_llm_flash.pretrain (2, 4096, 32 x 128+64/128) "
+        "bf16 fwd+bwd, heads in lanes, tiles "
+        f"{A._flash_tiles(4096, 4096, None, A._kv_vmem_bytes(4096, 256, 2, 128))}",
+        compile_latent)
     for dtype in (jnp.bfloat16, jnp.float32):
         for d in (64, 128, 256):
             per_key = A._kv_vmem_bytes(1, d, jnp.dtype(dtype).itemsize)

@@ -126,6 +126,29 @@ class TestCrossLowering:
         assert _kernels_for_tpu(fwd, *args) == 1, name
         assert _kernels_for_tpu(bwd, *args) == 3, name
 
+    @pytest.mark.parametrize("kpm", [False, True])
+    def test_latent_flash_fwd_bwd(self, kpm):
+        """Latent attention's call as the JoyAI cell makes it (the
+        projections' outputs, a head a 128-lane column range): one flash
+        kernel and the rotation forward; three and two with the backward."""
+        from apex_tpu.ops.attention import latent_flash_attention
+
+        def fwd(qn, qr, kv, kr, freqs, m):
+            return latent_flash_attention(
+                qn, qr, kv, kr, freqs, heads=32, interleaved=True,
+                key_padding_mask=m if kpm else None, impl="pallas")
+
+        def loss(*a):
+            return fwd(*a).astype(jnp.float32).sum()
+
+        bf = jnp.bfloat16
+        args = (_sds((2, 4096, 32 * 128), bf), _sds((2, 4096, 32 * 64), bf),
+                _sds((2, 4096, 32 * 256), bf), _sds((2, 4096, 64), bf),
+                _sds((4096, 1, 1, 64), jnp.float32),
+                _sds((2, 4096), jnp.bool_))
+        assert _kernels_for_tpu(fwd, *args) == 2
+        assert _kernels_for_tpu(jax.grad(loss, (0, 1, 2, 3)), *args) == 5
+
     @pytest.mark.parametrize("batch", [1, 4, 8])
     def test_flash_key_padding_any_batch(self, batch):
         """A (b, sk) key-padding mask blocked as (1, sk) is refused by the

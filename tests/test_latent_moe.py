@@ -95,8 +95,146 @@ def test_flash_metadata_names_both_head_dims():
     calls = A._flash_calls(2, 64, 64, 24, 16, (jnp.float32,) * 3, 2, 1, 0.2,
                            True, True, (32, 32), (32, 32), None, False)
     assert len(calls) == 3
+    # latent attention's calls: the same three sites, a head a lane range
+    parts = A._flash_calls(4, 64, 64, 128, 128, (jnp.float32,) * 3, 2, 1,
+                           0.2, True, True, (32, 32), (32, 32), None, False,
+                           64)
+    assert len(parts) == 3 and parts != calls
     assert A._kv_vmem_bytes(4096, 192, 2, 128) == 4096 * (256 + 128) * 2
     assert A._kv_vmem_bytes(1024, 64, 2) == 2 * 1024 * 128 * 2
+    # the latent call's residents: 128 + 128 (rope) lanes, and 128
+    assert A._kv_vmem_bytes(4096, 128 + 128, 2, 128) == A._kv_vmem_bytes(
+        4096, 192, 2, 128)
+
+
+# -- the flash kernels on latent attention's own layouts ----------------------
+
+LATENT_SHAPES = {
+    # b, heads, s, nope, rope, d_v, forced tiles
+    "s256": (2, 2, 256, 128, 64, 128, {}),
+    "tiny-32x32": (2, 2, 64, 128, 64, 128, dict(block_q=32, block_k=32)),
+}
+
+
+def _latent_operands(shape, kpm):
+    b, h, s, nope, rope, dv, _ = LATENT_SHAPES[shape]
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    widths = (h * nope, h * rope, h * (nope + dv), rope, h * dv)
+    q_nope, q_rope, kv, k_rope, do = (
+        jax.random.normal(k, (b, s, w)) for k, w in zip(ks, widths))
+    pad = None
+    if kpm:  # a run of keys in one row, the first keys in the other: the
+        # second row's first queries see no key at all (dead rows)
+        pad = jnp.zeros((b, s), bool).at[0, s // 3:s // 2].set(True).at[
+            1, :5].set(True)
+    return (q_nope, q_rope, kv, k_rope), do, pad
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_results(shape, kpm, impl):
+    """(forward, gradients) of the latent entry and of ``_attn_ref`` on the
+    assembled q, k, v, as {name: (got, want)}."""
+    b, h, s, nope, rope, dv, kw = LATENT_SHAPES[shape]
+    args, do, pad = _latent_operands(shape, kpm)
+    freqs = rope_frequencies(rope, s, base=32e6, interleaved=True)
+
+    def entry(*a):
+        return A.latent_flash_attention(
+            *a, freqs, heads=h, interleaved=True, key_padding_mask=pad,
+            impl=impl, **kw)
+
+    def plain(q_nope, q_rope, kv, k_rope):
+        rot = functools.partial(
+            apply_rotary_pos_emb, freqs=freqs.reshape(1, s, 1, rope),
+            interleaved=True)
+        heads_of = lambda t: t.reshape(b, s, h, -1)
+        kv4 = heads_of(kv)
+        q = jnp.concatenate([heads_of(q_nope), rot(heads_of(q_rope))], -1)
+        k = jnp.concatenate([kv4[..., :nope], jnp.broadcast_to(
+            rot(k_rope[:, :, None, :]), (b, s, h, rope))], -1)
+        o = A._attn_ref(
+            *(jnp.swapaxes(t, 1, 2) for t in (q, k, kv4[..., nope:])),
+            (nope + rope) ** -0.5, True,
+            None if pad is None else pad[:, None, None, :])
+        return jnp.swapaxes(o, 1, 2).reshape(b, s, h * dv)
+
+    out = {"fwd": (entry(*args), plain(*args))}
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) * do), (0, 1, 2, 3))(
+        *args) for f in (entry, plain)]
+    for name, got, want in zip(("dq_nope", "dq_rope", "dkv", "dk_rope"),
+                               *grads):
+        out[name] = (got, want)
+    # kv's gradient holds dk_nope and dv side by side, head by head
+    dkv = [g.reshape(b, s, h, nope + dv) for g in out.pop("dkv")]
+    out["dk_nope"] = tuple(g[..., :nope] for g in dkv)
+    out["dv"] = tuple(g[..., nope:] for g in dkv)
+    return out
+
+
+@pytest.mark.parametrize(
+    "what", ["fwd", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"])
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("kpm", [False, True], ids=["causal", "kpm"])
+@pytest.mark.parametrize("shape", list(LATENT_SHAPES))
+def test_latent_entry_matches_the_reference_on_assembled_q_k_v(
+        shape, kpm, impl, what):
+    """``dk_rope`` is the shared key's gradient: the sum over heads."""
+    got, want = _latent_results(shape, kpm, impl)[what]
+    assert got.shape == want.shape
+    close(got, want)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, through its sub-jaxprs but not into
+    Pallas kernel bodies (what a kernel does in VMEM moves nothing in
+    HBM)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_latent_attention_moves_no_head_sized_activation():
+    """The module's forward on the kernels' path: no concatenate, no
+    broadcast to (..., heads, rope), no transpose of anything with tokens
+    x heads x d elements; what the kernels are handed is what the
+    projections wrote."""
+    heads, nope, rope, dv, s, b = 4, 128, 64, 128, SEQ, 2
+    cfg = config(qk_nope_head_dim=nope, qk_rope_head_dim=rope, v_head_dim=dv,
+                 attention_impl="pallas")
+    x = jax.random.normal(jax.random.PRNGKey(0), (s, b, 64))
+    freqs = rope_frequencies(rope, s, base=32e6, interleaved=True)
+    mod = LatentAttention(config=cfg)
+    params = mod.init(jax.random.PRNGKey(1), x, rotary_pos_emb=(freqs, None))
+    jaxpr = jax.make_jaxpr(lambda p, x: mod.apply(
+        p, x, rotary_pos_emb=(freqs, None)))(params, x)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 2  # the rotation, the forward
+    assert "concatenate" not in names
+    head_sized = s * b * heads * min(rope, dv)
+    for e in eqns:
+        out = e.outvars[0].aval
+        if e.primitive.name == "transpose":
+            assert out.size < head_sized, out.shape
+        if e.primitive.name == "broadcast_in_dim":
+            assert out.shape[-2:] != (heads, rope), out.shape
+    # the flash kernel's operands are the projections' outputs themselves:
+    # the entry's rule is handed three matmuls' results, and inside it only
+    # q's rope part passes through another kernel (the rotation)
+    flash = [e for e in eqns if e.primitive.name == "pallas_call"][-1]
+    assert [v.aval.shape for v in flash.invars] == [
+        (b, s, heads * nope), (b, s, heads * rope), (b, s, heads * (nope + dv)),
+        (b, s, 128)]
+    assert flash.outvars[0].aval.shape == (b, s, heads * dv)
+    produced_by = {v: e.primitive.name for e in eqns for v in e.outvars}
+    rule = next(e for e in eqns if e.primitive.name.startswith("custom_vjp"))
+    assert [produced_by[v] for v in rule.invars[:3]] == ["dot_general"] * 3
+    assert produced_by[flash.invars[1]] == "pallas_call"
+    consumer = next(e for e in eqns if rule.outvars[0] in e.invars)
+    assert consumer.primitive.name == "dot_general"  # o_proj
 
 
 # -- rope ---------------------------------------------------------------------
@@ -594,8 +732,13 @@ def test_model_scopes_are_closed_and_the_reader_shows_them():
                                   d_qk=192, d_v=128)
     same = scopes.kernel_metadata("flash_fwd", block_q=1024, block_k=1024,
                                   d_qk=64, d_v=64)
+    parts = scopes.kernel_metadata("flash_bwd_dq", block_q=1024,
+                                   block_k=1024, d_nope=128, d_rope=64,
+                                   d_v=128)
     assert tiles_of(wide) == "1024x1024 d192/128"
     assert tiles_of(same) == "1024x1024"
+    assert tiles_of(parts) == "1024x1024 d128+64/128"
+    assert tiles_of(scopes.kernel_metadata("mla_rope")) == ""
     assert classify_path(
         "jit(train_step)/forward_backward/transformer/layer_3/mlp/"
         "checkpoint/rematted_computation/moe_dispatch/gather") == (

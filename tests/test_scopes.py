@@ -159,6 +159,23 @@ def _flash():
     return jax.value_and_grad(f, (0, 1, 2)), (qkv, qkv, qkv)
 
 
+def _latent_flash():
+    from apex_tpu.ops import rope_frequencies
+    from apex_tpu.ops.attention import latent_flash_attention
+
+    freqs = rope_frequencies(64, 256, interleaved=True)
+
+    def f(q_nope, q_rope, kv, k_rope):
+        return latent_flash_attention(
+            q_nope, q_rope, kv, k_rope, freqs, heads=2, interleaved=True,
+            impl="pallas").astype(jnp.float32).sum()
+
+    bf = jnp.bfloat16
+    return jax.value_and_grad(f, (0, 1, 2, 3)), (
+        _sds((2, 256, 256), bf), _sds((2, 256, 128), bf),
+        _sds((2, 256, 512), bf), _sds((2, 256, 64), bf))
+
+
 def _flat(which):
     from apex_tpu.ops.multi_tensor import CHUNK_SIZE
     from apex_tpu.optimizers._fused_kernels import adam_flat, l2norm_flat
@@ -180,9 +197,12 @@ def _flat(which):
     (lambda: _norm("layer_norm"), {"ln_fwd", "ln_bwd"}),
     (lambda: _norm("rms_norm"), {"rms_fwd", "rms_bwd"}),
     (_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    (_latent_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                     "mla_rope"}),
     (lambda: _flat("adam"), {"adam_flat"}),
     (lambda: _flat("l2norm"), {"sumsq_flat"}),
-], ids=["layer_norm", "rms_norm", "flash", "adam_flat", "sumsq_flat"])
+], ids=["layer_norm", "rms_norm", "flash", "latent_flash", "adam_flat",
+        "sumsq_flat"])
 def test_every_tpu_custom_call_carries_a_registered_kernel(build, expected):
     f, args = build()
     found = _kernels_lowered_for_tpu(f, *args)
@@ -198,6 +218,16 @@ def test_the_flash_kernels_carry_their_tiles():
     for meta in _metadata_lowered_for_tpu(f, *args):
         assert meta["kernel"].startswith("flash_")
         assert (meta["block_q"], meta["block_k"]) == ("256", "256")
+        assert (meta["d_qk"], meta["d_v"]) == ("64", "64")
+    # latent attention's calls name the score's parts instead
+    f, args = _latent_flash()
+    flash = [m for m in _metadata_lowered_for_tpu(f, *args)
+             if m["kernel"].startswith("flash_")]
+    assert len(flash) == 3
+    for meta in flash:
+        assert (meta["block_q"], meta["d_nope"], meta["d_rope"],
+                meta["d_v"]) == ("256", "128", "64", "128")
+        assert "d_qk" not in meta
     assert scopes.kernel_metadata("flash_fwd", block_q=512, block_k=128) == {
         "kernel": "flash_fwd", "block_q": "512", "block_k": "128"}
 
