@@ -1,7 +1,7 @@
 """Driver: GPT pretraining through the library's step builder.
 
 The window drives what ``examples/gpt/pretrain_gpt.py:main`` builds its hot
-path from (``resilience.replay.targets.build_gpt_training`` from
+path from (``apex_tpu.training.build_gpt_training`` from
 ``pretrain_gpt.target_config(parse_args(argv))``), with the loop ``main`` runs
 there: host batch -> device, one ``train_step``, fetch loss and verdict. The
 weights come from the benchmark's seed (``perf/reference/gpt.py``), not from
@@ -50,7 +50,7 @@ def build(cell, config):
     import jax
     import jax.numpy as jnp
 
-    from apex_tpu.resilience.replay.targets import build_gpt_training
+    from apex_tpu import training
 
     st = State()
     st.cell, st.config = cell, config
@@ -70,7 +70,7 @@ def build(cell, config):
         gpt.target_config(args, journal_on=False),
         max_devices=int(cell.get("chips", 1)))
     st.lr, st.weight_decay = tcfg.lr, tcfg.weight_decay
-    st.training = build_gpt_training(tcfg)
+    st.training = training.build_gpt_training(tcfg)
     st.batch = cell["global_batch"]
     st.n_batches = cell["corpus_samples"] // st.batch
 
@@ -199,6 +199,25 @@ def window(st, seconds, ctx):
                      "micro_batch": st.cell["micro_batch"],
                      "last_loss": loss},
     }
+
+
+def hlo_text(st):
+    """The compiled step's text, for a traced run's reduction (``perf/
+    hlo_scopes.py``): each instruction's ``op_name`` path and a kernel's
+    ``kernel_metadata``. Asked for before ``release`` drops the step."""
+    from apex_tpu.analysis.hlo.parser import module_text
+
+    return module_text(st.step)
+
+
+def scope_names():
+    """The program's registries of names, which tell ``hlo_scopes.
+    scope_map`` what in an ``op_name`` path is a phase of the step and what
+    a scope of the model."""
+    from apex_tpu.monitor.goodput import scopes
+
+    return {"phases": scopes.STEP_PHASES, "scopes": scopes.MODEL_SCOPES,
+            "kernel_key": scopes.KERNEL_KEY}
 
 
 def release(st):

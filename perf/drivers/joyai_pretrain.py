@@ -2,8 +2,8 @@
 library's step builder.
 
 Built like ``gpt_pretrain``: the window drives what ``examples/gpt/
-pretrain_gpt.py:main`` builds its hot path from (``resilience.replay.
-targets.build_gpt_training`` from ``pretrain_gpt.target_config(parse_args(
+pretrain_gpt.py:main`` builds its hot path from (``apex_tpu.training.
+build_gpt_training`` from ``pretrain_gpt.target_config(parse_args(
 argv))``, the model named by ``--arch-file`` and the share by ``--experts-
 held --first-expert --vocab-rows --layers-kept``), with the loop ``main``
 runs there: host batch -> device, one ``train_step``, fetch loss and
@@ -31,8 +31,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.realpath(__file__)))))
 # what a GPT-shaped run shares whatever the model: the example's loader, the
 # state, the corpus, one step of main()'s loop
-from perf.drivers.gpt_pretrain import (  # noqa: E402
-    CHECK_STEPS, State, _corpus, _load_example, _one_step)
+from perf.drivers.gpt_pretrain import (  # noqa: E402,F401
+    CHECK_STEPS, State, _corpus, _load_example, _one_step, hlo_text,
+    scope_names)
 
 
 def _published(config):
@@ -62,7 +63,7 @@ def build(cell, config):
     import jax
     import jax.numpy as jnp
 
-    from apex_tpu.resilience.replay.targets import build_gpt_training
+    from apex_tpu import training
     from perf.reference import joyai_llm_flash as ref
 
     st = State()
@@ -96,7 +97,7 @@ def build(cell, config):
     st.lr, st.weight_decay = tcfg.lr, tcfg.weight_decay
     st.mtp_coeff = config["assumed"]["mtp_loss_coeff"]
     st.bias_speed = config["assumed"]["router_bias_update_speed"]
-    st.training = build_gpt_training(tcfg)
+    st.training = training.build_gpt_training(tcfg)
     st.batch = cell["global_batch"]
     st.n_batches = cell["corpus_samples"] // st.batch
 
@@ -247,6 +248,9 @@ def window(st, seconds, ctx):
                 end["moe_load_max_over_mean"]
                 - start["moe_load_max_over_mean"]) / steps,
             "moe_dropped_assignments": end["moe_dropped"],
+            "moe_compact_share": (
+                end["moe_compact_share"] - start["moe_compact_share"])
+            / steps,
         },
     }
 

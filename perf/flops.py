@@ -7,6 +7,7 @@ causal attention counts the half of the score matrix it has to compute.
 Nothing here imports the program.
 """
 
+import fnmatch
 import json
 import os
 
@@ -64,8 +65,76 @@ def attention_train_cost(batch, heads, seq, head_dim, layers):
     return ops, 12.0 * tensor * layers
 
 
+#: the program's names (``kernel_metadata``) of its flash-attention kernels,
+#: as a pattern: ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv`` today.
+#: The readers of ALL of attention's kernels go by it, so a backward fused
+#: into one kernel stays counted under whatever ``flash_...`` name it takes
+FLASH_KERNELS = "flash_*"
+
+
+def flash_kernel_costs(batch, heads, seq, d_qk, d_v, layers):
+    """``{kernel: (operations, bytes)}``: what each of the three flash
+    kernels needs for one training step over ``layers`` layers of causal
+    attention, q and k ``d_qk`` wide a head, v and the output ``d_v``. The
+    six matmuls ``attention_train_cost`` counts, two a kernel, each pair
+    one ``d_qk`` and one ``d_v`` wide, halved under the causal mask: the
+    forward's QK^T and PV; dq's dP (= dO V^T) and dQ; dk/dv's dV and dK. The
+    scores both backward kernels recompute, and the dP the second of them
+    computes again, are not counted. Bytes by the tensors a kernel of that
+    job reads and writes, bf16 (the rows of lse and delta left out, as
+    ``attention_train_cost`` leaves them): forward q, k, v in and o out; dq
+    q, k, v, dO in and dq out; dk/dv q, k, v, dO in and dk, dv out. Two
+    backward kernels read q, k, v and dO twice, so the three byte counts
+    add up to more than ``attention_train_cost``'s, which is one pass's."""
+    pair = 2.0 * batch * heads * seq * seq * (d_qk + d_v) * 0.5 * layers
+    row = batch * heads * seq * 2.0 * layers  # bytes of one bf16 lane
+    return {"flash_fwd": (pair, row * (2 * d_qk + 2 * d_v)),
+            "flash_bwd_dq": (pair, row * (3 * d_qk + 2 * d_v)),
+            "flash_bwd_dkv": (pair, row * (3 * d_qk + 3 * d_v))}
+
+
+def attention_shape(config, cell):
+    """``(batch, heads, seq, d_qk, d_v, blocks)`` of a cell's flash-
+    attention calls, from its configuration's own keys: GPT-2's (``n_head``,
+    every head ``n_embd / n_head`` wide) or a latent-attention model's
+    (q/k ``qk_nope_head_dim + qk_rope_head_dim``, v ``v_head_dim``, the
+    blocks kept plus the multi-token-prediction blocks). None for a
+    configuration of neither kind."""
+    batch, seq = cell["global_batch"], cell["seq_len"]
+    if "n_head" in config:
+        d = config["n_embd"] // config["n_head"]
+        return batch, config["n_head"], seq, d, d, config["n_layer"]
+    if "qk_nope_head_dim" in config:
+        return (batch, config["num_attention_heads"], seq,
+                config["qk_nope_head_dim"] + config["qk_rope_head_dim"],
+                config["v_head_dim"],
+                config["layers_kept"] + config["num_nextn_predict_layers"])
+    return None
+
+
 def roofline_seconds(ops, nbytes, peaks):
     """The least time the chip could take and which bound sets it."""
     t_ops = ops / peaks["bf16_flops_per_s"]
     t_bytes = nbytes / peaks["hbm_bytes_per_s"]
     return (t_ops, "compute") if t_ops >= t_bytes else (t_bytes, "memory")
+
+
+def roofline_share(reduction, kernels, ops, nbytes, peaks):
+    """Some kernels' share of their roofline in percent, from a trace's
+    reduction (``trace_reduce.reduce``): the least time for ``ops`` and
+    ``nbytes`` a step, times the steps the trace holds, over the device time
+    booked to the kernels whose name matches the pattern ``kernels``
+    (``fnmatch``: ``flash_fwd``, ``flash_*``; ``kernel_seconds`` goes by the
+    name in the kernel's ``kernel_metadata``, whatever its instruction is
+    called). None where the trace holds no such kernel, no whole step, or
+    there is no chip whose peaks to divide by."""
+    if not reduction or peaks is None:
+        return None
+    secs = sum(s for k, s in reduction["kernel_seconds"].items()
+               if fnmatch.fnmatchcase(k, kernels))
+    if secs <= 0 or not reduction["steps"]:
+        return None
+    least, _bound = roofline_seconds(ops, nbytes, peaks)
+    # ``kernel_seconds`` are summed over the chips, a step's work is every
+    # chip's, and ``steps`` is a chip's count
+    return 100.0 * least * reduction["steps"] / secs

@@ -16,7 +16,10 @@ warmed; counted in ``setup_s``), the measured window of ``--seconds`` (no
 compile may happen in it), the device's memory peak, the program's state
 freed, then the comparison of what the timed path produced with the plain
 reference, which decides ``correct``. ``--trace 1`` profiles a slice of the
-window and reports the per-layer metrics in place of the end-to-end ones.
+window and reports the per-layer metrics in place of the end-to-end ones;
+where the driver hands out the compiled step's text (``hlo_text``,
+``scope_names``), the trace's device ops are booked to the program's own
+names: the step's phases, the model's scopes, the kernels.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` also ``breakdown``,
@@ -103,6 +106,8 @@ class Context:
         #: how long starting the profiler stalled the host (about 4 s on a
         #: v5e): rates over the window leave the stall out
         self.trace_stall_s = 0.0
+        #: how long stopping it took, after the window (read, not used)
+        self.trace_stop_s = 0.0
 
     def span(self, name):
         """A named host span. In a traced run it is written into the
@@ -118,18 +123,16 @@ class Context:
     def poll(self):
         """Called by the driver between steps or ticks. In a traced run the
         profiler covers the last ``trace_seconds`` of the ``--seconds`` (the
-        stall of starting it left out): it starts that long before their end
-        and stops at their end, or when the window returns, whichever comes
-        first. So a trace stays small whatever the window's length, and
-        stopping it stalls nothing that is measured."""
-        if self._trace_state in ("off", "done"):
+        stall of starting it left out): it starts that long before their
+        end. It is never stopped here: stopping it takes seconds (PERF.md
+        6), and a stop inside the driver's loop would count them into the
+        window's length. ``run_cell`` stops it once the window has
+        returned, so a traced window ends where a plain one does."""
+        if self._trace_state != "idle":
             return
         elapsed = time.perf_counter() - self._window_t0
-        if self._trace_state == "idle":
-            if elapsed >= self.seconds - self.cell.get("trace_seconds", 3.0):
-                self._start_trace()
-        elif elapsed >= self.seconds + self.trace_stall_s:
-            self.stop_trace()
+        if elapsed >= self.seconds - self.cell.get("trace_seconds", 3.0):
+            self._start_trace()
 
     def _start_trace(self):
         import jax
@@ -150,7 +153,9 @@ class Context:
             return
         import jax
 
+        before = time.perf_counter()
         jax.profiler.stop_trace()
+        self.trace_stop_s = time.perf_counter() - before
         self._trace_state = "done"
 
     def trace_file(self):
@@ -258,6 +263,12 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
          f"{in_window[0]} programs requested")
 
     device["memory_peak_bytes"] = _memory_peak(chips)
+    # the compiled step's text names each device op's phase, model scope and
+    # kernel; taken while the state still holds the compiled step
+    before = time.perf_counter()
+    hlo_text = (driver.hlo_text(state)
+                if trace and hasattr(driver, "hlo_text") else None)
+    names_s = time.perf_counter() - before
     driver.release(state)
     gc.collect()
 
@@ -276,11 +287,22 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
         path = ctx.trace_file()
         if path is None:
             raise RuntimeError("the profiler left no trace")
+        before = time.perf_counter()
+        scopes = None
+        if hlo_text is not None:
+            scopes = load_module(root, "hlo_scopes").scope_map(
+                hlo_text, **driver.scope_names())
+            del hlo_text
+        names_s += time.perf_counter() - before
         ctx.reduction = reducer.reduce(
             reducer.load_xplane(path), chips=chips,
             spans=cell.get("spans", ()),
-            device_required=device["platform"] == "tpu")
+            device_required=device["platform"] == "tpu", scopes=scopes)
         ctx.drop_trace()
+        _say(f"[run] starting the profiler stalled {ctx.trace_stall_s:.2f} s"
+             f", stopping it took {ctx.trace_stop_s:.2f} s (after the "
+             f"window); the compiled step's text and its scope map took "
+             f"{names_s:.2f} s")
         if ctx.reduction["busy_s"] is not None:
             device["busy_s"] = ctx.reduction["busy_s"]
             device["window_s"] = ctx.reduction["window_s"]

@@ -11,12 +11,12 @@ What a TPU trace looks like (checked by hand on a v5e trace, PERF.md 5): one
 plane per chip named ``/device:TPU:<n>`` whose line ``XLA Ops`` holds one event
 per executed HLO op, named by the op's whole HLO text (``%self_attention.117 =
 (bf16[2,64,1024,64]...) custom-call(...), custom_call_target="tpu_custom_call"``:
-the instruction's name carries the flax module's scope, which is what tells a
-flash-attention kernel from a layer-norm kernel today). Nested ops such as a
+the instruction's name carries the flax module's scope; what the program
+called a Pallas kernel rides in the text's ``kernel_metadata``). Nested ops such as a
 ``cond`` and its body overlap: the busy time is the UNION of the intervals, and
-time by name is reported for leaf events only. Beside it lie ``XLA Modules``
-(one event per program run), ``Steps`` and ``Async XLA Ops`` (copies that
-overlap the ops; not counted as busy). ``/host:CPU`` holds the host's thread
+time by name is each event's SELF time (its duration less what nests in it).
+Beside it lie ``XLA Modules`` (one event per program run), ``Steps`` and
+``Async XLA Ops`` (copies that overlap the ops; not counted as busy). ``/host:CPU`` holds the host's thread
 lines with the ``TraceAnnotation`` spans.
 
 ``python perf/trace_reduce.py <file.xplane.pb>`` prints what a trace holds,
@@ -35,13 +35,21 @@ _KEPT_STATS = ("tf_op", "hlo_category", "long_name", "kernel_details",
 
 
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
+_KERNEL_PAIRS = re.compile(r'kernel_metadata=\{([^{}]*)\}')
+_JSON_PAIR = re.compile(r'"((?:[^"\\]|\\.)*)"\s*:\s*"((?:[^"\\]|\\.)*)"')
+MODULES_LINE = "XLA Modules"
+MOSAIC_TARGET = "tpu_custom_call"
+UNATTRIBUTED = "(unattributed)"
 
 
 def split_hlo(raw):
     """An ``XLA Ops`` event's name is the op's HLO text. Returns the
     instruction's own name (``self_attention.117``) and what else of the text
-    tells kernels apart: the custom-call target and the head of the result
-    shape."""
+    tells kernels apart: the custom-call target, the head of the result
+    shape, and what the program itself called a Pallas kernel:
+    ``frontend_attributes={kernel_metadata={"kernel": "flash_fwd", "block_q":
+    "1024", ...}}`` (flat JSON of strings, printed over several lines), as a
+    dict under ``kernel_metadata``."""
     name, sep, rest = raw.partition(" = ")
     stats = {}
     if sep:
@@ -49,6 +57,9 @@ def split_hlo(raw):
         m = _TARGET.search(rest)
         if m:
             stats["custom_call_target"] = m.group(1)
+        m = _KERNEL_PAIRS.search(rest)
+        if m:
+            stats["kernel_metadata"] = dict(_JSON_PAIR.findall(m.group(1)))
     return name.lstrip("%"), stats
 
 
@@ -97,42 +108,68 @@ def union(intervals):
     return out
 
 
-def _leaf_events(events, slack_ns=2.0):
-    """Events that contain no other event of the same line (a ``cond`` and
-    the ops of its body nest; only the innermost did the work). Times come
-    in picoseconds and are read as float nanoseconds, so neighbours can
-    overlap by a rounding error: an event counts as inside another only if
-    it also ENDS inside it, give or take ``slack_ns``."""
-    evs = sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"]))
-    leaves, stack = [], []
-    for e in evs:
+def _self_times(events, slack_ns=2.0):
+    """``(event, self time in ns)`` for the events of ONE line of one plane:
+    each event's duration less the part that events nested in it cover. A
+    ``cond`` and the ops of its body nest, and so does a nanosecond-long
+    custom-call that the scheduler put inside a kernel's interval: booking
+    whole durations would count the body twice, booking innermost events
+    alone would lose the kernel. The self times of a line add up to its busy
+    union. Times come in picoseconds and are read as float nanoseconds, so
+    neighbours can overlap by a rounding error: an event counts as inside
+    another only if it also ENDS inside it, give or take ``slack_ns``."""
+    out, stack = [], []  # stack: [end, index into out]
+    for e in sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"])):
         end = e["start_ns"] + e["dur_ns"]
-        while stack and (stack[-1][1] <= e["start_ns"] + slack_ns
-                         or end > stack[-1][1] + slack_ns):
-            ev, _, has_child = stack.pop()
-            if not has_child:
-                leaves.append(ev)
+        while stack and (stack[-1][0] <= e["start_ns"] + slack_ns
+                         or end > stack[-1][0] + slack_ns):
+            stack.pop()
         if stack:
-            stack[-1][2] = True
-        stack.append([e, end, False])
-    for ev, _, has_child in stack:
-        if not has_child:
-            leaves.append(ev)
-    return leaves
+            out[stack[-1][1]][1] -= min(end, stack[-1][0]) - e["start_ns"]
+        stack.append([end, len(out)])
+        out.append([e, e["dur_ns"]])
+    return [(e, max(t, 0.0)) for e, t in out]
 
 
-def reduce(events, chips=1, spans=(), device_required=True):
+def module_runs(events, planes):
+    """How often the step's program ran on a chip in the trace: the events
+    of the ``XLA Modules`` line (one per program run) of the program that
+    took most of the time there, averaged over ``planes``. 0 where the line
+    is missing."""
+    secs, runs = collections.Counter(), collections.Counter()
+    for e in events:
+        if e["line"] == MODULES_LINE and e["plane"] in planes:
+            secs[e["name"]] += e["dur_ns"]
+            runs[e["name"]] += 1
+    if not secs:
+        return 0.0
+    return runs[max(secs, key=secs.get)] / float(len(planes))
+
+
+def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
     """The reduction the per-layer metrics read.
 
     ``window_s``: first to last instant of anything kept (device ops and the
     named host spans). ``busy_s``: union of device-op intervals, averaged
-    over the ``chips`` first device planes. ``op_seconds``: summed duration of
-    leaf device ops by name, over those planes. ``op_counts``: how many such events.
+    over the ``chips`` first device planes. ``op_seconds``: summed self time
+    (``_self_times``) of device ops by name, over those planes. ``op_counts``:
+    how many events with any.
     ``op_stats``: for each op name, the string stats its first event
     carried. ``top_ops``: the ten
     longest of ``op_seconds`` grouped by name without XLA's numbering. ``idle_gaps``: idle time on the first chip by
     the host span that overlaps each gap most (``(no span)`` where none
-    does), longest first. ``span_seconds``: host time by span name."""
+    does), longest first. ``span_seconds``: host time by span name.
+
+    ``steps``: runs of the step's program a chip (``module_runs``).
+    ``kernel_seconds`` / ``kernel_counts``: ops' self time and number by the
+    kernel their ``kernel_metadata`` names, over the planes; a Mosaic
+    custom-call that carries none (the library's grouped matmuls) by its
+    instruction's name without XLA's numbering. With ``scopes`` (instruction
+    name -> ``hlo_scopes.Scope``, from the compiled step's text) also
+    ``phase_seconds`` (ops' self time by ``Scope.part``; an op no
+    instruction of the text matches under ``(unattributed)``) and
+    ``scope_seconds`` (by model scope, ops under none left out), over the
+    planes; without, both are empty."""
     planes = sorted({e["plane"] for e in events
                      if DEVICE_PLANE.match(e["plane"])},
                     key=lambda p: int(DEVICE_PLANE.match(p).group(1)))[:chips]
@@ -152,7 +189,9 @@ def reduce(events, chips=1, spans=(), device_required=True):
                              - min(s for s, _ in ends)) * 1e-9 if ends else 0.0,
                 "busy_s": None, "chips": 0, "op_seconds": {}, "op_stats": {},
                 "op_counts": {}, "top_ops": [], "idle_gaps": [],
-                "span_seconds": dict(span_seconds)}
+                "span_seconds": dict(span_seconds), "steps": 0.0,
+                "kernel_seconds": {}, "kernel_counts": {},
+                "phase_seconds": {}, "scope_seconds": {}}
     dev = {p: [e for e in events
                if e["plane"] == p and e["line"] == OPS_LINE] for p in planes}
     if not any(dev.values()):
@@ -163,6 +202,8 @@ def reduce(events, chips=1, spans=(), device_required=True):
 
     busy, op_seconds, op_stats = [], collections.Counter(), {}
     op_counts = collections.Counter()
+    kernel_seconds, kernel_counts = collections.Counter(), collections.Counter()
+    phase_seconds, scope_seconds = collections.Counter(), collections.Counter()
     merged_first = None
     for p in planes:
         merged = union([(e["start_ns"], e["start_ns"] + e["dur_ns"])
@@ -170,11 +211,26 @@ def reduce(events, chips=1, spans=(), device_required=True):
         if merged_first is None:
             merged_first = merged
         busy.append(sum(e - s for s, e in merged))
-        for e in _leaf_events(dev[p]):
-            op_seconds[e["name"]] += e["dur_ns"] * 1e-9
+        for e, self_ns in _self_times(dev[p]):
+            if self_ns <= 0:
+                continue  # a ``while`` whose body did all the work
+            secs, stats = self_ns * 1e-9, e.get("stats", {})
+            op_seconds[e["name"]] += secs
             op_counts[e["name"]] += 1
-            if "stats" in e:
-                op_stats.setdefault(e["name"], e["stats"])
+            if stats:
+                op_stats.setdefault(e["name"], stats)
+            kernel = stats.get("kernel_metadata", {}).get("kernel")
+            if kernel is None and stats.get(
+                    "custom_call_target") == MOSAIC_TARGET:
+                kernel = base_name(e["name"])
+            if kernel is not None:
+                kernel_seconds[kernel] += secs
+                kernel_counts[kernel] += 1
+            if scopes is not None:
+                sc = scopes.get(e["name"])
+                phase_seconds[sc.part if sc else UNATTRIBUTED] += secs
+                if sc and sc.scope:
+                    scope_seconds[sc.scope] += secs
 
     gaps, cursor = [], t0
     for s, e in merged_first + [[t1, t1]]:
@@ -208,7 +264,26 @@ def reduce(events, chips=1, spans=(), device_required=True):
         "idle_gaps": [[k, v] for k, v in sorted(
             by_span.items(), key=lambda kv: -kv[1])],
         "span_seconds": dict(span_seconds),
+        "steps": module_runs(events, planes),
+        "kernel_seconds": dict(kernel_seconds),
+        "kernel_counts": dict(kernel_counts),
+        "phase_seconds": dict(phase_seconds),
+        "scope_seconds": dict(scope_seconds),
     }
+
+
+def per_step_ms(reduction, table, names):
+    """Milliseconds a step a chip that a reduction booked to ``names`` in
+    ``table`` (``phase_seconds``, ``scope_seconds``, ``kernel_seconds``:
+    summed over the chips, so divided by them and by a chip's ``steps``).
+    None where the trace holds no whole step or nothing under those names:
+    nothing to read is not 0."""
+    if not reduction or not reduction["steps"]:
+        return None
+    secs = sum(reduction[table].get(n, 0.0) for n in names)
+    if secs <= 0:
+        return None
+    return 1e3 * secs / reduction["chips"] / reduction["steps"]
 
 
 def describe(path, top=40):

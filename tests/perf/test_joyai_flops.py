@@ -94,8 +94,8 @@ class _Ctx:
 
 
 def test_readers_find_nothing_on_a_program_without_their_kernels():
-    r = {"op_seconds": {"fusion.1": 1.0}, "op_stats": {}, "op_counts": {},
-         "chips": 1}
+    r = {"kernel_seconds": {"rms_fwd": 1.0}, "kernel_counts": {"rms_fwd": 3},
+         "steps": 2.0, "chips": 1}
     for name in ("mla_attn_roofline", "moe_experts_roofline",
                  "moe_load_max_over_mean.train"):
         reader = load(f"layer_metrics/{name}.py")
@@ -103,15 +103,15 @@ def test_readers_find_nothing_on_a_program_without_their_kernels():
         assert reader.read(_Ctx({"n_layer": 2}, None)) is None
 
 
-def test_readers_read_the_kernels_by_their_instruction_names():
-    call = {"custom_call_target": "tpu_custom_call"}
-    r = {"op_seconds": {"self_attention.3": 2e-6, "gmm.7": 1e-6,
-                        "tgmm.1": 1e-6, "fusion.2": 5.0},
-         "op_stats": {"self_attention.3": call, "gmm.7": call,
-                      "tgmm.1": call},
-         "op_counts": {"self_attention.3": 24, "gmm.7": 10, "tgmm.1": 4},
-         "chips": 1}
-    ctx = _Ctx(TINY, r)  # 24 calls / (3 x 4 blocks) = 2 steps
+def test_readers_read_the_kernels_by_the_programs_names():
+    """The flash kernels by the ``flash_...`` name in their
+    ``kernel_metadata``, the grouped matmuls by their instruction names;
+    steps are the runs of the step's program."""
+    r = {"kernel_seconds": {"flash_fwd": 0.5e-6, "flash_bwd_dq": 0.5e-6,
+                            "flash_bwd_dkv": 1e-6, "mla_rope": 7.0,
+                            "gmm": 1e-6, "tgmm": 1e-6},
+         "steps": 2.0, "chips": 1}
+    ctx = _Ctx(TINY, r)
     ops, nbytes = jf.attention_train_cost(2, 8, 4, TINY)
     least = max(ops / 1e12, nbytes / 1e11)
     got = load("layer_metrics/mla_attn_roofline.py").read(ctx)

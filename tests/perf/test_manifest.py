@@ -150,3 +150,65 @@ def test_layer_names_are_perf_mds(bench):
         text = f.read()
     for m in bench["per_layer"]:
         assert m["layer"] in text, m["layer"]
+
+
+#: the per-layer metrics PR 32 added: (name, unit, better, source, layer,
+#: cells); every one moves train_step_ms
+ALL = ["gpt2_345m.pretrain", "gpt2_345m.pretrain_dp4",
+       "joyai_llm_flash.pretrain"]
+JOYAI = ["joyai_llm_flash.pretrain"]
+PR32 = [
+    ("step_forward_ms.train", "ms", "lower", "device_trace", "step builder",
+     ALL),
+    ("step_backward_ms.train", "ms", "lower", "device_trace", "step builder",
+     ALL),
+    ("step_optimizer_ms.train", "ms", "lower", "device_trace",
+     "step builder", ALL),
+    ("step_guard_ms.train", "ms", "lower", "device_trace", "step builder",
+     ALL),
+    ("step_grad_sync_ms.train", "ms", "lower", "device_trace", "parallel",
+     ["gpt2_345m.pretrain_dp4"]),
+    ("flash_fwd_roofline", "%", "higher", "device_trace", "kernels", ALL),
+    ("flash_bwd_dq_roofline", "%", "higher", "device_trace", "kernels", ALL),
+    ("flash_bwd_dkv_roofline", "%", "higher", "device_trace", "kernels",
+     ALL),
+    ("moe_routed_path_ms.train", "ms", "lower", "device_trace", "model",
+     JOYAI),
+    ("mla_project_ms.train", "ms", "lower", "device_trace", "model", JOYAI),
+    ("moe_compact_share.train", "share", "higher", "program_counter",
+     "model", JOYAI),
+]
+
+
+@pytest.mark.parametrize("name,unit,better,source,layer,cells", PR32,
+                         ids=[m[0] for m in PR32])
+def test_a_new_metric_has_its_entry_and_its_reader(bench, name, unit, better,
+                                                   source, layer, cells):
+    from _bench import load
+
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry == {"name": name, "unit": unit, "better": better,
+                     "source": source, "layer": layer,
+                     "moves": "train_step_ms", "workloads": cells}
+    reader = load(f"layer_metrics/{name}.py")
+    assert callable(reader.read) and reader.__doc__
+    # a reader that finds nothing to read returns nothing, never 0
+    import types
+
+    assert reader.read(types.SimpleNamespace(
+        reduction=None, counters={}, peaks=None, chips=1,
+        config={"n_layer": 2, "n_head": 4, "n_embd": 64},
+        cell={"global_batch": 4, "seq_len": 32},
+        device={"platform": "cpu"})) is None
+
+
+def test_the_metrics_that_stood_before_still_stand(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[:6] == ["mfu_pct.train", "device_idle_pct.train",
+                         "flash_attn_roofline", "mla_attn_roofline",
+                         "moe_experts_roofline",
+                         "moe_load_max_over_mean.train"]
+    assert [m["name"] for m in bench["end_to_end"]] == ["train_step_ms",
+                                                       "setup_s"]
+    assert [m["bound"] for m in bench["end_to_end"]] == [0.01, 0.1]
+    assert bench["run_seconds"] == 50

@@ -94,6 +94,44 @@ def test_a_cell_and_a_metric_are_added_as_files_only(tmp_path):
     assert line["correct"] is True
 
 
+def test_a_slow_profiler_stop_stays_out_of_the_traced_window(
+        tmp_path, monkeypatch):
+    """Stopping the profiler takes seconds on the chip. It happens once the
+    window has returned: ``window_s``, which every rate of a traced run
+    divides by (``mfu_pct.train``), ends where a plain run's ends."""
+    import jax
+
+    real, stop_s = jax.profiler.stop_trace, 5.0
+
+    def slow_stop():
+        time.sleep(stop_s)
+        real()
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    readers = [(f"perf/layer_metrics/{name}.fixture.py",
+                f'"""Fixture."""\n\n\ndef read(ctx):\n    return {what}\n')
+               for name, what in (
+                   ("window_s", 'ctx.counters["window_s"]'),
+                   ("stop_s", "ctx.trace_stop_s"),
+                   ("step_s", 'ctx.counters["window_s"] '
+                              '/ ctx.counters["steps"]'))]
+    cell = "gpt_tiny.pretrain"
+    root = fixture_root(
+        tmp_path, {cell: PRETRAIN_TINY}, {"gpt_tiny": GPT_TINY},
+        TRAIN_METRICS + [layer(f"{n}.fixture", "s", "train_step_ms", [cell])
+                         for n in ("window_s", "stop_s", "step_s")],
+        extra_files=readers)
+    seconds = 0.6
+    traced = _run(root, cell, seconds=seconds, trace=1)["metrics"]
+    assert traced["stop_s.fixture"]["value"] >= stop_s
+    # the window is the --seconds and the step that was under way at their
+    # end, not the five seconds after it
+    assert seconds <= traced["window_s.fixture"]["value"] < seconds + 1.0
+    plain = _run(root, cell, seconds=seconds, trace=0)["metrics"]
+    assert traced["step_s.fixture"]["value"] < 2 * 1e-3 * plain[
+        "train_step_ms"]["value"] + 0.05
+
+
 def test_unknown_cell_and_no_accelerator_are_refused(train_root):
     with pytest.raises(run.Refused, match="no cell"):
         run.run_cell(train_root, "nope.cell", 1, 0.1, 0, allow_cpu=True)
@@ -108,13 +146,13 @@ def _break_train_step(monkeypatch, how):
     """Plant a fault in the program's own step, under the driver."""
     import jax
 
-    from apex_tpu.resilience.replay import targets
+    from apex_tpu import training
 
-    real = targets.build_gpt_training
+    real = training.build_gpt_training
 
     def broken(cfg):
-        training = real(cfg)
-        step = training.train_step
+        built = real(cfg)
+        step = built.train_step
 
         def unchanged(*a):
             out = step(*a)
@@ -128,11 +166,11 @@ def _break_train_step(monkeypatch, how):
             a[5], a[6] = a[5][:1], a[6][:1]
             return step(*a)
 
-        training.train_step = jax.jit(
+        built.train_step = jax.jit(
             {"unchanged": unchanged, "half_batch": half_batch}[how])
-        return training
+        return built
 
-    monkeypatch.setattr(targets, "build_gpt_training", broken)
+    monkeypatch.setattr(training, "build_gpt_training", broken)
 
 
 @pytest.mark.parametrize("how,caught_by", [

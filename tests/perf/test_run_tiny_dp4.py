@@ -52,3 +52,25 @@ def test_the_exchange_left_out_reads_incorrect(root, monkeypatch):
     assert line["correct"] is False
     over = {c["name"] for c in line["compared"] if c["value"] > c["limit"]}
     assert "grad_norm_gap" in over, line["compared"]
+
+
+def test_the_exchange_is_booked_after_the_backward_pass():
+    """What ``step_grad_sync_ms.train`` reads: the compiled four-device
+    step's text has ops under the phase ``grad_sync``, none of them the
+    forward or backward pass's, and places every collective in a phase that
+    follows the backward pass. (The CPU's compiler merges the gradients'
+    all-reduce with the overflow flag's, under the latter's name; on the
+    chip they stand apart: PERF.md 5.)"""
+    hs = load("hlo_scopes.py")
+    drv = load("drivers/gpt_pretrain.py")
+    st = drv.build(DP4_TINY, GPT_TINY)
+    text = drv.hlo_text(st)
+    drv.release(st)
+    scopes = hs.scope_map(text, **drv.scope_names())
+    synced = [s for s in scopes.values() if s.part == "grad_sync"]
+    assert synced and not any("/forward_backward/" in s.op_name
+                              for s in synced)
+    exchanged = {i.name: scopes[i.name].part for i in hs.instructions(text)
+                 if i.opcode.startswith("all-reduce")}
+    assert exchanged and set(exchanged.values()) <= {
+        "grad_sync", "unscale", "guard"}, exchanged

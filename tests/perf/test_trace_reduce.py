@@ -3,12 +3,21 @@ numbers. The recording (fixtures/trace_events.json, nanoseconds):
 
   chip 0, line "XLA Ops":  while.7 [0,100) holding fusion.1 [0,40) and
       self_attention.3 [40,100); fusion.1 [150,250); self_attention.3 [400,500)
-  chip 1, line "XLA Ops":  fusion.9 [0,250)
+      (both self_attention.3 carry kernel_metadata: flash_fwd)
+  chip 1, line "XLA Ops":  fusion.9 [0,250) holding flash_bwd_dq.7 [0,80)
+      (kernel flash_bwd_dq; itself holding custom-call.5 [10,11)), mla_rope.2
+      [80,100) (kernel mla_rope), gmm.4 [100,130) (a Mosaic call that names
+      no kernel) and all-reduce.1 [200,240)
+  line "XLA Modules":  jit_step(77) twice on each chip; on chip 0 also
+      jit_convert_element_type(5) three times, a nanosecond each
   host spans:  fetch_batch [90,160)  step [160,380)  fetch_loss [380,520)
 
 so on chip 0 the device is busy over [0,100) + [150,250) + [400,500) = 300 ns
-of a window [0,520), and idle over [100,150), [250,400) and [500,520).
+of a window [0,520), and idle over [100,150), [250,400) and [500,520); chip 1
+is busy 250 ns, of which fusion.9 itself has 250 - 80 - 20 - 30 - 40 = 80.
 """
+
+import collections
 
 import json
 import os
@@ -48,7 +57,7 @@ def test_busy_is_averaged_over_the_chips_used(events):
     assert r["busy_s"] == pytest.approx((300e-9 + 250e-9) / 2)
 
 
-def test_time_by_name_counts_leaf_events_only(events):
+def test_time_by_name_is_self_time(events):
     r = tr.reduce(events, chips=1, spans=SPANS)
     assert r["op_seconds"]["fusion.1"] == pytest.approx(140e-9)
     assert r["op_seconds"]["self_attention.3"] == pytest.approx(160e-9)
@@ -66,11 +75,12 @@ def test_time_by_name_counts_leaf_events_only(events):
 def test_neighbours_that_overlap_by_rounding_are_not_nested():
     mk = lambda name, s, d: {"plane": "/device:TPU:0", "line": "XLA Ops",
                              "name": name, "start_ns": s, "dur_ns": d}
-    # b starts half a nanosecond before a ends: neighbours, both leaves;
-    # c lies inside b: b is not a leaf
+    # b starts half a nanosecond before a ends: neighbours, neither inside
+    # the other; c lies inside b, which keeps what c does not cover
     evs = [mk("a", 0.0, 100.5), mk("b", 100.0, 100.0), mk("c", 120.0, 50.0)]
     r = tr.reduce(evs, chips=1, spans=())
     assert r["op_seconds"] == {"a": pytest.approx(100.5e-9),
+                               "b": pytest.approx(50e-9),
                                "c": pytest.approx(50e-9)}
     assert r["busy_s"] == pytest.approx(200e-9)
 
@@ -121,8 +131,9 @@ def test_hlo_text_is_split_into_a_name_and_what_tells_kernels_apart():
 
 
 def test_flash_roofline_reader_on_the_recording(events):
-    """Two kernel events = 2/(3*2) of a step of a 2-layer model; 160 ns of
-    device time against the least time for that much attention."""
+    """Two runs of the step's program; 160 ns of device time in kernels the
+    program named ``flash_fwd`` against the least time for two steps'
+    attention."""
     import types
 
     flops = load("flops.py")
@@ -134,11 +145,217 @@ def test_flash_roofline_reader_on_the_recording(events):
         cell={"global_batch": 4, "seq_len": 32})
     ops, nbytes = flops.attention_train_cost(4, 4, 32, 16, 2)
     least, bound = flops.roofline_seconds(ops, nbytes, peaks)
-    assert reader.read(ctx) == pytest.approx(
-        100.0 * least * (2 / 6.0) / 160e-9)
+    assert reader.read(ctx) == pytest.approx(100.0 * least * 2 / 160e-9)
     ctx.peaks = None  # no chip, no share of a roofline
     assert reader.read(ctx) is None
     ctx.peaks, ctx.reduction = peaks, tr.reduce(
         [e for e in events if e["name"] != "self_attention.3"], chips=1,
         spans=SPANS)
     assert reader.read(ctx) is None  # nothing to read is not 0
+
+
+# -- what names a kernel and a phase ------------------------------------------
+
+Scope = collections.namedtuple("Scope", "part scope")
+#: instruction name -> where the compiled step's text would place it
+SCOPES = {
+    "while.7": Scope("forward", ""), "fusion.1": Scope("forward", ""),
+    "self_attention.3": Scope("forward", ""),
+    "fusion.9": Scope("backward", "moe_combine"),
+    "flash_bwd_dq.7": Scope("backward", ""),
+    "mla_rope.2": Scope("backward", "mla_project"),
+    "gmm.4": Scope("backward", "moe_experts"),
+    "all-reduce.1": Scope("grad_sync", ""),
+}
+
+
+def test_split_hlo_keeps_what_the_program_called_the_kernel():
+    raw = ('%self_attention.3 = (bf16[8,1024,1024]{2,1,0}, f32[128,1,1024]) '
+           'custom-call(bf16[128,1024,64]{2,1,0} %bitcast.1), '
+           'custom_call_target="tpu_custom_call", '
+           'operand_layout_constraints={bf16[128,1024,64]{2,1,0}}, '
+           'frontend_attributes={kernel_metadata={\n"kernel":"flash_fwd",\n'
+           '"block_q":"1024",\n"block_k":"512",\n"d_qk":"64",\n"d_v":"64"\n}}')
+    name, stats = tr.split_hlo(raw)
+    assert name == "self_attention.3"
+    assert stats["custom_call_target"] == "tpu_custom_call"
+    assert stats["kernel_metadata"] == {
+        "kernel": "flash_fwd", "block_q": "1024", "block_k": "512",
+        "d_qk": "64", "d_v": "64"}
+    # the library's grouped matmuls print an empty one
+    _, stats = tr.split_hlo('%gmm.4 = bf16[8,8] custom-call(), custom_call_'
+                            'target="tpu_custom_call", frontend_attributes='
+                            '{kernel_metadata={}}')
+    assert stats["kernel_metadata"] == {}
+    assert "kernel_metadata" not in tr.split_hlo(
+        "%fusion.1 = bf16[8] fusion(bf16[8] %p), kind=kLoop")[1]
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_steps_are_the_runs_of_the_program_that_took_the_time(events, chips):
+    # jit_convert_element_type ran three times on chip 0, for 3 ns in all
+    assert tr.reduce(events, chips=chips, spans=SPANS)["steps"] == 2.0
+
+
+def test_steps_without_a_modules_line_are_nought(events):
+    ops = [e for e in events if e["line"] != "XLA Modules"]
+    assert tr.reduce(ops, chips=1, spans=SPANS)["steps"] == 0.0
+    host_only = [e for e in events if e["plane"].startswith("/host")]
+    r = tr.reduce(host_only, chips=1, spans=SPANS, device_required=False)
+    assert r["steps"] == 0.0 and r["kernel_seconds"] == {}
+    assert r["phase_seconds"] == {} and r["scope_seconds"] == {}
+
+
+def test_time_by_kernel_goes_by_the_programs_name(events):
+    r = tr.reduce(events, chips=2, spans=SPANS)
+    assert r["kernel_seconds"] == {
+        "flash_fwd": pytest.approx(160e-9),      # named self_attention.3
+        "flash_bwd_dq": pytest.approx(79e-9),    # 80 less what nests in it
+        "mla_rope": pytest.approx(20e-9),
+        "gmm": pytest.approx(30e-9)}             # by instruction name
+    assert r["kernel_counts"] == {"flash_fwd": 2, "flash_bwd_dq": 1,
+                                  "mla_rope": 1, "gmm": 1}
+    # a parent keeps what its children do not cover
+    assert r["op_seconds"]["fusion.9"] == pytest.approx(80e-9)
+    assert r["op_seconds"]["custom-call.5"] == pytest.approx(1e-9)
+    # without the compiled step's text nothing is said of phases
+    assert r["phase_seconds"] == {} and r["scope_seconds"] == {}
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_phases_add_up_to_busy_time(events, chips):
+    r = tr.reduce(events, chips=chips, spans=SPANS, scopes=SCOPES)
+    assert sum(r["phase_seconds"].values()) == pytest.approx(
+        r["busy_s"] * chips)
+    assert sum(r["op_seconds"].values()) == pytest.approx(
+        r["busy_s"] * chips)
+    if chips == 1:
+        assert r["phase_seconds"] == {"forward": pytest.approx(300e-9)}
+        assert r["scope_seconds"] == {}
+    else:
+        # custom-call.5 is no instruction of the text given
+        assert r["phase_seconds"] == {
+            "forward": pytest.approx(300e-9),
+            "backward": pytest.approx(209e-9),
+            "grad_sync": pytest.approx(40e-9),
+            "(unattributed)": pytest.approx(1e-9)}
+        assert r["scope_seconds"] == {
+            "moe_combine": pytest.approx(80e-9),
+            "mla_project": pytest.approx(20e-9),
+            "moe_experts": pytest.approx(30e-9)}
+
+
+def test_per_step_ms_is_a_chips_and_a_steps(events):
+    r = tr.reduce(events, chips=2, spans=SPANS, scopes=SCOPES)
+    # 209 ns on two chips in two steps
+    assert tr.per_step_ms(r, "phase_seconds", ("backward",)) == (
+        pytest.approx(1e3 * 209e-9 / 2 / 2))
+    assert tr.per_step_ms(r, "phase_seconds", ("unscale", "guard")) is None
+    assert tr.per_step_ms(r, "scope_seconds", ("moe_route", "moe_combine")
+                          ) == pytest.approx(1e3 * 80e-9 / 2 / 2)
+    assert tr.per_step_ms(None, "phase_seconds", ("forward",)) is None
+    no_steps = dict(r, steps=0.0)
+    assert tr.per_step_ms(no_steps, "phase_seconds", ("forward",)) is None
+
+
+def _ctx(events, chips, **kw):
+    import types
+
+    flops = load("flops.py")
+    return types.SimpleNamespace(
+        reduction=tr.reduce(events, chips=chips, spans=SPANS, scopes=SCOPES),
+        peaks=flops.peaks_for("TPU v5 lite"), counters={}, chips=chips,
+        config={"n_layer": 2, "n_head": 4, "n_embd": 64},
+        cell={"global_batch": 4, "seq_len": 32}, **kw)
+
+
+def test_rooflines_read_the_same_whatever_the_instruction_is_called(events):
+    """``name=`` on the flash ``pallas_call``s renames the instructions; the
+    readers go by ``kernel_metadata`` and read what they read. A foreign
+    kernel (``mla_rope``) in the module is no flash call."""
+    reader = load("layer_metrics/flash_attn_roofline.py")
+    before = reader.read(_ctx(events, 2))
+    renamed = [dict(e, name={"self_attention.3": "flash_fwd.3",
+                             "flash_bwd_dq.7": "self_attention.9"}.get(
+                                 e["name"], e["name"])) for e in events]
+    assert reader.read(_ctx(renamed, 2)) == pytest.approx(before)
+    without_rope = [e for e in events if e["name"] != "mla_rope.2"]
+    assert reader.read(_ctx(without_rope, 2)) == pytest.approx(before)
+    flops = load("flops.py")
+    ops, nbytes = flops.attention_train_cost(4, 4, 32, 16, 2)
+    least, _ = flops.roofline_seconds(ops, nbytes, flops.peaks_for(
+        "TPU v5 lite"))
+    # 160 ns of flash_fwd + 79 ns of flash_bwd_dq, two steps
+    assert before == pytest.approx(100.0 * least * 2 / 239e-9)
+
+
+@pytest.mark.parametrize("metric,kernel,secs", [
+    ("flash_fwd_roofline", "flash_fwd", 160e-9),
+    ("flash_bwd_dq_roofline", "flash_bwd_dq", 79e-9),
+])
+def test_each_flash_kernel_has_a_roofline_of_its_own(events, metric, kernel,
+                                                     secs):
+    flops = load("flops.py")
+    reader = load(f"layer_metrics/{metric}.py")
+    ops, nbytes = flops.flash_kernel_costs(4, 4, 32, 16, 16, 2)[kernel]
+    least, _ = flops.roofline_seconds(ops, nbytes, flops.peaks_for(
+        "TPU v5 lite"))
+    assert reader.read(_ctx(events, 2)) == pytest.approx(
+        100.0 * least * 2 / secs)
+    ctx = _ctx(events, 2)
+    ctx.peaks = None  # no chip, no share of a roofline
+    assert reader.read(ctx) is None
+
+
+def test_a_kernel_the_trace_does_not_hold_reads_nothing(events):
+    reader = load("layer_metrics/flash_bwd_dkv_roofline.py")
+    assert reader.read(_ctx(events, 2)) is None  # nothing to read is not 0
+    ctx = _ctx(events, 2)
+    ctx.config = {"hidden_size": 64}  # neither GPT-2's keys nor latent's
+    assert load("layer_metrics/flash_fwd_roofline.py").read(ctx) is None
+
+
+@pytest.mark.parametrize("metric,table,names,ns", [
+    ("step_forward_ms.train", "phase", ("forward",), 300),
+    ("step_backward_ms.train", "phase", ("backward",), 209),
+    ("step_grad_sync_ms.train", "phase", ("grad_sync",), 40),
+    ("step_optimizer_ms.train", "phase", ("optimizer",), None),
+    ("step_guard_ms.train", "phase", ("unscale", "guard"), None),
+    ("moe_routed_path_ms.train", "scope", ("moe_combine",), 80),
+    ("mla_project_ms.train", "scope", ("mla_project",), 20),
+])
+def test_phase_and_scope_readers_on_the_recording(events, metric, table,
+                                                  names, ns):
+    value = load(f"layer_metrics/{metric}.py").read(_ctx(events, 2))
+    if ns is None:
+        assert value is None  # the recording holds no such phase
+    else:
+        assert value == pytest.approx(1e3 * ns * 1e-9 / 2 / 2)
+    bare = _ctx(events, 2)
+    bare.reduction = tr.reduce(events, chips=2, spans=SPANS)  # no text
+    assert load(f"layer_metrics/{metric}.py").read(bare) is None
+
+
+def test_grouped_matmuls_go_by_instruction_name_and_the_counted_rows(events):
+    joyai = load("joyai_flops.py")
+    flops = load("flops.py")
+    cfg = {"layers_kept": 2, "num_nextn_predict_layers": 1,
+           "first_k_dense_replace": 1, "hidden_size": 64,
+           "moe_intermediate_size": 32, "n_routed_experts": 4}
+    ctx = _ctx(events, 2)
+    ctx.config, ctx.counters = cfg, {"moe_rows_here_per_step": 512.0}
+    ops, nbytes = joyai.experts_train_cost(cfg, 512.0, 2)
+    least, _ = flops.roofline_seconds(ops, nbytes, ctx.peaks)
+    reader = load("layer_metrics/moe_experts_roofline.py")
+    assert reader.read(ctx) == pytest.approx(100.0 * least * 2 / 30e-9)
+    ctx.counters = {}
+    assert reader.read(ctx) is None
+
+
+def test_the_compact_share_is_the_programs_counter():
+    import types
+
+    reader = load("layer_metrics/moe_compact_share.train.py")
+    assert reader.read(types.SimpleNamespace(
+        counters={"moe_compact_share": 0.875})) == 0.875
+    assert reader.read(types.SimpleNamespace(counters={})) is None
