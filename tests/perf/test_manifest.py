@@ -208,7 +208,7 @@ def test_the_metrics_that_stood_before_still_stand(bench):
                          "flash_attn_roofline", "mla_attn_roofline",
                          "moe_experts_roofline",
                          "moe_load_max_over_mean.train"]
-    assert [m["name"] for m in bench["end_to_end"]] == ["train_step_ms",
-                                                       "setup_s"]
-    assert [m["bound"] for m in bench["end_to_end"]] == [0.01, 0.1]
+    assert [m["name"] for m in bench["end_to_end"]][:2] == ["train_step_ms",
+                                                           "setup_s"]
+    assert [m["bound"] for m in bench["end_to_end"]][:2] == [0.01, 0.1]
     assert bench["run_seconds"] == 50
