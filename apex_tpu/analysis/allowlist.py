@@ -734,6 +734,18 @@ _CONCURRENCY = [
     ),
     AllowlistEntry(
         rule="concurrency.unbounded-wait",
+        match="apex_tpu/ops/attention.py",
+        reason=(
+            "not a host wait: the paged decode kernel's cp.wait() is a "
+            "Pallas DMA descriptor's wait on its semaphore, traced into "
+            "the Mosaic kernel body (_paged_decode_kernel) — every wait "
+            "follows the start of the same copy in program order, and a "
+            "DMA semaphore has no timeout to give"
+        ),
+        require_hit=True,
+    ),
+    AllowlistEntry(
+        rule="concurrency.unbounded-wait",
         match="apex_tpu/utils/autoresume.py",
         reason=(
             "the durability barrier: _commit's self._writer.wait() "

@@ -91,12 +91,14 @@ MODEL_SCOPES = (
 #: dk/dv), ops/layer_norm.py (LayerNorm and RMSNorm, forward and
 #: backward), optimizers/_fused_kernels.py (flat Adam, flat sum of
 #: squares); ``mla_rope`` is ops/attention.py's rotation of latent
-#: attention's rope queries in the projection's own layout.
+#: attention's rope queries in the projection's own layout, and
+#: ``paged_decode`` its decode attention over the serving engine's KV pool.
 KERNELS = (
     "flash_fwd",
     "flash_bwd_dq",
     "flash_bwd_dkv",
     "mla_rope",
+    "paged_decode",
     "ln_fwd",
     "ln_bwd",
     "rms_fwd",

@@ -42,7 +42,7 @@ digit-for-digit:
   SECOND stash of deferred-W inputs (the schedule's documented memory
   price for its zero bubble);
 - the serving KV pool per ``serving/kvcache.CacheSpec.pool_shapes``:
-  one ``(num_blocks, h_kv, block_size, head_dim)`` pool per cached
+  one ``(num_blocks, block_size, h_kv * head_dim)`` pool per cached
   K and V leaf.
 """
 
@@ -562,7 +562,7 @@ def kv_pool_bytes(
     block_size: int,
     cache_dtype: str = "bfloat16",
 ) -> int:
-    """The serving block pool: one ``(num_blocks, h_kv, block_size,
+    """The serving block pool: one ``(num_blocks, block_size, h_kv *
     head_dim)`` array per cached K and per cached V leaf, one K/V pair
     per layer (``CacheSpec.pool_shapes``)."""
     per_leaf = num_blocks * num_kv_heads * block_size * head_dim

@@ -34,7 +34,11 @@ from apex_tpu.ops.rope import (
 from apex_tpu.ops.xentropy import softmax_cross_entropy_loss
 from apex_tpu.ops.fused_dense import fused_dense, fused_dense_gelu_dense
 from apex_tpu.ops.mlp import mlp_apply, mlp_init
-from apex_tpu.ops.attention import flash_attention, latent_flash_attention
+from apex_tpu.ops.attention import (
+    flash_attention,
+    latent_flash_attention,
+    paged_decode_attention,
+)
 
 __all__ = [
     "CHUNK_SIZE",
@@ -63,4 +67,5 @@ __all__ = [
     "mlp_init",
     "flash_attention",
     "latent_flash_attention",
+    "paged_decode_attention",
 ]

@@ -38,6 +38,14 @@ rope): the kernels' bodies loop over a list of (q part, k part) that has
 one member for ``flash_attention`` (``_operands``). One more kernel,
 ``mla_rope``, rotates the queries' rope part where it lies.
 
+Serving decode (``paged_decode_attention``): one query token a lane against
+the serving engine's block pool (``serving/kvcache.py``), read where it lies
+by block table and length. Heads ride in the lanes here too: a pool block
+is (block_size, h_kv * hd), one contiguous copy, and each query head sits
+in its kv head's lanes of a (heads, h_kv * hd) operand, so one product a
+chunk of keys scores every head. Forward only (no gradient flows through a
+decode step).
+
 Single-chip long context: K/V residency caps the kernel at
 ``_KV_RESIDENT_BYTES`` (below 14k bf16 / 7k fp32 keys at head_dim <= 128).
 Beyond it — or when the XLA fallback's full (sq, sk) score tensor would blow
@@ -1389,3 +1397,231 @@ def latent_flash_attention(
         causal=True, scale=scale, key_padding_mask=key_padding_mask,
         impl=impl, block_q=block_q, block_k=block_k)
     return jnp.swapaxes(o, 1, 2).reshape(b, s, heads * d_v)
+
+
+# -- decode attention over a paged KV pool ----------------------------------
+
+# keys one step of the paged kernel's loop fetches and attends (whole pool
+# blocks: a block with all its heads is one contiguous copy): on the chip
+# 256 beat 128 and 512 on ragged lanes (PERF.md 6, PR 34); fewer where the
+# two double-buffered chunks would pass their share of VMEM
+_PAGED_CHUNK_KEYS = 256
+_PAGED_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, *, scale, window, bs, chunk,
+                         max_blocks, hd, group):
+    """One lane: its query rows against the keys its block table names, a
+    chunk of ``chunk`` pool blocks at a time, double-buffered. ``q_ref`` is
+    (1, rows, h_kv * hd): row i holds query head i in its kv head's lanes
+    and zeros beside them, so one product with a (keys, h_kv * hd) chunk
+    gives every head's scores; the pools stay in HBM, a block (bs,
+    h_kv * hd). The loop runs over the chunks the lane's length (and
+    window) covers and no further."""
+    lane = pl.program_id(0)
+    length = lengths_ref[lane]
+    keys = chunk * bs
+    lo = (0 if window is None
+          else jax.lax.div(jnp.maximum(length - window, 0), keys))
+    hi = jax.lax.div(length + keys - 1, keys)
+
+    def copies(i, slot):
+        """The DMAs of chunk ``i`` into buffer ``slot``: whole chunks, so
+        past the lane's last block they fetch what the (clipped) table
+        names there, bytes the length masks."""
+        out = []
+        for c in range(chunk):
+            col = jnp.minimum(i * chunk + c, max_blocks - 1)
+            blk = tables_ref[lane * max_blocks + col]
+            rows = pl.ds(c * bs, bs)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[blk], k_buf.at[slot, rows], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[blk], v_buf.at[slot, rows], sems.at[1, slot]))
+        return out
+
+    @pl.when(lo < hi)
+    def _():
+        for cp in copies(lo, jax.lax.rem(lo, 2)):
+            cp.start()
+
+    q = q_ref[0]
+    rows, width = q.shape
+
+    def body(i, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < hi)
+        def _():
+            for cp in copies(i + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(i, slot):
+            cp.wait()
+        s = _dot(q, k_buf[slot], _NT) * scale  # (rows, keys), fp32
+        at = i * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = at < length
+        if window is not None:
+            keep = jnp.logical_and(keep, at >= length - window)
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        v = v_buf[slot]
+        return (m_new, l * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + _dot(p.astype(v.dtype), v, _NN))
+
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, (
+        jnp.full((rows, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+        jnp.zeros((rows, width), jnp.float32)))
+    # a row that saw no key (length 0) is zero, as the flash kernels' dead rows
+    o = jnp.where(m <= _NEG_INF * 0.5, 0.0, acc / jnp.maximum(l, 1e-30))
+    # head i's output lies in its kv head's lanes: row j of the result
+    # holds, for every kv head, the j-th query head of its group
+    row = jax.lax.broadcasted_iota(jnp.int32, o.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, o.shape, 1)
+    own = col // hd == row // group
+    for j in range(group):
+        mine = jnp.logical_and(own, row % group == j)
+        o_ref[0, j:j + 1, :] = jnp.sum(
+            jnp.where(mine, o, 0.0), axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=32)
+def _paged_decode_call(lanes, rows, group, hd, bs, width, max_blocks, dtype,
+                       scale, window, interpret):
+    """The ``pallas_call`` of one static configuration (cached: a model
+    makes the same call once a layer)."""
+    keys = min(_PAGED_CHUNK_KEYS,
+               _PAGED_VMEM_BYTES // (4 * width * dtype.itemsize))
+    chunk = max(1, min(keys // bs, max_blocks))
+    buf = pltpu.VMEM((2, chunk * bs, width), dtype)
+    return pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, scale=scale, window=window, bs=bs,
+            chunk=chunk, max_blocks=max_blocks, hd=hd, group=group),
+        out_shape=jax.ShapeDtypeStruct((lanes, group, width), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(lanes,),
+            in_specs=[
+                pl.BlockSpec((1, rows, width), lambda b, t, n: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, group, width),
+                                   lambda b, t, n: (b, 0, 0)),
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        interpret=interpret,
+        name="paged_decode",
+        metadata=kernel_metadata("paged_decode", chunk_keys=chunk * bs,
+                                 d_qk=hd, d_v=hd),
+    )
+
+
+def _paged_decode_xla(q, k_pool, v_pool, tables, lengths, scale, window):
+    """The same attention in plain XLA: a scan over the lanes' table
+    columns, each step one pool block a lane (gathered as the table names
+    it, so a block named twice counts twice, as in the kernel) folded into
+    an online softmax. The largest array is one block a lane: no lane's
+    window is ever gathered whole."""
+    lanes, heads, hd = q.shape
+    bs = k_pool.shape[1]
+    h_kv = k_pool.shape[2] // hd
+    q4 = q.reshape(lanes, h_kv, heads // h_kv, hd)
+    n = lengths[:, None, None, None]
+
+    def block(carry, j):
+        m, l, acc = carry
+        k, v = (pool[tables[:, j]].reshape(lanes, bs, h_kv, hd)
+                for pool in (k_pool, v_pool))
+        s = jnp.einsum("lgjd,ltgd->lgjt", q4, k,
+                       preferred_element_type=jnp.float32) * scale
+        at = j * bs + jnp.arange(bs)
+        keep = at < n
+        if window is not None:
+            keep = jnp.logical_and(keep, at >= n - window)
+        s = jnp.where(keep, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + jnp.einsum(
+                    "lgjt,ltgd->lgjd", p.astype(q.dtype), v,
+                    preferred_element_type=jnp.float32)), None
+
+    stat = q4.shape[:3] + (1,)
+    (m, l, acc), _ = jax.lax.scan(block, (
+        jnp.full(stat, _NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+        jnp.zeros(q4.shape, jnp.float32)), jnp.arange(tables.shape[1]))
+    # a lane that saw no key (length 0) is zero, as in the kernel
+    out = jnp.where(m <= _NEG_INF * 0.5, 0.0, acc / jnp.maximum(l, 1e-30))
+    return out.astype(q.dtype).reshape(q.shape)
+
+
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
+                           scale: float, window: int = None,
+                           impl: str = "auto"):
+    """One decode token a lane against the keys its block table names, read
+    from the pool where they lie. ``q``: (lanes, heads, hd); the pools:
+    (num_blocks, block_size, h_kv * hd), a block's keys as rows, kv head g
+    in lanes [g * hd, (g + 1) * hd), ``heads % h_kv == 0`` (consecutive
+    grouping, as ``flash_attention``); ``block_tables``: (lanes,
+    max_blocks_per_lane) int32, lane-local block j -> pool block;
+    ``lengths``: (lanes,) int32, the keys the lane attends: positions
+    [0, length), or with a sliding ``window`` the last ``window`` of them.
+    Returns (lanes, heads, hd). Scores, softmax statistics and the P.V sum
+    in float32.
+
+    Every table entry is clipped into the pool before it addresses memory,
+    so an out-of-range entry (the engine's sentinel ``num_blocks``) inside
+    a lane's length reads another block's bytes and never faults; a length
+    of 0 gives zeros.
+
+    On a TPU a Pallas kernel (``paged_decode``): table and lengths are
+    scalar-prefetch operands, a lane's blocks come by DMA a chunk of
+    ``_PAGED_CHUNK_KEYS`` keys at a time, double-buffered, and the loop
+    ends at the lane's length, so a lane costs what it holds. It needs
+    whole tiles: h_kv * hd a multiple of 128 lanes, block_size of the
+    dtype's sublane tile, one dtype. Any other call, ``impl="xla"`` and
+    ``auto`` off the TPU compute the same in plain XLA, a block a lane at a
+    time (``_paged_decode_xla``): no gather of a lane's window on either
+    path."""
+    lanes, heads, hd = q.shape
+    nb, bs, width = k_pool.shape
+    if width % hd or heads % (width // hd):
+        raise ValueError(
+            f"pool rows {width} wide hold no whole number of {hd}-wide kv "
+            f"heads that divides the {heads} query heads")
+    h_kv = width // hd
+    group = heads // h_kv
+    tables = jnp.clip(block_tables, 0, nb - 1).astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    use_pallas, interpret = resolve_impl(impl)
+    whole_tiles = (
+        width % 128 == 0
+        and bs % (32 // jnp.dtype(k_pool.dtype).itemsize) == 0
+        and q.dtype == k_pool.dtype == v_pool.dtype
+    )
+    if not (use_pallas and whole_tiles):
+        return _paged_decode_xla(q, k_pool, v_pool, tables, lengths, scale,
+                                 window)
+    # query head i into the lanes of its kv head, zeros beside them; rows
+    # padded to the bf16 sublane tile
+    rows = -(-heads // 16) * 16
+    in_group = (jnp.arange(heads)[:, None] // group
+                == jnp.arange(h_kv)[None, :])
+    q_rows = jnp.where(in_group[None, :, :, None], q[:, :, None, :],
+                       jnp.zeros((), q.dtype)).reshape(lanes, heads, width)
+    q_rows = jnp.pad(q_rows, ((0, 0), (0, rows - heads), (0, 0)))
+    out = _paged_decode_call(
+        lanes, rows, group, hd, bs, width, tables.shape[1],
+        jnp.dtype(q.dtype), float(scale), window, interpret,
+    )(tables.reshape(-1), lengths, q_rows, k_pool, v_pool)
+    # (lanes, group, h_kv * hd): member j of every kv head's group
+    return out.reshape(lanes, group, h_kv, hd).transpose(0, 2, 1, 3).reshape(
+        lanes, heads, hd)

@@ -12,10 +12,14 @@ admits up to ``max_prefills_per_tick`` queued requests (one compiled
 prefill each, bucketed by prompt length), then advances EVERY in-flight
 request by one token through ONE compiled decode step — requests join
 and leave the batch at tick granularity, no waiting for stragglers to
-finish a "batch". Per-lane state (its own ``cache_index``, block table
-and sampling temperature) is threaded through a ``jax.vmap`` of the
-model's single-sequence decode, so the model's cache machinery is
-reused unchanged and per-request positions diverge freely.
+finish a "batch". The decode step calls the model ONCE for a batch of
+``lanes`` tokens over the KV pool where it lies (kvcache.py): per-lane
+state (its position, block table and sampling temperature) rides in the
+paged cache the attention layers are handed, each layer writes every
+lane's new key and value into its one pool slot and attends by block
+table and length (``ops.paged_decode_attention``), so per-request
+positions diverge freely, nothing is gathered into a contiguous window,
+and a lane costs what it holds (``stats()["decode_keys_read_share"]``).
 
 Zero steady-state recompiles: prefill shapes are BUCKETED (block-size
 multiples, doubling up to ``max_seq_len``) and every bucket plus the
@@ -102,8 +106,8 @@ class ServingConfig:
 
     ``lanes`` bounds concurrent in-flight decodes; ``num_blocks`` x
     ``block_size`` tokens is the whole KV pool; ``max_seq_len`` caps one
-    request's prompt+generation (and is each lane's contiguous decode
-    view, so it must divide into blocks). ``prefill_buckets`` (derived
+    request's prompt+generation (and is what a lane's block table spans,
+    so it must divide into blocks). ``prefill_buckets`` (derived
     when None: block-size multiples doubling up to ``max_seq_len``) are
     the ONLY prompt shapes ever compiled. ``ttft_budget_s`` arms the
     admission-time TTFT estimate — beyond it, submissions shed with
@@ -233,6 +237,8 @@ class ServingEngine:
         self._prefill_ema: Optional[float] = None
         self._decode_ema: Optional[float] = None
         self._steady_compiles = 0
+        self._decode_ticks = 0
+        self._decode_keys = 0  # keys the lanes' lengths covered, all ticks
         self._compile_watch = None
         self._spec: Optional[CacheSpec] = None
         self._pool = None
@@ -373,7 +379,6 @@ class ServingEngine:
         from apex_tpu.models.generate import sample_next_token
 
         cfg, spec, model = self.config, self._spec, self.model
-        n_pb = P // cfg.block_size
 
         def prefill(pool, variables, tokens, true_len, block_ids, temp, key):
             logits, st = model.apply(
@@ -393,15 +398,11 @@ class ServingEngine:
             kv = spec.kv_from_cache(st["cache"])
             new_pool = dict(pool)
             for k, leaf in kv.items():
-                # (1, h_kv, P, hd) -> (P/bs blocks, h_kv, bs, hd);
                 # out-of-range sentinel ids drop their (unreserved,
                 # fully-padded) blocks on the scatter
-                h_kv, hd = leaf.shape[1], leaf.shape[3]
-                blocks = leaf[0].reshape(
-                    h_kv, n_pb, cfg.block_size, hd
-                ).transpose(1, 0, 2, 3)
                 new_pool[k] = pool[k].at[block_ids].set(
-                    blocks.astype(pool[k].dtype), mode="drop"
+                    spec.to_blocks(leaf, cfg.block_size).astype(
+                        pool[k].dtype), mode="drop"
                 )
             out = (new_pool, tok.astype(jnp.int32), key)
             if cfg.collect_logits:
@@ -417,66 +418,34 @@ class ServingEngine:
         from apex_tpu.models.generate import sample_next_token
 
         cfg, spec, model = self.config, self._spec, self.model
-        bs, nb, MB = cfg.block_size, cfg.num_blocks, cfg.max_blocks_per_lane
-        kv_keys = [CacheSpec.key(l.path) for l in spec.kv_leaves]
 
         def decode(pool, variables, tables, positions, tokens, temps, keys,
                    active):
-            def lane(table, pos, tok, temp, key):
-                safe = jnp.clip(table, 0, nb - 1)
-                kv = {}
-                for k in kv_keys:
-                    g = pool[k][safe]  # (MB, h_kv, bs, hd)
-                    h_kv, hd = g.shape[1], g.shape[3]
-                    kv[k] = g.transpose(1, 0, 2, 3).reshape(
-                        h_kv, MB * bs, hd
-                    )[None]
-                cache = spec.build_cache(kv, jnp.asarray(pos, jnp.int32))
-                logits, upd = model.apply(
-                    {**variables, "cache": cache},
-                    tok[None, None],
-                    position_ids=pos[None, None],
-                    cache_len=cfg.max_seq_len,
-                    decode_step=True,
-                    mutable=["cache"],
-                )
-                # only the block containing slot `pos` changed — scatter
-                # exactly it back; the rest of the lane's view is the
-                # pool's own bytes round-tripping
-                blk = pos // bs
-                off = blk * bs
-                new_kv = spec.kv_from_cache(upd["cache"])
-                written = []
-                for k in kv_keys:
-                    leaf = new_kv[k]  # (1, h_kv, max_seq_len, hd)
-                    h_kv, hd = leaf.shape[1], leaf.shape[3]
-                    written.append(jax.lax.dynamic_slice(
-                        leaf, (0, 0, off, 0), (1, h_kv, bs, hd)
-                    )[0])
-                key, sub = jax.random.split(key)
-                last = logits[0, 0].astype(jnp.float32)
-                nxt = sample_next_token(
-                    last, temp, sub, top_k=cfg.top_k, top_p=cfg.top_p
-                )
-                out = (nxt.astype(jnp.int32), table[blk], tuple(written),
-                       key)
-                if cfg.collect_logits:
-                    out = out + (last,)
-                return out
-
-            res = jax.vmap(lane)(tables, positions, tokens, temps, keys)
-            nxts, blk_ids, written, new_keys = res[:4]
-            # inactive lanes compute garbage (static batch); their writes
-            # are dropped via the out-of-range sentinel
-            blk_ids = jnp.where(active, blk_ids, nb)
-            new_pool = dict(pool)
-            for i, k in enumerate(kv_keys):
-                new_pool[k] = pool[k].at[blk_ids].set(
-                    written[i].astype(pool[k].dtype), mode="drop"
-                )
-            out = (new_pool, nxts, new_keys)
+            # ONE call of the model for all lanes, over the pool where it
+            # lies (kvcache.py): every attention layer writes each lane's
+            # new key and value into its slot and attends by table and
+            # length. Inactive lanes compute garbage (static batch); an
+            # all-sentinel table drops their writes
+            tables = jnp.where(active[:, None], tables, cfg.num_blocks)
+            logits, upd = model.apply(
+                {**variables,
+                 "cache": spec.paged_cache(pool, tables, positions)},
+                tokens[:, None],
+                position_ids=positions[:, None],
+                cache_len=cfg.max_seq_len,
+                decode_step=True,
+                mutable=["cache"],
+            )
+            last = logits[:, 0].astype(jnp.float32)
+            split = jax.vmap(jax.random.split)(keys)
+            nxts = jax.vmap(
+                lambda row, temp, sub: sample_next_token(
+                    row, temp, sub, top_k=cfg.top_k, top_p=cfg.top_p)
+            )(last, temps, split[:, 1])
+            out = (spec.pool_from_cache(upd["cache"]),
+                   nxts.astype(jnp.int32), split[:, 0])
             if cfg.collect_logits:
-                out = out + (res[4],)
+                out = out + (last,)
             return out
 
         return decode
@@ -787,6 +756,11 @@ class ServingEngine:
 
     def _run_decode(self, t: int) -> None:
         cfg = self.config
+        # what the decode step reads of the pool: every active lane's keys
+        # [0, position], and nothing of an idle lane
+        self._decode_ticks += 1
+        self._decode_keys += int(self._positions[self._lane_mask].sum()
+                                 + self._lane_mask.sum())
         t0 = time.perf_counter()
         try:
             with span("decode", router=self.router, step=t):
@@ -1063,7 +1037,12 @@ class ServingEngine:
     def stats(self) -> dict:
         """Aggregate serving outcome (docs/serving.md): per-terminal
         counts, shed reasons, TTFT percentiles over requests that got a
-        first token, and the zero-recompile violation counter."""
+        first token, the zero-recompile violation counter, and
+        ``decode_keys_read_share``: over the decode ticks so far, the keys
+        the lanes' lengths covered over ``lanes * max_seq_len``, i.e. the
+        share of a fixed full window per lane that decode attention, which
+        stops at each lane's length, had to read (None before the first
+        decode tick)."""
         from apex_tpu.serving.loadgen import percentile
 
         counts: Dict[str, int] = {}
@@ -1095,4 +1074,8 @@ class ServingEngine:
             "steady_state_compiles": self._steady_compiles,
             "free_blocks": self.allocator.free_blocks,
             "kv_pool_peak_blocks": self.allocator.peak_used_blocks,
+            "decode_keys_read_share": (
+                self._decode_keys / (self._decode_ticks * self.config.lanes
+                                     * self.config.max_seq_len)
+                if self._decode_ticks else None),
         }

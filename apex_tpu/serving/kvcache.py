@@ -6,18 +6,22 @@ cache sized for every lane's worst case, the cache is a POOL of
 fixed-size blocks (``block_size`` token slots each, the vLLM paged-KV
 idea at allocation granularity):
 
-- each layer's ``cached_key`` / ``cached_value`` live as
-  ``(num_blocks, h_kv, block_size, head_dim)`` arrays — ONE donated
-  pytree threaded through the compiled prefill/decode steps, so
-  steady-state serving reuses the same HBM in place;
+- each layer's keys and values live as ``(num_blocks, block_size,
+  h_kv * head_dim)`` arrays (a block's token slots as rows, kv head g in
+  lanes ``[g * head_dim, (g + 1) * head_dim)``: one block with all its
+  heads is one contiguous, lane-dense copy) — ONE donated pytree threaded
+  through the compiled prefill/decode steps, so steady-state serving
+  reuses the same HBM in place;
 - each admitted request owns a BLOCK TABLE row: lane-local block ``j``
   maps to pool block ``table[j]``. Unreserved entries carry the
-  out-of-range sentinel ``num_blocks`` — gathers clip them onto an
-  arbitrary in-range block (``num_blocks - 1``), whose stale bytes are
-  safe NOT because of which block it is but because the decode validity
-  mask excludes them: lane positions beyond the request's reservation
-  are always ``> cache_index``. Scatters drop sentinel entries outright
-  (``mode="drop"``);
+  out-of-range sentinel ``num_blocks``. The sentinel rule: CLIPPED ON
+  READ (decode attention clips every entry into the pool before it
+  addresses memory, so a bad table reads another block's bytes and never
+  faults), DROPPED ON WRITE (the prefill scatter and the decode step's
+  one-slot write use ``mode="drop"``: an inactive lane, whose table is all
+  sentinel, writes nothing), MASKED BY LENGTH (a lane attends positions
+  ``[0, position]`` only, and a request's reservation always covers them,
+  so no sentinel entry lies inside a sound lane's length);
 - the host-side :class:`BlockAllocator` hands out blocks atomically
   (all-or-nothing) and admission reserves a request's WORST CASE
   (``ceil((prompt+max_new)/block_size)``, plus the prefill bucket's
@@ -27,16 +31,22 @@ idea at allocation granularity):
   queue-wait the admission TTFT estimate absorbs. The cost is bucket-
   granularity over-reservation, documented in docs/serving.md.
 
-The compiled steps reuse the MODEL's own cache machinery
-(transformer/layer.py "cache" variables) unchanged: per lane, the pool
-blocks are gathered into the contiguous per-layer layout the model
-expects, the model's prefill/decode writes into that contiguous view,
-and only the touched block is scattered back. :class:`CacheSpec` is the
-bridge — it records, from one ``jax.eval_shape`` of a prefill, which
-cache leaves are K/V payload and which are the scalar ``cache_index``
-bookkeeping, and refuses cache layouts it does not understand
-(context-parallel ``prompt_len_local``, future variables) rather than
-guessing.
+Decode reads the pool IN PLACE. The model's attention layer
+(transformer/layer.py) picks its decode branch by the KIND of cache it is
+handed: the engine hands every attention module the paged kind —
+``key_pool`` / ``value_pool`` (the pool leaves themselves), ``block_table``
+(lanes, max_blocks_per_lane) and ``cache_index`` (per-lane positions) —
+and the layer writes each lane's new token into its one slot and attends
+by table and length (``ops.paged_decode_attention``); nothing is gathered
+into a contiguous window and nothing is scattered back. Prefill still runs
+the model's own contiguous cache for one prompt and scatters its blocks
+into the pool. :class:`CacheSpec` is the bridge and the one place that
+says how the model's cache maps to the pool — it records, from one
+``jax.eval_shape`` of a prefill, which cache leaves are K/V payload and
+which are the scalar ``cache_index`` bookkeeping, builds the paged cache
+dict and reads the updated pool back out of it, and refuses cache layouts
+it does not understand (context-parallel ``prompt_len_local``, future
+variables) rather than guessing.
 """
 
 import dataclasses
@@ -124,10 +134,11 @@ class CacheSpec:
 
     Built once from an abstract prefill (:meth:`from_cache_shapes`);
     thereafter :meth:`pool_shapes` names the pool leaves (keyed by the
-    joined cache path — a flat dict is the donated pytree), and the
-    engine's compiled steps use the path lists to (a) rebuild the
-    nested cache dict the model expects from gathered pool blocks and
-    (b) pick the written block back out of the model's updated cache.
+    joined cache path — a flat dict is the donated pytree), prefill turns
+    the model's contiguous K/V into pool blocks (:meth:`kv_from_cache`,
+    :meth:`to_blocks`), and decode hands the model the pool itself as a
+    paged cache (:meth:`paged_cache`) and takes the updated pool back
+    (:meth:`pool_from_cache`).
     """
 
     kv_leaves: Tuple[CacheLeaf, ...]
@@ -180,42 +191,59 @@ class CacheSpec:
     def key(path: Tuple[str, ...]) -> str:
         return "/".join(path)
 
+    #: a prefill K/V leaf's name -> the paged cache variable the attention
+    #: layer reads the pool under (transformer/layer.py's paged branch)
+    PAGED_NAMES = {"cached_key": "key_pool", "cached_value": "value_pool"}
+
     def pool_shapes(self, num_blocks: int,
                     block_size: int) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
-        """``{pool_key: ((num_blocks, h_kv, block_size, hd), dtype)}``."""
+        """``{pool_key: ((num_blocks, block_size, h_kv * hd), dtype)}``."""
         out = {}
         for leaf in self.kv_leaves:
             _, h_kv, _, hd = leaf.shape
             out[self.key(leaf.path)] = (
-                (int(num_blocks), h_kv, int(block_size), hd), leaf.dtype
+                (int(num_blocks), int(block_size), h_kv * hd), leaf.dtype
             )
         return out
 
-    def build_cache(self, kv_arrays: Dict[str, Any], index_value) -> dict:
-        """The nested cache dict the model expects, from per-leaf
-        contiguous K/V arrays (keyed like :meth:`pool_shapes`) and the
-        per-lane ``cache_index`` scalar."""
+    @staticmethod
+    def to_blocks(leaf, block_size: int):
+        """A prefill's contiguous K or V, (1, h_kv, P, hd), as P /
+        block_size pool blocks (P / block_size, block_size, h_kv * hd)."""
+        _, h_kv, P, hd = leaf.shape
+        return leaf[0].transpose(1, 0, 2).reshape(
+            P // block_size, block_size, h_kv * hd)
+
+    def paged_cache(self, pool: Dict[str, Any], tables, positions) -> dict:
+        """The cache dict of a decode step over the pool: beside every
+        attention module's two pool leaves, the lanes' block tables and
+        positions (the same two arrays for every layer)."""
         cache: dict = {}
-
-        def insert(path, value):
-            node = cache
-            for k in path[:-1]:
-                node = node.setdefault(k, {})
-            node[path[-1]] = value
-
         for leaf in self.kv_leaves:
-            insert(leaf.path, kv_arrays[self.key(leaf.path)])
-        for leaf in self.index_leaves:
-            insert(leaf.path, index_value)
+            node = cache
+            for k in leaf.path[:-1]:
+                node = node.setdefault(k, {})
+            node[self.PAGED_NAMES[leaf.path[-1]]] = pool[self.key(leaf.path)]
+            node["block_table"] = tables
+            node["cache_index"] = positions
         return cache
 
+    def pool_from_cache(self, cache: dict) -> Dict[str, Any]:
+        """The pool leaves of a (decode-updated) paged cache dict, keyed
+        like :meth:`pool_shapes`."""
+        return self._leaves_of(cache, self.PAGED_NAMES)
+
     def kv_from_cache(self, cache: dict) -> Dict[str, Any]:
-        """Extract the K/V leaves of a (possibly updated) cache dict,
-        keyed like :meth:`pool_shapes`."""
+        """The K/V leaves of a prefill's contiguous cache dict, keyed like
+        :meth:`pool_shapes`."""
+        return self._leaves_of(cache, {})
+
+    def _leaves_of(self, cache: dict, rename: Dict[str, str]) -> Dict[str, Any]:
         out = {}
         for leaf in self.kv_leaves:
             node = cache
-            for k in leaf.path:
+            for k in leaf.path[:-1]:
                 node = node[k]
-            out[self.key(leaf.path)] = node
+            name = leaf.path[-1]
+            out[self.key(leaf.path)] = node[rename.get(name, name)]
         return out

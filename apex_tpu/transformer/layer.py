@@ -274,7 +274,14 @@ class ParallelAttention(nn.Module):
             if attention_mask is not None or key_padding_mask is not None:
                 raise NotImplementedError("KV-cache decode computes its own "
                                           "masks")
-            if decode_step and not self.has_variable("cache", "cached_key"):
+            # the KIND of cache the step is handed picks its decode branch:
+            # this module's own contiguous cached_key / cached_value with a
+            # scalar cache_index (models.generate, cp decode), or a serving
+            # engine's paged pool (key_pool / value_pool, a block table and
+            # per-lane positions: serving/kvcache.py)
+            paged = decode_step and self.has_variable("cache", "key_pool")
+            if (decode_step and not paged
+                    and not self.has_variable("cache", "cached_key")):
                 raise ValueError("decode_step before prefill: call once with "
                                  "cache_len=<total length> first")
 
@@ -381,11 +388,18 @@ class ParallelAttention(nn.Module):
                 # absolute positions [pos0, pos0 + sq).  sq comes from q,
                 # not the layer input: under SP the column linear has
                 # already gathered the sequence, so q is s_global long
-                pos0 = cache_index if decode_step else 0
-                q_pos_emb = jax.lax.dynamic_slice_in_dim(
-                    q_pos_emb, pos0, q.shape[0], 0)
-                k_pos_emb = jax.lax.dynamic_slice_in_dim(
-                    k_pos_emb, pos0, k.shape[0], 0)
+                if decode_step and paged:
+                    # every lane's token at its own position: (1, b, 1, rot)
+                    q_pos_emb, k_pos_emb = (
+                        jnp.take(emb[:, 0, 0], cache_index, axis=0)[
+                            None, :, None]
+                        for emb in (q_pos_emb, k_pos_emb))
+                else:
+                    pos0 = cache_index if decode_step else 0
+                    q_pos_emb = jax.lax.dynamic_slice_in_dim(
+                        q_pos_emb, pos0, q.shape[0], 0)
+                    k_pos_emb = jax.lax.dynamic_slice_in_dim(
+                        k_pos_emb, pos0, k.shape[0], 0)
             q = apply_rotary_pos_emb(q, q_pos_emb, cfg.rotary_interleaved)
             k = apply_rotary_pos_emb(k, k_pos_emb, cfg.rotary_interleaved)
 
@@ -394,7 +408,9 @@ class ParallelAttention(nn.Module):
         kb = jnp.transpose(k, (1, 2, 0, 3))
         vb = jnp.transpose(v, (1, 2, 0, 3))
 
-        if cache_active:
+        if cache_active and paged:
+            ctx = self._paged_decode(q, k, v, cache_index)
+        elif cache_active:
             h_kv_local = kb.shape[1]
             # Under CP each rank caches ONLY the positions it computed:
             # its contiguous prompt shard in slots [0, prompt_local), then
@@ -602,6 +618,43 @@ class ParallelAttention(nn.Module):
             name="dense",
         )(ctx)
         return out
+
+    def _paged_decode(self, q, k, v, positions):
+        """A decode step over a paged KV pool: each lane's new key and value
+        ((1, lanes, h_kv, hn), rotated) go into slot ``positions % bs`` of
+        pool block ``table[positions // bs]``, then every lane's query
+        attends keys [0, position] where they lie
+        (``ops.paged_decode_attention``). A lane with no block under its
+        position (an out-of-range entry: the sentinel of an idle lane) holds
+        nothing: its write is dropped and it attends no key (length 0, a
+        zero context). Returns (lanes, np, 1, hn)."""
+        from apex_tpu.ops.attention import paged_decode_attention
+
+        cfg = self.config
+        if q.shape[0] != 1:
+            raise NotImplementedError(
+                "decode_step appends one token at a time; use a prefill "
+                "call (cache_len=...) for multi-token blocks")
+        if cfg.context_parallel_mode is not None:
+            raise NotImplementedError(
+                "a paged cache holds whole sequences: no context parallelism")
+        table = self.get_variable("cache", "block_table")
+        pools = [self.get_variable("cache", name)
+                 for name in ("key_pool", "value_pool")]
+        lanes = q.shape[1]
+        nb, bs, _ = pools[0].shape
+        blk = table[jnp.arange(lanes), positions // bs]
+        live = jnp.logical_and(blk >= 0, blk < nb)
+        blk = jnp.where(live, blk, nb)  # out of range: the write drops
+        for i, (name, new) in enumerate((("key_pool", k), ("value_pool", v))):
+            pools[i] = pools[i].at[blk, positions % bs].set(
+                new[0].reshape(lanes, -1).astype(pools[i].dtype), mode="drop")
+            self.put_variable("cache", name, pools[i])
+        ctx = paged_decode_attention(
+            q[0], *pools, table, jnp.where(live, positions + 1, 0),
+            scale=1.0 / math.sqrt(cfg.kv_channels),
+            window=cfg.attention_window, impl=cfg.attention_impl)
+        return ctx[:, :, None, :]
 
 
 class _HeadColumnsDense(nn.Module):

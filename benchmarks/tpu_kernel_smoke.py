@@ -279,6 +279,38 @@ def build_checks():
 
     yield "decode sq=1 kpm fwd", decode_fwd
 
+    # the serving engine's decode: one token a lane against a paged pool,
+    # by block table and length (ragged lengths, a window, grouped heads,
+    # an idle lane whose table is all sentinel)
+    def paged(heads, h_kv, hd, dtype, window, tol):
+        from apex_tpu.ops.attention import paged_decode_attention
+
+        nb, bs, mb = 192, 16, 64
+        lengths = np.array([1, 15, 16, 17, 255, 256, 257, 900, 1024, 1],
+                           np.int32)
+        rng = np.random.RandomState(7)
+        tables = np.full((lengths.size, mb), nb, np.int32)
+        order, used = rng.permutation(nb), 0
+        for lane, n in enumerate(lengths[:-1]):
+            need = -(-int(n) // bs)
+            tables[lane, :need] = order[used:used + need]
+            used += need
+        ks = jax.random.split(jax.random.fold_in(key, 29), 3)
+        q = jax.random.normal(ks[0], (lengths.size, heads, hd), dtype)
+        kp, vp = (jax.random.normal(k, (nb, bs, h_kv * hd), dtype)
+                  for k in ks[1:])
+        run = lambda impl: jax.jit(lambda *a: paged_decode_attention(
+            *a, scale=hd ** -0.5, window=window, impl=impl))(
+                q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths))
+        return check(f"paged_decode {heads}/{h_kv} x {hd} "
+                     f"{jnp.dtype(dtype).name} window {window}",
+                     run("pallas"), run("xla"), tol)
+
+    for case in ((16, 16, 64, jnp.bfloat16, None, 2e-2),
+                 (16, 16, 64, jnp.float32, None, 2e-2),
+                 (32, 8, 128, jnp.bfloat16, 300, 2e-2)):
+        yield f"paged_decode {case[:3]}", functools.partial(paged, *case)
+
     # ---- flat optimizer engine ----
     # 3 chunks: the production case is a MULTI-chunk buffer (grid > 1), which
     # exercises the sequential-grid accumulation in l2norm_flat and the

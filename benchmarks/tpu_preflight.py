@@ -13,6 +13,8 @@ is a measurement.
     python benchmarks/tpu_preflight.py --attention  # the K/V residency edge
     python benchmarks/tpu_preflight.py --cell joyai_llm_flash.pretrain
                                                     # a benchmark cell's step
+    python benchmarks/tpu_preflight.py --cell gpt2_345m.chat
+                                # a serving cell's prefill buckets + decode
 
 Exit code 0 only when every program compiled. One at a time: libtpu holds a
 machine-wide lock, so two of these cannot run side by side.
@@ -53,7 +55,7 @@ def report(name, compiled, seconds):
     print(f"ok   {name}: {seconds:.0f} s, {calls} Mosaic kernels, "
           f"arguments {mem.argument_size_in_bytes / GiB:.2f} GiB "
           f"(aliased {mem.alias_size_in_bytes / GiB:.2f}), temporaries "
-          f"{mem.temp_size_in_bytes / GiB:.2f} GiB", flush=True)
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB", flush=True)
 
 
 def attempt(name, build):
@@ -231,6 +233,31 @@ def cell_program(cell, config, hlo_out=None):
     return step
 
 
+def serving_cell_programs(cell, config, device):
+    """Every program of a serving cell's engine (a prefill a bucket, the
+    decode step), compiled: the model and the engine as
+    ``perf/drivers/gpt_serve.py:build`` makes them, the weights as shapes."""
+    from apex_tpu.models import GPTModel
+    from apex_tpu.serving import ServingConfig, ServingEngine
+    from apex_tpu.transformer import TransformerConfig
+
+    model = GPTModel(config=TransformerConfig(
+        num_layers=config["n_layer"], hidden_size=config["n_embd"],
+        num_attention_heads=config["n_head"],
+        vocab_size=config["assumed"]["padded_vocab_size"],
+        max_position_embeddings=config["n_positions"], hidden_dropout=0.0,
+        attention_dropout=0.0, position_embedding_type="learned"))
+    variables = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = ServingEngine(model, {"params": variables["params"]},
+                        ServingConfig(**cell["engine"]))
+    ok = True
+    for key, lowered in eng.lower_programs(
+            SingleDeviceSharding(device)).items():
+        ok &= attempt(f"{cell['driver']} {key}", lowered.compile)
+    return ok
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -238,8 +265,9 @@ def main():
                         help="sweep the flash-attention K/V residency edge "
                              "instead of chip_smoke's programs")
     parser.add_argument("--cell", default=None,
-                        help="compile this benchmark cell's training step "
-                             "(for the chips it asks for) instead")
+                        help="compile this benchmark cell's training step, "
+                             "or a serving cell's engine programs (for the "
+                             "chips it asks for) instead")
     parser.add_argument("--hlo-out", default=None,
                         help="with --cell: write the compiled step's text")
     args = parser.parse_args()
@@ -254,7 +282,9 @@ def main():
           f"compiling for {len(devices)} chip(s)", flush=True)
     pretend_tpu(devices)
 
-    if args.cell:
+    if args.cell and "engine" in cell:
+        ok = serving_cell_programs(cell, config, devices[0])
+    elif args.cell:
         ok = attempt(f"cell {args.cell}, {args.chips} chip(s)",
                      lambda: cell_program(cell, config, args.hlo_out))
     elif args.attention:

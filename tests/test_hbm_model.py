@@ -21,6 +21,7 @@ The load-bearing contracts:
 
 import itertools
 import json
+import math
 import os
 import subprocess
 import sys
@@ -403,8 +404,7 @@ class TestKvPool:
         spec = kvcache.CacheSpec.from_cache_shapes(shapes)
         pools = spec.pool_shapes(num_blocks=10, block_size=16)
         real = sum(
-            shape[0] * shape[1] * shape[2] * shape[3]
-            * dtype_bytes(dtype)
+            math.prod(shape) * dtype_bytes(dtype)
             for shape, dtype in pools.values()
         )
         assert real == kv_pool_bytes(

@@ -176,6 +176,18 @@ def _latent_flash():
         _sds((2, 256, 512), bf), _sds((2, 256, 64), bf))
 
 
+def _paged_decode():
+    from apex_tpu.ops.attention import paged_decode_attention
+
+    def f(q, k_pool, v_pool, tables, lengths):
+        return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                      scale=0.125, impl="pallas")
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    return f, (_sds((4, 16, 64), bf), _sds((32, 16, 1024), bf),
+               _sds((32, 16, 1024), bf), _sds((4, 8), i32), _sds((4,), i32))
+
+
 def _flat(which):
     from apex_tpu.ops.multi_tensor import CHUNK_SIZE
     from apex_tpu.optimizers._fused_kernels import adam_flat, l2norm_flat
@@ -199,10 +211,11 @@ def _flat(which):
     (_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_latent_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                      "mla_rope"}),
+    (_paged_decode, {"paged_decode"}),
     (lambda: _flat("adam"), {"adam_flat"}),
     (lambda: _flat("l2norm"), {"sumsq_flat"}),
-], ids=["layer_norm", "rms_norm", "flash", "latent_flash", "adam_flat",
-        "sumsq_flat"])
+], ids=["layer_norm", "rms_norm", "flash", "latent_flash", "paged_decode",
+        "adam_flat", "sumsq_flat"])
 def test_every_tpu_custom_call_carries_a_registered_kernel(build, expected):
     f, args = build()
     found = _kernels_lowered_for_tpu(f, *args)

@@ -160,16 +160,26 @@ class TestCacheSpec:
         assert len(spec.kv_leaves) == 2 and len(spec.index_leaves) == 1
         pools = spec.pool_shapes(num_blocks=10, block_size=16)
         for shape, _ in pools.values():
-            assert shape == (10, 4, 16, 8)
+            # a block's token slots as rows, every kv head in the lanes
+            assert shape == (10, 16, 4 * 8)
 
     def test_build_and_extract_roundtrip(self):
         spec = kvcache.CacheSpec.from_cache_shapes(self._shapes())
-        kv = {kvcache.CacheSpec.key(l.path): f"arr-{i}"
-              for i, l in enumerate(spec.kv_leaves)}
-        cache = spec.build_cache(kv, 7)
+        pool = {kvcache.CacheSpec.key(l.path): f"arr-{i}"
+                for i, l in enumerate(spec.kv_leaves)}
+        cache = spec.paged_cache(pool, "tables", "positions")
         att = cache["transformer"]["layers_0"]["attention"]
-        assert att["cache_index"] == 7
-        assert spec.kv_from_cache(cache) == kv
+        # the paged KIND of cache the attention layer picks its branch by
+        assert set(att) == {"key_pool", "value_pool", "block_table",
+                            "cache_index"}
+        assert att["cache_index"] == "positions"
+        assert att["block_table"] == "tables"
+        assert spec.pool_from_cache(cache) == pool
+        # prefill's contiguous kind reads back under the same keys
+        contiguous = {"transformer": {"layers_0": {"attention": {
+            "cached_key": "k", "cached_value": "v", "cache_index": 3}}}}
+        assert spec.kv_from_cache(contiguous) == dict(
+            zip(sorted(pool), ("k", "v")))
 
     def test_refuses_unknown_layouts(self):
         bad = self._shapes()
