@@ -391,15 +391,18 @@ def _extra(st):
 def check(st, ctx):
     """A sample of the window's finished requests, the longest among them,
     against the float32 reference (PERF.md 2). In a traced run also the
-    trace's device time by program, for the readers: the harness drops the
-    trace before it calls them."""
+    trace's device time by program, for the readers, from the events the
+    harness loaded once (``ctx.events``): it drops them before it calls the
+    readers."""
     from perf import compare_serving
 
-    if ctx.trace and ctx.trace_file():
-        from perf import serve_trace, trace_reduce
+    if ctx.events is not None:
+        from perf import serve_trace
 
-        st.counters["programs"] = serve_trace.by_program(
-            trace_reduce.load_xplane(ctx.trace_file()), chips=ctx.chips)
+        before = time.perf_counter()
+        st.counters["programs"] = serve_trace.by_program(ctx.events,
+                                                         chips=ctx.chips)
+        ctx.trace_reads["by_program"] = time.perf_counter() - before
     sample = _sample(st, st.seed)
     _say(f"replaying {len(sample)} of {len(st.finished)} finished requests "
          f"({sum(len(r['served']) for r in sample)} served tokens)")

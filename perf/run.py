@@ -98,6 +98,11 @@ class Context:
         self.device, self.peaks = device, peaks
         self.chips = int(cell.get("chips", 1))
         self.counters = {}      # filled by the driver's window
+        #: a traced run's events (``trace_reduce.load_xplane``), loaded once
+        #: after the state is freed, for the correctness check and reduction
+        self.events = None
+        #: seconds the workload's own readings of ``events`` took, by name
+        self.trace_reads = {}
         self.reduction = None   # filled from the trace
         self._trace_dir = os.path.join(root, ".perf_trace")
         self._trace_state = "idle" if trace else "off"
@@ -272,7 +277,17 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
     driver.release(state)
     gc.collect()
 
+    if trace:
+        reducer = load_module(root, "trace_reduce")
+        path = ctx.trace_file()
+        if path is None:
+            raise RuntimeError("the profiler left no trace")
+        before = time.perf_counter()
+        ctx.events = reducer.load_xplane(path)
+        load_s = time.perf_counter() - before
+    before = time.perf_counter()
     compared = list(driver.check(state, ctx))
+    check_s = time.perf_counter() - before
     compared.append({"name": "compiles_in_window", "value": in_window[0],
                      "limit": 0})
     correct = load_module(root, "compare").correct(compared)
@@ -283,10 +298,6 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
     ctx.counters["window_s"] = result["window_s"] - ctx.trace_stall_s
     metrics, breakdown = {}, None
     if trace:
-        reducer = load_module(root, "trace_reduce")
-        path = ctx.trace_file()
-        if path is None:
-            raise RuntimeError("the profiler left no trace")
         before = time.perf_counter()
         scopes = None
         if hlo_text is not None:
@@ -294,15 +305,21 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
                 hlo_text, **driver.scope_names())
             del hlo_text
         names_s += time.perf_counter() - before
+        before = time.perf_counter()
         ctx.reduction = reducer.reduce(
-            reducer.load_xplane(path), chips=chips,
-            spans=cell.get("spans", ()),
+            ctx.events, chips=chips, spans=cell.get("spans", ()),
             device_required=device["platform"] == "tpu", scopes=scopes)
+        reduce_s = time.perf_counter() - before
+        n_events, ctx.events = len(ctx.events), None
         ctx.drop_trace()
+        reads = "".join(f", {k} {v:.2f} s"
+                        for k, v in ctx.trace_reads.items())
         _say(f"[run] starting the profiler stalled {ctx.trace_stall_s:.2f} s"
              f", stopping it took {ctx.trace_stop_s:.2f} s (after the "
-             f"window); the compiled step's text and its scope map took "
-             f"{names_s:.2f} s")
+             f"window); the trace: loaded once in {load_s:.2f} s ({n_events}"
+             f" events), reduced in {reduce_s:.2f} s; the correctness check "
+             f"read it{reads or ' not at all'}; the compiled step's text and "
+             f"its scope map took {names_s:.2f} s")
         if ctx.reduction["busy_s"] is not None:
             device["busy_s"] = ctx.reduction["busy_s"]
             device["window_s"] = ctx.reduction["window_s"]
@@ -323,6 +340,8 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
     if breakdown is not None:
         line["breakdown"] = breakdown
     line["compared"] = compared
+    _say(f"[run] the output check took {check_s:.2f} s; the result after "
+         f"{time.perf_counter() - _T_START:.1f} s from process start")
     for c in compared:
         _say(f"[compared] {c['name']} = {c['value']:.6g} "
              f"(limit {c['limit']:.6g})"

@@ -24,6 +24,7 @@ for the look by hand that has to come before any new reader.
 """
 
 import collections
+import heapq
 import json
 import re
 import sys
@@ -146,6 +147,33 @@ def module_runs(events, planes):
     return runs[max(secs, key=secs.get)] / float(len(planes))
 
 
+def _gap_spans(gaps, host):
+    """For each gap ``(start, end)`` of ``gaps`` (ascending, disjoint), the
+    name of the host span that overlaps it most: the strictly largest
+    overlap wins, a tie goes to the span first in ``host``'s order, and
+    ``(no span)`` where no overlap is positive. One sweep: spans enter by
+    start as the gaps' ends pass them and leave, by end, once a gap starts
+    after them, so each gap scores only the spans that overlap it, in
+    ``host``'s order and with the overlap as the plain double loop over gaps
+    and spans computes it."""
+    order = sorted(range(len(host)), key=lambda i: host[i]["start_ns"])
+    ends = [h["start_ns"] + h["dur_ns"] for h in host]
+    active, nxt, out = [], 0, []  # active: heap of (end, index into host)
+    for gs, ge in gaps:
+        while nxt < len(order) and host[order[nxt]]["start_ns"] < ge:
+            heapq.heappush(active, (ends[order[nxt]], order[nxt]))
+            nxt += 1
+        while active and active[0][0] <= gs:
+            heapq.heappop(active)
+        best, best_ov = "(no span)", 0.0
+        for i in sorted(i for _, i in active):
+            ov = min(ge, ends[i]) - max(gs, host[i]["start_ns"])
+            if ov > best_ov:
+                best, best_ov = host[i]["name"], ov
+        out.append(best)
+    return out
+
+
 def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
     """The reduction the per-layer metrics read.
 
@@ -238,12 +266,7 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
             gaps.append((cursor, s))
         cursor = max(cursor, e)
     by_span = collections.Counter()
-    for gs, ge in gaps:
-        best, best_ov = "(no span)", 0.0
-        for h in host:
-            ov = min(ge, h["start_ns"] + h["dur_ns"]) - max(gs, h["start_ns"])
-            if ov > best_ov:
-                best, best_ov = h["name"], ov
+    for (gs, ge), best in zip(gaps, _gap_spans(gaps, host)):
         by_span[best] += (ge - gs) * 1e-9
     span_seconds = collections.Counter()
     for h in host:

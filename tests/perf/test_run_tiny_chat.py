@@ -113,6 +113,27 @@ def test_a_traced_chat_run_reports_what_a_cpu_can(chat_root):
     assert line["correct"] is True, line["compared"]
 
 
+def test_a_traced_chat_run_loads_its_trace_once(chat_root, monkeypatch):
+    """The harness loads the ``.xplane.pb`` once and hands the events to the
+    correctness check for the time by program and to the reduction; the
+    readers read what they read with two loads."""
+    from perf import trace_reduce
+
+    calls = []
+    for module in (run.load_module(chat_root, "trace_reduce"), trace_reduce):
+        def counted(path, real=module.load_xplane):
+            calls.append(path)
+            return real(path)
+        monkeypatch.setattr(module, "load_xplane", counted)
+    line = _run(chat_root, seed=2**31 + 9, trace=1)
+    assert len(calls) == 1, calls
+    assert set(line["metrics"]) == {
+        "tpot_p95_ms.serve", "queue_wait_p95_ms.serve", "ttft_mean_ms.serve",
+        "ttft_p50_ms.serve", "ttft_p95_ms.serve", "loadgen_late_p95_ms.serve",
+        "kv_pool_peak_share.serve", "kv_pool_held_share.serve"}
+    assert line["correct"] is True, line["compared"]
+
+
 # -- the comparison has been shown to fail -----------------------------------
 
 
@@ -168,7 +189,10 @@ def test_an_unanswered_request_reads_incorrect(chat_root, monkeypatch):
     """An answer that comes late is late, not wrong; one that never comes
     is for ``correct``: a drain limit too short for the requests in flight
     at the close (behind an engine slowed to 20 ms a tick) leaves them
-    unanswered."""
+    unanswered. The window is long enough that early requests finish
+    whatever the CPU's speed (the seed's schedule over 1.2 s: the first
+    three, due by 0.09 s, want 12, 10 and 6 tokens), and the last, due at
+    1.183 s and wanting 15 tokens, cannot (the close leaves it two ticks)."""
     from apex_tpu import serving
 
     real = serving.ServingEngine.tick
@@ -181,9 +205,10 @@ def test_an_unanswered_request_reads_incorrect(chat_root, monkeypatch):
     cell = dict(CHAT_TINY, drain_limit_s=0.0)
     with open(f"{chat_root}/perf/workloads/{CELL}.json", "w") as f:
         json.dump(cell, f)
-    line = _run(chat_root, seconds=0.3)
+    line = _run(chat_root, seconds=1.2)
     never = next(c for c in line["compared"]
                  if c["name"] == "requests_never_answered")
+    assert line["attempted"] - line["failed"] >= 1  # some were answered
     assert never["value"] >= 1 and line["failed"] >= never["value"]
     assert line["correct"] is False
 

@@ -21,6 +21,8 @@ import collections
 
 import json
 import os
+import random
+import time
 
 import pytest
 
@@ -104,6 +106,184 @@ def test_gap_outside_every_span_is_said_so(events):
     gaps = dict(r["idle_gaps"])
     assert gaps["fetch_batch"] == pytest.approx(50e-9)
     assert gaps["(no span)"] == pytest.approx(150e-9)
+
+
+# -- idle gaps by host span: the sweep against the plain double loop ----------
+
+
+def loop_idle_gaps(events, spans, chips=1):
+    """``reduce``'s ``idle_gaps`` as the plain double loop over gaps and host
+    spans computes them: each gap of the first chip to the span that
+    overlaps it most (the first in the events' order of those that tie),
+    seconds added in gap order, longest first. The reference the sweep is
+    held to with ``==``."""
+    planes = sorted({e["plane"] for e in events
+                     if tr.DEVICE_PLANE.match(e["plane"])},
+                    key=lambda p: int(tr.DEVICE_PLANE.match(p).group(1)))
+    planes = planes[:chips]
+    host = [e for e in events if e["plane"].startswith("/host:")
+            and e["name"] in set(spans)]
+    dev = [e for e in events if e["plane"] in planes
+           and e["line"] == tr.OPS_LINE]
+    t0 = min(e["start_ns"] for e in host + dev)
+    t1 = max(e["start_ns"] + e["dur_ns"] for e in host + dev)
+    merged = tr.union([(e["start_ns"], e["start_ns"] + e["dur_ns"])
+                       for e in dev if e["plane"] == planes[0]])
+    gaps, cursor = [], t0
+    for s, e in merged + [[t1, t1]]:
+        if s > cursor:
+            gaps.append((cursor, s))
+        cursor = max(cursor, e)
+    by_span = collections.Counter()
+    for gs, ge in gaps:
+        best, best_ov = "(no span)", 0.0
+        for h in host:
+            ov = min(ge, h["start_ns"] + h["dur_ns"]) - max(gs, h["start_ns"])
+            if ov > best_ov:
+                best, best_ov = h["name"], ov
+        by_span[best] += (ge - gs) * 1e-9
+    return [[k, v] for k, v in sorted(by_span.items(), key=lambda kv: -kv[1])]
+
+
+def _op(name, start, dur, chip=0):
+    return {"plane": f"/device:TPU:{chip}", "line": "XLA Ops", "name": name,
+            "start_ns": start, "dur_ns": dur}
+
+
+def _span(name, start, dur, line="python"):
+    return {"plane": "/host:CPU", "line": line, "name": name,
+            "start_ns": start, "dur_ns": dur}
+
+
+SERVE_SPANS = ("submit", "tick", "observe", "wait")
+
+
+def synthetic_trace(seed, ticks, ops_per_tick=40, gap_share=0.3, grid=None):
+    """A serving loop's trace from ``seed``: per tick the host spans
+    ``submit``, ``tick`` (the device's ops run inside it, some nested, a gap
+    after ``gap_share`` of them), ``observe`` and now and then ``wait``;
+    besides, stray spans of those names and of others, short and long, that
+    nest in, overlap and straddle the rest, on two host lines, and a few ops
+    on a second chip. ``grid`` rounds every time to its multiples, so that
+    overlaps tie exactly."""
+    rng = random.Random(seed)
+    q = (lambda x: round(x / grid) * grid) if grid else (lambda x: x)
+    events, t = [], q(rng.uniform(0.0, 500.0))
+    for k in range(ticks):
+        dur = q(rng.uniform(5.0, 30.0))
+        events.append(_span("submit", t, dur))
+        t += dur
+        began, d = t, t + q(rng.uniform(10.0, 50.0))
+        for i in range(ops_per_tick):
+            dur = q(rng.uniform(1.0, 20.0))
+            events.append(_op(f"fusion.{i}", d, dur))
+            if rng.random() < 0.2:
+                events.append(_op(f"copy.{i}", d + q(dur / 4), q(dur / 2)))
+            d += dur
+            if rng.random() < gap_share:
+                d += q(rng.uniform(0.5, 30.0))
+        t = d + q(rng.uniform(5.0, 40.0))
+        events.append(_span("tick", began, t - began))
+        if k % 7 == 0:
+            events.append(_op("all-reduce.1", began, t - began, chip=1))
+        dur = q(rng.uniform(2.0, 20.0))
+        events.append(_span("observe", t, dur))
+        t += dur
+        if rng.random() < 0.3:
+            dur = q(rng.uniform(50.0, 400.0))
+            events.append(_span("wait", t, dur))
+            t += dur
+        for _ in range(rng.randrange(3)):
+            events.append(_span(
+                rng.choice(SERVE_SPANS + ("unrelated",)),
+                q(began + rng.uniform(-300.0, 600.0)),
+                q(rng.choice((0.0, rng.uniform(1.0, 60.0),
+                              rng.uniform(100.0, 3000.0)))),
+                line=rng.choice(("python", "runtime"))))
+    return events
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+@pytest.mark.parametrize("spans", [SPANS, ("fetch_batch",), ()])
+def test_idle_gaps_equal_the_double_loop_on_the_recording(events, chips,
+                                                          spans):
+    assert tr.reduce(events, chips=chips, spans=spans)["idle_gaps"] == (
+        loop_idle_gaps(events, spans, chips))
+
+
+#: device ops on chip 0 at [0,100) and [300,400), so one gap [100,300),
+#: and each case's host spans around it
+GAP_CASES = {
+    # spans inside spans: the outer one overlaps most
+    "nested": [_span("step", 50.0, 300.0), _span("fetch_batch", 120.0, 80.0),
+               _span("fetch_loss", 210.0, 80.0)],
+    # a chain of partial overlaps: the middle one overlaps most
+    "overlapping": [_span("fetch_batch", 80.0, 100.0),
+                    _span("step", 150.0, 110.0),
+                    _span("fetch_loss", 240.0, 90.0)],
+    # one span across each end of the gap, 20 ns of it each: a tie
+    "straddling": [_span("fetch_batch", 50.0, 70.0),
+                   _span("fetch_loss", 280.0, 80.0)],
+    # two spans over the same stretch: a tie
+    "tied": [_span("step", 150.0, 100.0), _span("fetch_loss", 150.0, 100.0),
+             _span("fetch_batch", 160.0, 10.0)],
+    # spans that touch the gap's ends or last no time: no overlap at all
+    "touching": [_span("step", 0.0, 100.0), _span("fetch_loss", 300.0, 50.0),
+                 _span("fetch_batch", 200.0, 0.0)],
+    # before the first op and after the last: gaps at both ends of the window
+    "before_and_after": [_span("fetch_batch", -80.0, 60.0),
+                         _span("fetch_loss", 420.0, 40.0),
+                         _span("step", 390.0, 100.0)],
+    # long spans that hold every gap, beside short ones
+    "enclosing": [_span("step", -50.0, 600.0), _span("fetch_batch", 110.0,
+                                                     5.0),
+                  _span("fetch_loss", -40.0, 590.0)],
+    "no_host_span": [],
+}
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("case", sorted(GAP_CASES))
+def test_idle_gaps_equal_the_double_loop_in_each_case(case, reverse):
+    host = GAP_CASES[case][::-1] if reverse else GAP_CASES[case]
+    evs = [_op("fusion.1", 0.0, 100.0), _op("fusion.2", 300.0, 100.0),
+           _span("unrelated", 100.0, 200.0)] + host
+    got = tr.reduce(evs, chips=1, spans=SPANS)["idle_gaps"]
+    assert got == loop_idle_gaps(evs, SPANS)
+    if case == "tied":  # the first of the two in the events' order
+        assert got[0][0] == ("fetch_loss" if reverse else "step")
+    if case == "touching":
+        assert got == [["(no span)", pytest.approx(200e-9)]]
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("grid", [None, 5.0])
+@pytest.mark.parametrize("seed", range(6))
+def test_idle_gaps_equal_the_double_loop_on_synthetic_traces(seed, grid,
+                                                             reverse):
+    evs = synthetic_trace(seed, ticks=30, grid=grid)
+    if reverse:
+        evs = evs[::-1]
+    for spans in (SERVE_SPANS, ("tick", "wait")):
+        got = tr.reduce(evs, chips=2, spans=spans)["idle_gaps"]
+        assert got == loop_idle_gaps(evs, spans), spans
+    assert {k for k, _ in got} == {"tick", "wait"}  # both win gaps
+
+
+def test_idle_gaps_of_a_long_slice_take_a_sweep_not_a_square():
+    """About a chat cell's 5 s slice: 600 ticks, 24,674 gaps against some
+    2,700 host spans. The double loop takes about 20 s here on one CPU core
+    (and grows with the square of the ticks), the sweep under one."""
+    evs = synthetic_trace(7, ticks=600, ops_per_tick=100, gap_share=0.4)
+    host = [e for e in evs if e["plane"].startswith("/host:")
+            and e["name"] in SERVE_SPANS]
+    t0 = time.perf_counter()
+    r = tr.reduce(evs, chips=1, spans=SERVE_SPANS)
+    took = time.perf_counter() - t0
+    assert len(host) > 2400
+    assert sum(v for _, v in r["idle_gaps"]) == pytest.approx(
+        r["window_s"] - r["busy_s"])
+    assert took < 10.0, f"{took:.1f} s"
 
 
 def test_no_device_plane_is_an_error_on_the_chip(events):
