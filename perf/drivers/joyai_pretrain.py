@@ -7,8 +7,10 @@ build_gpt_training`` from ``pretrain_gpt.target_config(parse_args(
 argv))``, the model named by ``--arch-file`` and the share by ``--experts-
 held --first-expert --vocab-rows --layers-kept``), with the loop ``main``
 runs there: host batch -> device, one ``train_step``, fetch loss and
-verdict. The weights come from the benchmark's seed (``perf/reference/
-joyai_llm_flash.py``), so that the reference can follow the same run.
+verdict. The weights come from the cell's ``weights_seed`` where its file
+gives one, else from the benchmark's seed (``perf/reference/
+joyai_llm_flash.py``), so that the reference can follow the same run; the
+corpus always comes from the benchmark's seed.
 
 Set-up builds ONE compiled step with its state and drives it through the
 first three steps (the window's call and feed) while keeping what the output
@@ -114,9 +116,14 @@ def build(cell, config):
 
 
 def _make_w0(st, seed):
+    """The initial weights: one draw for every seed where the cell fixes it
+    (``weights_seed``: how soon the MTP block's router overflows the short
+    buffer follows the weights, and with it the work of a step, PERF.md 7
+    row 21), else the run's own."""
     from perf.reference import joyai_llm_flash as ref
 
-    return ref.init_weights(ref.seed_key(seed), **st.dims)
+    return ref.init_weights(
+        ref.seed_key(st.cell.get("weights_seed", seed)), **st.dims)
 
 
 def _bag(st):
@@ -148,7 +155,8 @@ def _step_keeping_choices(st, ctx):
 
 
 def start_run(st, seed, ctx):
-    """Corpus, weights and state from the seed, then the first three steps
+    """Corpus, weights and state from the seed (the weights from the cell's
+    ``weights_seed`` where it gives one), then the first three steps
     through the window's own call, keeping what the output check reads."""
     import jax
 

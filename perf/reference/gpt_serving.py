@@ -14,67 +14,34 @@ is a rounding error; a token that came from other weights, another
 position, a cache that lost a block or half a prompt lies whole standard
 deviations below.
 
-Valid for greedy tokens only. Nothing here imports the program, and the
-weights come from ``reference/gpt.py:init_weights`` and the seed.
-
-The control (``candidate="fp8"``): the same forward pass with its linear
-layers' operands rounded to fp8 stands in the engine's place. It need not
-decode: at each position of the same prompt and tokens, the token IT puts
-first is read against the float32 reference in the served token's stead.
+The arithmetic of the gap, the padding and the fp8 control's candidate is
+every served model's (``reference/served.py``); what is GPT-2's here is the
+forward pass it is handed. Valid for greedy tokens only. Nothing here
+imports the program, and the weights come from ``reference/gpt.py:
+init_weights`` and the seed.
 """
 
 import functools
 
-import jax
-import jax.numpy as jnp
-
 from perf.reference import gpt
+from perf.reference import served as shared
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "rows", "candidate"))
-def _position_gaps(w, tokens, start, served, *, heads, rows, candidate):
-    """``tokens`` (seq,): prompt + served tokens, right-padded (the pad is
-    causally shadowed). Rows ``start .. start + rows`` of the logits are the
-    next-token logits of the served positions. Returns per row the gap of
-    the candidate token under the reference's best over the row's spread,
-    and whether the candidate is the reference's best."""
-    def rows_of(precision):
-        logits = gpt.forward(w, tokens[None], heads=heads,
-                             precision=precision)[0]
-        return jax.lax.dynamic_slice_in_dim(logits, start, rows, axis=0)
-
-    ref = rows_of("f32")
-    cand = served if candidate == "served" else jnp.argmax(
-        rows_of(candidate), axis=-1).astype(served.dtype)
-    best = jnp.max(ref, axis=-1)
-    picked = jnp.take_along_axis(ref, cand[:, None], axis=-1)[:, 0]
-    spread = jnp.std(ref, axis=-1)
-    return (best - picked) / spread, cand == jnp.argmax(ref, axis=-1)
+@functools.lru_cache(maxsize=None)
+def forward_of(heads):
+    """``forward(w, tokens, precision) -> logits`` of a GPT-2 of ``heads``
+    heads, one object a head count: the shared program takes it as a static
+    argument and compiles once for it."""
+    def forward(w, tokens, precision):
+        return gpt.forward(w, tokens, heads=heads, precision=precision)
+    return forward
 
 
-def served_gaps(w, prompt, served, *, heads, seq, rows, candidate="served"):
+def served_gaps(w, prompt, served, *, heads, seq, rows,
+                candidate="served"):
     """Per served position of one request: (gap over spread, is the
-    reference's best). ``seq`` and ``rows`` pad the sequence and the answer
-    to one shape for every request (``seq`` the engine's ``max_seq_len``,
-    ``rows`` the longest answer the mix allows), so one program serves the
-    whole check."""
-    import numpy as np
-
-    n, start = len(served), len(prompt) - 1
-    if n > rows or start + n > seq:
-        raise ValueError(f"prompt {len(prompt)} + answer {n} does not fit "
-                         f"({seq} positions, {rows} rows)")
-    # a request that fills its positions ends at the sequence's end: its
-    # window of ``rows`` rows then starts before its first served position
-    first = min(start, seq - rows)
-    skip = start - first
-    tokens = np.zeros((seq,), np.int32)
-    tokens[:len(prompt)] = prompt
-    tokens[len(prompt):len(prompt) + n - 1] = served[:-1]
-    padded = np.zeros((rows,), np.int32)
-    padded[skip:skip + n] = served
-    gaps, same = _position_gaps(w, jnp.asarray(tokens), jnp.int32(first),
-                                jnp.asarray(padded), heads=heads, rows=rows,
-                                candidate=candidate)
-    return (np.asarray(gaps)[skip:skip + n],
-            np.asarray(same)[skip:skip + n])
+    reference's best), by ``reference/served.py:served_gaps`` with GPT-2's
+    forward pass. ``candidate="fp8"`` reads the token the fp8 forward puts
+    first in the served token's stead."""
+    return shared.served_gaps(forward_of(heads), w, prompt, served,
+                              seq=seq, rows=rows, candidate=candidate)

@@ -19,7 +19,9 @@ reference, which decides ``correct``. ``--trace 1`` profiles a slice of the
 window and reports the per-layer metrics in place of the end-to-end ones;
 where the driver hands out the compiled step's text (``hlo_text``,
 ``scope_names``), the trace's device ops are booked to the program's own
-names: the step's phases, the model's scopes, the kernels.
+names: the step's phases, the model's scopes, the kernels. A driver of
+several programs (a serving engine) hands out ``{program: text}``, and each
+program's ops are booked by its own text alone.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` also ``breakdown``,
@@ -299,16 +301,23 @@ def run_cell(root, workload, seed, seconds, trace, allow_cpu=False,
     metrics, breakdown = {}, None
     if trace:
         before = time.perf_counter()
-        scopes = None
+        scopes = program_scopes = None
         if hlo_text is not None:
-            scopes = load_module(root, "hlo_scopes").scope_map(
-                hlo_text, **driver.scope_names())
+            scope_map = load_module(root, "hlo_scopes").scope_map
+            names = driver.scope_names()
+            if isinstance(hlo_text, dict):
+                # several programs, each booked by its own text
+                program_scopes = {p: scope_map(t, **names)
+                                  for p, t in hlo_text.items()}
+            else:
+                scopes = scope_map(hlo_text, **names)
             del hlo_text
         names_s += time.perf_counter() - before
         before = time.perf_counter()
         ctx.reduction = reducer.reduce(
             ctx.events, chips=chips, spans=cell.get("spans", ()),
-            device_required=device["platform"] == "tpu", scopes=scopes)
+            device_required=device["platform"] == "tpu", scopes=scopes,
+            program_scopes=program_scopes)
         reduce_s = time.perf_counter() - before
         n_events, ctx.events = len(ctx.events), None
         ctx.drop_trace()

@@ -15,20 +15,14 @@ Works on the plain event dicts ``trace_reduce.load_xplane`` gives, so the
 arithmetic is checked on a small recorded trace with no profiler.
 """
 
-import bisect
 import collections
-import re
 
 from perf import trace_reduce
 
-_RUN_ID = re.compile(r"\(\d+\)$")
 OUTSIDE = "(outside any program)"
-
-
-def program_name(module_event_name):
-    """``jit_decode(1234567)`` -> ``jit_decode``: the seven prefill buckets
-    are seven programs of one name, and read as one."""
-    return _RUN_ID.sub("", module_event_name)
+#: ``jit_decode(1234567)`` -> ``jit_decode``: the seven prefill buckets are
+#: seven programs of one name, and read as one
+program_name = trace_reduce.program_name
 
 
 def by_program(events, chips=1):
@@ -47,12 +41,7 @@ def by_program(events, chips=1):
         "runs": 0, "module_s": 0.0, "op_s": 0.0,
         "ops": collections.Counter()})
     for plane in planes:
-        runs = sorted(
-            (e["start_ns"], e["start_ns"] + e["dur_ns"],
-             program_name(e["name"])) for e in events
-            if e["plane"] == plane
-            and e["line"] == trace_reduce.MODULES_LINE)
-        starts = [r[0] for r in runs]
+        starts, runs = trace_reduce.module_line(events, plane)
         for _s, _e, name in runs:
             out[name]["runs"] += 1
             out[name]["module_s"] += (_e - _s) * 1e-9
@@ -61,9 +50,8 @@ def by_program(events, chips=1):
         for e, self_ns in trace_reduce._self_times(ops):
             if self_ns <= 0:
                 continue
-            i = bisect.bisect_right(starts, e["start_ns"] + 1.0) - 1
-            inside = i >= 0 and e["start_ns"] < runs[i][1]
-            book = out[runs[i][2] if inside else OUTSIDE]
+            book = out[trace_reduce.program_at(starts, runs, e["start_ns"])
+                       or OUTSIDE]
             book["op_s"] += self_ns * 1e-9
             book["ops"][trace_reduce.base_name(e["name"])] += self_ns * 1e-9
     return {k: dict(v, ops=dict(v["ops"])) for k, v in out.items()}

@@ -23,6 +23,7 @@ lines with the ``TraceAnnotation`` spans.
 for the look by hand that has to come before any new reader.
 """
 
+import bisect
 import collections
 import heapq
 import json
@@ -41,6 +42,7 @@ _JSON_PAIR = re.compile(r'"((?:[^"\\]|\\.)*)"\s*:\s*"((?:[^"\\]|\\.)*)"')
 MODULES_LINE = "XLA Modules"
 MOSAIC_TARGET = "tpu_custom_call"
 UNATTRIBUTED = "(unattributed)"
+_RUN_ID = re.compile(r"\(\d+\)$")
 
 
 def split_hlo(raw):
@@ -147,6 +149,41 @@ def module_runs(events, planes):
     return runs[max(secs, key=secs.get)] / float(len(planes))
 
 
+def program_name(module_event_name):
+    """``jit_decode(1234567)`` -> ``jit_decode``: the name of a program
+    without the id of its compiled module (the prefill buckets are several
+    programs of one name, and read as one)."""
+    return _RUN_ID.sub("", module_event_name)
+
+
+def module_line(events, plane):
+    """The runs on ``plane``'s ``XLA Modules`` line, by start: their starts,
+    and each run's (start, end, ``program_name``)."""
+    runs = sorted((e["start_ns"], e["start_ns"] + e["dur_ns"],
+                   program_name(e["name"])) for e in events
+                  if e["plane"] == plane and e["line"] == MODULES_LINE)
+    return [r[0] for r in runs], runs
+
+
+def program_at(starts, runs, t):
+    """The program whose run (``module_line``) holds the instant ``t`` (an
+    op's start, give or take a nanosecond of rounding), None outside every
+    run. A TPU trace names no program on an op's event: the run that holds
+    it is what ties an op to its program."""
+    i = bisect.bisect_right(starts, t + 1.0) - 1
+    return runs[i][2] if i >= 0 and t < runs[i][1] else None
+
+
+def program_runs(events, planes):
+    """How often each program (``program_name``) ran on a chip: its events
+    on the ``XLA Modules`` line, averaged over ``planes``."""
+    runs = collections.Counter()
+    for e in events:
+        if e["line"] == MODULES_LINE and e["plane"] in planes:
+            runs[program_name(e["name"])] += 1
+    return {k: v / float(len(planes)) for k, v in runs.items()}
+
+
 def _gap_spans(gaps, host):
     """For each gap ``(start, end)`` of ``gaps`` (ascending, disjoint), the
     name of the host span that overlaps it most: the strictly largest
@@ -174,7 +211,16 @@ def _gap_spans(gaps, host):
     return out
 
 
-def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
+def _book(sc, secs, phase_seconds, scope_seconds):
+    """An op's self time under its ``Scope``'s phase (``(unattributed)``
+    where the text has no such instruction) and model scope, if any."""
+    phase_seconds[sc.part if sc else UNATTRIBUTED] += secs
+    if sc and sc.scope:
+        scope_seconds[sc.scope] += secs
+
+
+def reduce(events, chips=1, spans=(), device_required=True, scopes=None,
+           program_scopes=None):
     """The reduction the per-layer metrics read.
 
     ``window_s``: first to last instant of anything kept (device ops and the
@@ -197,7 +243,16 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
     ``phase_seconds`` (ops' self time by ``Scope.part``; an op no
     instruction of the text matches under ``(unattributed)``) and
     ``scope_seconds`` (by model scope, ops under none left out), over the
-    planes; without, both are empty."""
+    planes; without, both are empty.
+
+    With ``program_scopes`` (program name -> such a map, from that
+    program's own compiled text: a serving engine runs several programs,
+    whose instruction names collide) also ``programs``: for every program
+    on the ``XLA Modules`` line its ``runs`` a chip (``program_runs``), and
+    for each that has a map its ``phase_seconds`` and ``scope_seconds`` as
+    above. An op is booked by the map of the program whose run holds it
+    (``program_at``), never by another's; the ops of a program without a map
+    are booked nowhere. Nothing else of the reduction changes."""
     planes = sorted({e["plane"] for e in events
                      if DEVICE_PLANE.match(e["plane"])},
                     key=lambda p: int(DEVICE_PLANE.match(p).group(1)))[:chips]
@@ -213,13 +268,16 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
         for h in host:
             span_seconds[h["name"]] += h["dur_ns"] * 1e-9
         ends = [(h["start_ns"], h["start_ns"] + h["dur_ns"]) for h in host]
-        return {"window_s": (max(e for _, e in ends)
-                             - min(s for s, _ in ends)) * 1e-9 if ends else 0.0,
-                "busy_s": None, "chips": 0, "op_seconds": {}, "op_stats": {},
-                "op_counts": {}, "top_ops": [], "idle_gaps": [],
-                "span_seconds": dict(span_seconds), "steps": 0.0,
-                "kernel_seconds": {}, "kernel_counts": {},
-                "phase_seconds": {}, "scope_seconds": {}}
+        out = {"window_s": (max(e for _, e in ends)
+                            - min(s for s, _ in ends)) * 1e-9 if ends else 0.0,
+               "busy_s": None, "chips": 0, "op_seconds": {}, "op_stats": {},
+               "op_counts": {}, "top_ops": [], "idle_gaps": [],
+               "span_seconds": dict(span_seconds), "steps": 0.0,
+               "kernel_seconds": {}, "kernel_counts": {},
+               "phase_seconds": {}, "scope_seconds": {}}
+        if program_scopes is not None:
+            out["programs"] = {}
+        return out
     dev = {p: [e for e in events
                if e["plane"] == p and e["line"] == OPS_LINE] for p in planes}
     if not any(dev.values()):
@@ -232,6 +290,8 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
     op_counts = collections.Counter()
     kernel_seconds, kernel_counts = collections.Counter(), collections.Counter()
     phase_seconds, scope_seconds = collections.Counter(), collections.Counter()
+    by_program = collections.defaultdict(lambda: (collections.Counter(),
+                                                  collections.Counter()))
     merged_first = None
     for p in planes:
         merged = union([(e["start_ns"], e["start_ns"] + e["dur_ns"])
@@ -239,6 +299,8 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
         if merged_first is None:
             merged_first = merged
         busy.append(sum(e - s for s, e in merged))
+        if program_scopes is not None:
+            starts, runs = module_line(events, p)
         for e, self_ns in _self_times(dev[p]):
             if self_ns <= 0:
                 continue  # a ``while`` whose body did all the work
@@ -255,10 +317,13 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
                 kernel_seconds[kernel] += secs
                 kernel_counts[kernel] += 1
             if scopes is not None:
-                sc = scopes.get(e["name"])
-                phase_seconds[sc.part if sc else UNATTRIBUTED] += secs
-                if sc and sc.scope:
-                    scope_seconds[sc.scope] += secs
+                _book(scopes.get(e["name"]), secs, phase_seconds,
+                      scope_seconds)
+            if program_scopes is not None:
+                program = program_at(starts, runs, e["start_ns"])
+                if program in program_scopes:
+                    _book(program_scopes[program].get(e["name"]), secs,
+                          *by_program[program])
 
     gaps, cursor = [], t0
     for s, e in merged_first + [[t1, t1]]:
@@ -276,7 +341,7 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
     for name, secs in op_seconds.items():
         grouped[base_name(name)] += secs
     ranked = sorted(grouped.items(), key=lambda kv: -kv[1])
-    return {
+    out = {
         "window_s": (t1 - t0) * 1e-9,
         "busy_s": sum(busy) * 1e-9 / len(planes),
         "chips": len(planes),
@@ -293,6 +358,14 @@ def reduce(events, chips=1, spans=(), device_required=True, scopes=None):
         "phase_seconds": dict(phase_seconds),
         "scope_seconds": dict(scope_seconds),
     }
+    if program_scopes is not None:
+        runs = program_runs(events, planes)
+        out["programs"] = {
+            p: {"runs": runs.get(p, 0.0),
+                "phase_seconds": dict(by_program[p][0]),
+                "scope_seconds": dict(by_program[p][1])}
+            for p in sorted(set(runs) | set(by_program))}
+    return out
 
 
 def per_step_ms(reduction, table, names):
@@ -307,6 +380,22 @@ def per_step_ms(reduction, table, names):
     if secs <= 0:
         return None
     return 1e3 * secs / reduction["chips"] / reduction["steps"]
+
+
+def per_run_ms(reduction, program, table, names):
+    """Milliseconds a run a chip of ``program`` that a reduction booked to
+    ``names`` in that program's ``table`` (``phase_seconds``,
+    ``scope_seconds``; ``reduce``'s ``programs``): ``per_step_ms`` for a
+    reader of one program of several, such as a serving engine's decode
+    step. None where the trace holds no run of it, no map of it, or nothing
+    under those names: nothing to read is not 0."""
+    p = ((reduction or {}).get("programs") or {}).get(program)
+    if not p or not p["runs"]:
+        return None
+    secs = sum(p[table].get(n, 0.0) for n in names)
+    if secs <= 0:
+        return None
+    return 1e3 * secs / reduction["chips"] / p["runs"]
 
 
 def describe(path, top=40):

@@ -17,7 +17,8 @@ if REPO not in sys.path:
 
 
 def load(relpath, name=None):
-    """Import ``perf/<relpath>`` by path."""
+    """Import ``perf/<relpath>`` (or a file by its absolute path) by
+    path."""
     path = os.path.join(PERF, relpath)
     name = name or "perf_test_" + relpath.replace("/", "_").replace(".", "_")
     spec = importlib.util.spec_from_file_location(name, path)
@@ -37,17 +38,19 @@ def fixture_root(tmp_path, workloads, configs, metrics=(), extra_files=()):
     and a ``BENCHMARK.json`` that names only fixture cells. ``workloads`` and
     ``configs`` map a name to the dict its file holds; ``metrics`` are
     ``BENCHMARK.json`` metric entries (end-to-end ones have no ``moves``);
-    ``extra_files`` are (relative path, text) pairs written under the
-    root."""
+    ``extra_files`` are (relative path, text) pairs written under the root.
+    The readers and the drivers are linked file by file, so that a fixture
+    can bring a reader or a driver of its own (``perf/drivers/<name>.py``
+    in ``extra_files``) without writing into the repository."""
     root = str(tmp_path)
     os.makedirs(os.path.join(root, "perf"))
-    for sub in ("workloads", "configs", "layer_metrics"):
+    for sub in ("workloads", "configs", "layer_metrics", "drivers"):
         os.makedirs(os.path.join(root, "perf", sub))
     for entry in os.listdir(PERF):
         src = os.path.join(PERF, entry)
         if entry in ("workloads", "configs", "__pycache__"):
             continue
-        if entry == "layer_metrics":
+        if entry in ("layer_metrics", "drivers"):
             for f in os.listdir(src):
                 if f.endswith(".py"):
                     os.symlink(os.path.join(src, f),
