@@ -12,7 +12,26 @@ admits up to ``max_prefills_per_tick`` queued requests (one compiled
 prefill each, bucketed by prompt length), then advances EVERY in-flight
 request by one token through ONE compiled decode step — requests join
 and leave the batch at tick granularity, no waiting for stragglers to
-finish a "batch". The decode step calls the model ONCE for a batch of
+finish a "batch".
+
+One decode step stays in flight across ticks: tick t dispatches step t
+and only THEN reads step t-1's tokens, appends them and releases the
+requests they complete, so the device runs step t while the host books
+step t-1, returns and takes the next submissions; the period of a tick
+is max(host, device) and not their sum. Step t's token inputs are step
+t-1's output where it lies on the device. That is exact because the
+schedule never depends on a token's value: a request ends at
+``max_new_tokens`` (the host knows, before step t-1 is read, which
+lanes it completes, and leaves them out of step t), deadlines and
+cancels are host events, and positions advance by one. A stop token
+would change that: the one step dispatched after a stop would be
+wasted and its token dropped. A request stays live until its last
+token is read; a token whose request was cancelled, timed out or
+extracted while its step was in flight is dropped (its lane and blocks
+may already serve another request: the device orders that prefill
+after the step in flight, both take the donated pool). ``idle`` is
+false while a step is in flight; ``drain`` and ``extract`` read it
+first. The decode step calls the model ONCE for a batch of
 ``lanes`` tokens over the KV pool where it lies (kvcache.py): per-lane
 state (its position, block table and sampling temperature) rides in the
 paged cache the attention layers are handed, each layer writes every
@@ -98,6 +117,16 @@ __all__ = ["ServingConfig", "ServingEngine"]
 
 def _ema(old: Optional[float], x: float, alpha: float = 0.5) -> float:
     return x if old is None else (1.0 - alpha) * old + alpha * x
+
+
+@dataclasses.dataclass
+class _Step:
+    """A dispatched decode step whose tokens the host has not read yet."""
+
+    tokens: Any                  # (lanes,) int32, where the step left them
+    logits: Any                  # (lanes, vocab) rows, or None
+    lanes: Dict[int, Request]    # the requests it advances, by lane
+    at: Tuple[float, float]      # (dispatch time, prefill seconds by then)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,6 +268,7 @@ class ServingEngine:
         self._steady_compiles = 0
         self._decode_ticks = 0
         self._decode_keys = 0  # keys the lanes' lengths covered, all ticks
+        self._decode_ahead = 0  # steps dispatched with the last one unread
         self._compile_watch = None
         self._spec: Optional[CacheSpec] = None
         self._pool = None
@@ -253,6 +283,16 @@ class ServingEngine:
         self._last_tok = np.zeros((B,), np.int32)
         self._temps = np.zeros((B,), np.float32)
         self._lane_mask = np.zeros((B,), bool)
+        # the next decode step's token inputs are the last step's output
+        # where it lies; a lane placed since then (prefill, adopt) is
+        # FRESH and reads its token from _last_tok on the host
+        self._tok_src = np.zeros((B,), np.int32)
+        self._fresh = np.zeros((B,), bool)
+        self._inflight: Optional[_Step] = None
+        # the decode EMA's clock: (when the last step's tokens were read,
+        # prefill seconds by then), and the prefill seconds so far
+        self._read_at = (0.0, 0.0)
+        self._prefill_wall = 0.0
 
     # -- model validation ---------------------------------------------------
 
@@ -504,8 +544,10 @@ class ServingEngine:
     def estimated_ttft_s(self) -> Optional[float]:
         """Admission-time TTFT estimate for a NEW submission: queue depth
         x the measured per-admission cost (prefill + one decode tick,
-        EMAs), scaled by the per-tick admission width. None until the
-        first prefill measured (the budget arms with the estimator)."""
+        EMAs; the decode EMA is the period between two steps' reads, less
+        the prefills run in it), scaled by the per-tick admission width.
+        None until the first prefill measured (the budget arms with the
+        estimator)."""
         if self._prefill_ema is None:
             return None
         per = self._prefill_ema + (self._decode_ema or 0.0)
@@ -634,7 +676,8 @@ class ServingEngine:
 
     @property
     def idle(self) -> bool:
-        return not self._queue and not self._active
+        return (not self._queue and not self._active
+                and self._inflight is None)
 
     @property
     def steady_state_compiles(self) -> int:
@@ -671,7 +714,7 @@ class ServingEngine:
             emit_request_record(self.router, t, req, trace=self.trace)
             self._run_prefill(req, t)
             n_pref += 1
-        if self._active:
+        if self._active or self._inflight is not None:
             self._run_decode(t)
         if self.watchdog is not None:
             self.watchdog.beat(t)
@@ -731,8 +774,9 @@ class ServingEngine:
                        reason=f"engine_error: {type(e).__name__}")
             emit_request_record(self.router, t, req, trace=self.trace)
             return
-        self._prefill_ema = _ema(
-            self._prefill_ema, time.perf_counter() - t0)
+        dt = time.perf_counter() - t0
+        self._prefill_ema = _ema(self._prefill_ema, dt)
+        self._prefill_wall += dt
         req.first_token_t = self.time_fn()
         req.tokens_out.append(tok)
         if cfg.collect_logits:
@@ -750,51 +794,107 @@ class ServingEngine:
         self._tables[lane, :len(req.blocks)] = req.blocks
         self._positions[lane] = L
         self._last_tok[lane] = tok
+        self._fresh[lane] = True
         self._temps[lane] = req.temperature
         self._lane_mask[lane] = True
         self._active[lane] = req
 
     def _run_decode(self, t: int) -> None:
-        cfg = self.config
-        # what the decode step reads of the pool: every active lane's keys
-        # [0, position], and nothing of an idle lane
-        self._decode_ticks += 1
-        self._decode_keys += int(self._positions[self._lane_mask].sum()
-                                 + self._lane_mask.sum())
-        t0 = time.perf_counter()
+        """Dispatch decode step t, then read step t-1 (module docstring):
+        a lane whose request step t-1's token completes takes no step t."""
+        prev = self._inflight
+        lanes = self._lane_mask.copy()
+        if prev is not None:
+            for lane, req in prev.lanes.items():
+                if (self._active.get(lane) is req
+                        and len(req.tokens_out) + 1 >= req.max_new_tokens):
+                    lanes[lane] = False
         try:
             with span("decode", router=self.router, step=t):
                 if self.fault_plan is not None:
                     # injected INSIDE the span: the inflated tick is
                     # exactly the span the stall warn flags
                     self.fault_plan.maybe_slow_decode(t)
-                out = self._decode_c(
-                    self._pool, self.variables, self._tables,
-                    self._positions,
-                    self._last_tok, self._temps, self._keys,
-                    self._lane_mask,
-                )
-                self._pool, nxts_dev, self._keys = out[:3]
-                nxts = np.asarray(nxts_dev)
-                logits_rows = (np.asarray(out[3])
-                               if cfg.collect_logits else None)
+                self._inflight = None
+                if lanes.any():
+                    self._dispatch_decode(lanes, ahead=prev is not None)
+                if prev is not None:
+                    self._read_decode(prev)
         except Exception as e:
             logger.exception("decode tick %d failed", t)
+            self._inflight = None
             for req in list(self._active.values()):
                 self._release(
                     req, FAILED, f"engine_error: {type(e).__name__}")
             raise
-        self._decode_ema = _ema(self._decode_ema, time.perf_counter() - t0)
-        for lane, req in list(self._active.items()):
+
+    def _dispatch_decode(self, lanes: np.ndarray, ahead: bool) -> None:
+        """Dispatch one decode step for ``lanes`` without waiting for it.
+        ``ahead``: the last step's tokens are still unread, and stay so
+        unless a fresh lane needs them merged on the host."""
+        tokens = self._tok_src
+        fresh = lanes & self._fresh
+        if fresh.any():
+            # a lane placed since the last step joins it: its first token
+            # is on the host, so the others' are fetched to sit beside it
+            # (the tick's prefill already waited for the last step)
+            tokens = np.array(tokens)
+            tokens[fresh] = self._last_tok[fresh]
+            ahead = False
+        self._fresh[lanes] = False
+        # what the decode step reads of the pool: every active lane's keys
+        # [0, position], and nothing of an idle lane
+        self._decode_ticks += 1
+        self._decode_ahead += int(ahead)
+        self._decode_keys += int(self._positions[lanes].sum() + lanes.sum())
+        at = (time.perf_counter(), self._prefill_wall)
+        # copies: the host advances its arrays while the step runs
+        out = self._decode_c(
+            self._pool, self.variables, self._tables.copy(),
+            self._positions.copy(), tokens, self._temps.copy(), self._keys,
+            lanes,
+        )
+        self._pool, self._tok_src, self._keys = out[:3]
+        self._positions[lanes] += 1
+        self._inflight = _Step(
+            tokens=self._tok_src,
+            logits=out[3] if self.config.collect_logits else None,
+            lanes={lane: req for lane, req in self._active.items()
+                   if lanes[lane]},
+            at=at,
+        )
+
+    def _read_decode(self, step: _Step) -> None:
+        """Read a dispatched step's tokens into their requests and release
+        the requests they complete. A request that left its lane while the
+        step was in flight (cancel, deadline, extract) gets no token."""
+        nxts = np.asarray(step.tokens)
+        logits_rows = (np.asarray(step.logits)
+                       if step.logits is not None else None)
+        now = time.perf_counter()
+        # the tick's period since the later of this step's dispatch and
+        # the last read, less the prefills run in it
+        start, prefill_then = max(step.at, self._read_at)
+        self._decode_ema = _ema(
+            self._decode_ema,
+            now - start - (self._prefill_wall - prefill_then))
+        self._read_at = (now, self._prefill_wall)
+        for lane, req in step.lanes.items():
+            if self._active.get(lane) is not req:
+                continue
             tok = int(nxts[lane])
             req.tokens_out.append(tok)
+            self._last_tok[lane] = tok
             if logits_rows is not None:
                 req.logits = (req.logits or []) + [logits_rows[lane]]
             if len(req.tokens_out) >= req.max_new_tokens:
                 self._release(req, COMPLETED, None)
-            else:
-                self._positions[lane] += 1
-                self._last_tok[lane] = tok
+
+    def _settle(self) -> None:
+        """Read the decode step in flight, if any, dispatching none."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._read_decode(step)
 
     def _release(self, req: Request, state: str,
                  reason: Optional[str]) -> None:
@@ -803,16 +903,20 @@ class ServingEngine:
         leak past an ending."""
         lane = req.lane
         if lane is not None and self._active.get(lane) is req:
-            del self._active[lane]
-            self._lane_mask[lane] = False
-            self._tables[lane, :] = self.config.num_blocks
-            self._positions[lane] = 0
-            self._last_tok[lane] = 0
-            self._temps[lane] = 0.0
+            self._vacate(lane)
         self.allocator.free(req.blocks)
         transition(req, state, now=self.time_fn(), reason=reason)
         emit_request_record(self.router, self._tick, req,
                             trace=self.trace)
+
+    def _vacate(self, lane: int) -> None:
+        del self._active[lane]
+        self._lane_mask[lane] = False
+        self._tables[lane, :] = self.config.num_blocks
+        self._positions[lane] = 0
+        self._last_tok[lane] = 0
+        self._fresh[lane] = False
+        self._temps[lane] = 0.0
 
     def _expire(self, now: float) -> None:
         """Deadline enforcement, EVERY tick, queue and batch alike."""
@@ -843,8 +947,12 @@ class ServingEngine:
         handoff. Returns None unless ``rid`` is live in a decode lane
         (queued/terminal requests have nothing to hand off). The lane
         and blocks are reclaimed here; the request leaves this engine's
-        books entirely — its lifecycle continues on the adopter.
+        books entirely — its lifecycle continues on the adopter. The
+        decode step in flight is read first, so that the handed-over
+        cache and ``tokens_out`` agree (a request that step completes
+        ends here, and None is returned).
         """
+        self._settle()
         req = self._requests.get(rid)
         if req is None or req.state != DECODE or req.lane is None:
             return None
@@ -866,12 +974,7 @@ class ServingEngine:
             "n_blocks": len(ids),
             "bytes": int(nbytes),
         }
-        del self._active[lane]
-        self._lane_mask[lane] = False
-        self._tables[lane, :] = self.config.num_blocks
-        self._positions[lane] = 0
-        self._last_tok[lane] = 0
-        self._temps[lane] = 0.0
+        self._vacate(lane)
         self.allocator.free(req.blocks)
         req.lane, req.blocks = None, ()
         del self._requests[rid]
@@ -924,6 +1027,7 @@ class ServingEngine:
         self._tables[lane, :len(ids)] = ids
         self._positions[lane] = payload["position"]
         self._last_tok[lane] = payload["last_token"]
+        self._fresh[lane] = True
         self._temps[lane] = req.temperature
         self._lane_mask[lane] = True
         self.trace.adopted(self._tick, req)
@@ -983,6 +1087,9 @@ class ServingEngine:
                         evicted += 1
                     break
                 self.tick()
+            # a step still in flight advances no live request now: its
+            # tokens are dropped
+            self._settle()
         # summarize by the ACTUAL endings of the requests that were in
         # flight at drain start — a request whose OWN deadline expired
         # inside the window is a timeout, not a finish; the jsonl stream
@@ -1037,12 +1144,14 @@ class ServingEngine:
     def stats(self) -> dict:
         """Aggregate serving outcome (docs/serving.md): per-terminal
         counts, shed reasons, TTFT percentiles over requests that got a
-        first token, the zero-recompile violation counter, and
+        first token, the zero-recompile violation counter,
         ``decode_keys_read_share``: over the decode ticks so far, the keys
         the lanes' lengths covered over ``lanes * max_seq_len``, i.e. the
         share of a fixed full window per lane that decode attention, which
         stops at each lane's length, had to read (None before the first
-        decode tick)."""
+        decode tick), and ``decode_dispatched_ahead_share``: the decode
+        steps dispatched while the previous step's tokens were still
+        unread, over all decode steps (None before the first)."""
         from apex_tpu.serving.loadgen import percentile
 
         counts: Dict[str, int] = {}
@@ -1077,5 +1186,8 @@ class ServingEngine:
             "decode_keys_read_share": (
                 self._decode_keys / (self._decode_ticks * self.config.lanes
                                      * self.config.max_seq_len)
+                if self._decode_ticks else None),
+            "decode_dispatched_ahead_share": (
+                self._decode_ahead / self._decode_ticks
                 if self._decode_ticks else None),
         }

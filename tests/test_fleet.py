@@ -519,3 +519,92 @@ def test_fleet_chaos_drill():
     assert any(r.get("alert") for r in slo_recs)
     assert all(r["n"] >= r["violations"] >= r["sheds"] >= 0
                for r in slo_recs)
+
+
+# -- KV handoff with a decode step in flight --------------------------------
+
+
+@pytest.mark.parametrize("ticks", [1, 4])
+def test_extract_adopt_with_a_step_in_flight(ticks):
+    """The source keeps one decode step in flight across ticks; ``extract``
+    reads it first, so the handed-over cache and ``tokens_out`` agree, and
+    the adopter (itself mid-step on a bystander) goes on to the tokens of
+    an engine that was never interrupted."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models import GPTModel
+    from apex_tpu.serving import ServingConfig, ServingEngine
+    from apex_tpu.transformer import TransformerConfig
+
+    model = GPTModel(config=TransformerConfig(
+        num_layers=1, hidden_size=32, num_attention_heads=4, vocab_size=61,
+        max_position_embeddings=64, hidden_dropout=0.0,
+        attention_dropout=0.0, position_embedding_type="rope",
+        compute_dtype=jnp.float32,
+    ))
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    cfg = ServingConfig(lanes=2, block_size=8, num_blocks=12,
+                        max_seq_len=32, prefill_buckets=(8, 16), seed=0)
+    prompt, other = np.random.RandomState(3).randint(0, 61, size=(2, 9))
+    engines = [ServingEngine(model, variables, cfg).start()
+               for _ in range(3)]
+    for eng in engines:
+        # the compile watcher is process-wide: the others' start-up
+        # compiles are booked, not steady-state
+        eng.acknowledge_compiles()
+    plain, src, dst = engines
+    want = plain.submit(prompt.astype(np.int32), max_new_tokens=10)
+    while not plain.idle:
+        plain.tick()
+    assert want.state == "completed"
+
+    bystander = dst.submit(other.astype(np.int32), max_new_tokens=12)
+    dst.tick()
+    req = src.submit(prompt.astype(np.int32), max_new_tokens=10, rid=5)
+    for _ in range(ticks):
+        src.tick()
+    assert src._inflight is not None and dst._inflight is not None
+    seen = len(req.tokens_out)
+    payload = src.extract(req.rid)
+    assert payload is not None and src.idle
+    # the step in flight was read: its token joined, the cursor follows it
+    assert len(req.tokens_out) == seen + 1
+    assert payload["last_token"] == req.tokens_out[-1]
+    assert payload["position"] == len(prompt) + len(req.tokens_out) - 1
+    assert dst.adopt(payload)
+    while not dst.idle:
+        dst.tick()
+    assert req.state == "completed" and req.tokens_out == want.tokens_out
+    assert bystander.state == "completed"
+    assert all(e.steady_state_compiles == 0 for e in engines)
+    assert dst.allocator.free_blocks == cfg.num_blocks
+
+
+def test_extract_of_a_request_its_step_in_flight_completes():
+    """A request whose last token is in flight completes when ``extract``
+    reads the step: nothing is handed over."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models import GPTModel
+    from apex_tpu.serving import ServingConfig, ServingEngine
+    from apex_tpu.transformer import TransformerConfig
+
+    model = GPTModel(config=TransformerConfig(
+        num_layers=1, hidden_size=32, num_attention_heads=4, vocab_size=61,
+        max_position_embeddings=64, hidden_dropout=0.0,
+        attention_dropout=0.0, position_embedding_type="rope",
+    ))
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    eng = ServingEngine(model, variables, ServingConfig(
+        lanes=2, block_size=8, num_blocks=8, max_seq_len=32,
+        prefill_buckets=(8,), seed=0)).start()
+    req = eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=2)
+    eng.tick()      # prefill's token, and the last step dispatched
+    assert req.state == "decode" and len(req.tokens_out) == 1
+    assert eng.extract(req.rid) is None
+    assert req.state == "completed" and len(req.tokens_out) == 2
+    assert eng.idle and eng.allocator.free_blocks == 8

@@ -705,3 +705,249 @@ def test_serving_cancel_and_drain_hardening():
             terminal.setdefault(r["id"], []).append(r["state"])
     assert set(terminal) == {a.rid, b.rid, c.rid, 997, late.rid}
     assert all(len(v) == 1 for v in terminal.values())
+
+
+# -- one decode step in flight across ticks (engine.py) ---------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_gpt():
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models import GPTModel
+    from apex_tpu.transformer import TransformerConfig
+
+    model = GPTModel(config=TransformerConfig(
+        num_layers=1, hidden_size=32, num_attention_heads=4, vocab_size=37,
+        max_position_embeddings=0, position_embedding_type="rope",
+        hidden_dropout=0.0, attention_dropout=0.0,
+        compute_dtype=jnp.float32,
+    ))
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 4), jnp.int32))
+    return model, variables
+
+
+@pytest.fixture(scope="module")
+def greedy(tiny_gpt):
+    """Step-by-step greedy reference over the contiguous cache: a prefill,
+    then one ``decode_step`` call a token, each token read before the next
+    step is made (jitted, the cache at the engines' ``max_seq_len``, so
+    one decode program serves every reference)."""
+    import jax
+    import jax.numpy as jnp
+
+    model, variables = tiny_gpt
+    cache_len = 32
+    prefill = jax.jit(lambda v, p: model.apply(
+        v, p, cache_len=cache_len, mutable=["cache"]))
+    step = jax.jit(lambda v, c, tok, pos: model.apply(
+        {**v, "cache": c}, tok, position_ids=pos, cache_len=cache_len,
+        decode_step=True, mutable=["cache"]))
+
+    def run(prompt, max_new):
+        logits, state = prefill(variables, jnp.asarray(prompt)[None])
+        tokens = [int(np.asarray(logits[0, -1]).argmax())]
+        cache = state["cache"]
+        for cur in range(len(prompt), len(prompt) + max_new - 1):
+            logits, upd = step(variables, cache,
+                               jnp.asarray([[tokens[-1]]], jnp.int32),
+                               jnp.asarray([[cur]], jnp.int32))
+            cache = upd["cache"]
+            tokens.append(int(np.asarray(logits[0, 0]).argmax()))
+        return tokens
+
+    return run
+
+
+def _prompts(*lens, seed=11):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 37, size=n).astype(np.int32) for n in lens]
+
+
+def _engine(tiny_gpt, **kw):
+    from apex_tpu.serving import ServingConfig, ServingEngine
+
+    time_fn = kw.pop("time_fn", time.monotonic)
+    cfg = dict(lanes=3, block_size=8, num_blocks=12, max_seq_len=32,
+               prefill_buckets=(8, 16), max_queue_depth=8, seed=0)
+    cfg.update(kw)
+    return ServingEngine(*tiny_gpt, ServingConfig(**cfg),
+                         time_fn=time_fn).start()
+
+
+def _run_to_idle(eng, limit=200):
+    for _ in range(limit):
+        if eng.idle:
+            return
+        eng.tick()
+    raise AssertionError("the engine never went idle")
+
+
+# (prompt length, max_new_tokens, tick it is submitted before): mixed
+# lengths through both buckets, answers of 1 and 2 tokens, and requests
+# that arrive while a decode step is in flight, into lanes freed mid-run
+AHEAD_CASES = {
+    "mixed_lengths": [(5, 7, 0), (13, 4, 0), (3, 9, 0)],
+    "admitted_in_flight": [(6, 8, 0), (11, 6, 2), (4, 5, 3), (9, 7, 5),
+                           (2, 6, 9)],
+    "one_and_two_tokens": [(7, 1, 0), (5, 2, 0), (12, 2, 1), (3, 1, 1),
+                           (8, 6, 2), (4, 2, 4)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(AHEAD_CASES))
+def test_dispatched_ahead_serves_the_step_by_step_tokens(tiny_gpt, greedy,
+                                                       case):
+    """Step t runs on step t-1's tokens where they lie while the host
+    reads step t-1: the tokens served are the reference's, one request at
+    a time, token for token."""
+    plan = AHEAD_CASES[case]
+    prompts = _prompts(*(n for n, _, _ in plan))
+    want = [greedy(p, m) for p, (_, m, _) in zip(prompts, plan)]
+    eng = _engine(tiny_gpt)
+    reqs, tick = [None] * len(plan), 0
+    while any(r is None for r in reqs) or not eng.idle:
+        for i, (_, m, at) in enumerate(plan):
+            if reqs[i] is None and at <= tick:
+                reqs[i] = eng.submit(prompts[i], max_new_tokens=m)
+        eng.tick()
+        tick += 1
+        assert tick < 200
+    assert [r.state for r in reqs] == ["completed"] * len(plan)
+    assert [r.tokens_out for r in reqs] == want
+    assert eng.allocator.free_blocks == eng.config.num_blocks
+    assert eng.steady_state_compiles == 0
+
+
+def test_dispatched_ahead_compiles_nothing_after_start(tiny_gpt):
+    """Admissions into a batch with a step in flight, a cancel and an
+    extract read mid-flight: every program the tick runs was compiled in
+    start()."""
+    prompts = _prompts(5, 12, 3, 9)
+    eng = _engine(tiny_gpt)
+    a = eng.submit(prompts[0], max_new_tokens=9)
+    eng.tick()
+    b = eng.submit(prompts[1], max_new_tokens=6)
+    eng.tick()
+    eng.cancel(a.rid)
+    c = eng.submit(prompts[2], max_new_tokens=5)
+    eng.tick()
+    eng.tick()
+    assert eng.extract(b.rid) is not None
+    eng.submit(prompts[3], max_new_tokens=4)
+    _run_to_idle(eng)
+    assert c.state == "completed"
+    assert eng.steady_state_compiles == 0
+    assert eng.stats()["steady_state_compiles"] == 0
+
+
+def test_cancel_with_a_step_in_flight_drops_its_token(tiny_gpt, greedy):
+    """The in-flight token of a cancelled request is dropped, its lane and
+    blocks go to the next request in the same tick (the device orders that
+    prefill after the step in flight), and that request's tokens are
+    right."""
+    pa, pb = _prompts(6, 7)
+    want_b = greedy(pb, 9)
+    # one lane and blocks for one request: B can only take A's
+    eng = _engine(tiny_gpt, lanes=1, num_blocks=3, prefill_buckets=(8,),
+                  max_seq_len=24)
+    a = eng.submit(pa, max_new_tokens=12)
+    eng.tick()
+    eng.tick()
+    assert eng._inflight is not None and len(a.tokens_out) == 2
+    lane, blocks = a.lane, set(a.blocks)
+    b = eng.submit(pb, max_new_tokens=9)
+    assert b.state == "queued"
+    assert eng.cancel(a.rid)
+    assert a.state == "cancelled" and len(a.tokens_out) == 2
+    eng.tick()      # B admitted into A's lane and blocks; A's token read
+    assert (b.state, b.lane) == ("decode", lane)
+    assert set(b.blocks) <= blocks
+    assert len(a.tokens_out) == 2
+    _run_to_idle(eng)
+    assert b.state == "completed" and b.tokens_out == want_b
+    assert len(a.tokens_out) == 2
+    assert eng.allocator.free_blocks == 3
+
+
+def test_deadline_with_a_step_in_flight_drops_its_token(tiny_gpt, greedy):
+    clock = {"t": 100.0}
+    pa, pb = _prompts(5, 9)
+    want_b = greedy(pb, 6)
+    eng = _engine(tiny_gpt, time_fn=lambda: clock["t"])
+    a = eng.submit(pa, max_new_tokens=10, deadline_s=1.0)
+    b = eng.submit(pb, max_new_tokens=6)
+    eng.tick()      # A prefilled, its first decode step dispatched
+    eng.tick()      # B prefilled, both step; A's first step read
+    assert eng._inflight is not None and len(a.tokens_out) == 2
+    clock["t"] += 2.0
+    eng.tick()      # the sweep times A out before its step is read
+    assert (a.state, a.reason) == ("timed_out", "deadline")
+    assert len(a.tokens_out) == 2
+    assert a.lane not in eng._active
+    _run_to_idle(eng)
+    assert len(a.tokens_out) == 2
+    assert b.state == "completed" and b.tokens_out == want_b
+    assert eng.allocator.free_blocks == eng.config.num_blocks
+
+
+def test_idle_and_drain_with_a_step_in_flight(tiny_gpt, greedy):
+    """``idle`` is false while a step is in flight, even with no request
+    left to serve; ``drain`` reads the step, finishing what it carries."""
+    pa, pb, pc = _prompts(5, 8, 4)
+    want_b = greedy(pb, 5)
+    eng = _engine(tiny_gpt)
+    a = eng.submit(pa, max_new_tokens=6)
+    eng.tick()
+    eng.cancel(a.rid)
+    assert not eng._queue and not eng._active
+    assert not eng.idle         # A's step is still in flight
+    eng.tick()                  # read, its token dropped
+    assert eng.idle and len(a.tokens_out) == 1
+
+    b = eng.submit(pb, max_new_tokens=5)
+    eng.tick()
+    eng.tick()
+    assert not eng.idle and eng._inflight is not None
+    report = eng.drain(grace_s=60.0)
+    assert report["finished"] == 1 and report["evicted"] == 0
+    assert b.state == "completed" and b.tokens_out == want_b
+    assert eng.idle and eng._inflight is None
+
+    # a zero-grace drain evicts, and the step in flight is read with it
+    eng2 = _engine(tiny_gpt)
+    c = eng2.submit(pc, max_new_tokens=8)
+    eng2.tick()
+    assert eng2._inflight is not None
+    report2 = eng2.drain(grace_s=0.0)
+    assert report2["evicted"] == 1
+    assert (c.state, c.reason) == ("timed_out", "drain_deadline")
+    assert len(c.tokens_out) == 1
+    assert eng2.idle and eng2.allocator.free_blocks == 12
+
+
+def test_decode_dispatched_ahead_share(tiny_gpt):
+    """A step counts as dispatched ahead when the last one's tokens were
+    still unread: not the first step after an empty engine, nor the step
+    of a tick that admitted a request (its first token is merged with the
+    others' on the host)."""
+    pa, pb = _prompts(5, 6)
+    eng = _engine(tiny_gpt)
+    assert eng.stats()["decode_dispatched_ahead_share"] is None
+    a = eng.submit(pa, max_new_tokens=6)    # 5 decode steps
+    _run_to_idle(eng)
+    assert a.state == "completed"
+    assert eng.stats()["decode_dispatched_ahead_share"] == pytest.approx(
+        4 / 5)
+    a = eng.submit(pa, max_new_tokens=7)    # steps 6-11
+    eng.tick()
+    eng.tick()
+    b = eng.submit(pb, max_new_tokens=3)    # joins at step 8, merged
+    _run_to_idle(eng)
+    assert a.state == b.state == "completed"
+    # 11 steps: the two after an empty engine and the one B joined are
+    # not ahead
+    assert eng.stats()["decode_dispatched_ahead_share"] == pytest.approx(
+        8 / 11)
