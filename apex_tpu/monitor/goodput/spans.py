@@ -74,6 +74,8 @@ __all__ = [
     "set_router",
     "get_router",
     "flush_open_spans",
+    "profiling",
+    "open_annotation",
 ]
 
 #: The closed phase taxonomy. Every span names exactly one of these;
@@ -250,14 +252,28 @@ def emit_span(router, phase: str, start: float, dur_s: float,
     )
 
 
-def _open_annotation(phase: str):
-    """The span on the profiler's clock: an entered
-    ``jax.profiler.TraceAnnotation``, or None when jax is not imported
-    (never imported from here: the module stays jax-free)."""
+def profiling():
+    """``jax.profiler`` while a profiler session records, else None (jax
+    not imported, or no session on): the one flag test before an
+    annotation. jax is looked up, never imported from here, so the module
+    stays jax-free; an annotation made with no session on records
+    nothing, so skipping it loses nothing."""
     profiler = getattr(sys.modules.get("jax"), "profiler", None)
     if profiler is None:
         return None
-    annotation = profiler.TraceAnnotation(phase)
+    on = getattr(profiler.TraceAnnotation, "is_enabled", None)
+    return profiler if on is None or on() else None
+
+
+def open_annotation(name: str, profiler=None):
+    """``name`` on the profiler's clock: an entered
+    ``jax.profiler.TraceAnnotation``, or None while :func:`profiling`
+    finds no session. A caller that already holds ``profiler`` (from
+    :func:`profiling`) passes it and skips the test."""
+    profiler = profiler or profiling()
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(name)
     annotation.__enter__()
     return annotation
 
@@ -283,7 +299,7 @@ class Span:
         self.fields = fields
         self._router = router
         self._closed = False
-        self._annotation = _open_annotation(phase)
+        self._annotation = open_annotation(phase)
         self.start = time.perf_counter()
         with _LOCK:
             _OPEN[id(self)] = self

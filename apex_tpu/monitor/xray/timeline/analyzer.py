@@ -39,10 +39,12 @@ and backward), to the flax module, and — for a Pallas custom-call — to
 the kernel its ``kernel_metadata`` names. The self times of one device
 sum to its busy union, so the table's total is the device's busy time;
 what no rule could place is reported as ``(unattributed)``, never
-dropped. The device's idle gaps are put down to the host annotation
-that covers each (the goodput spans open a
+dropped. The device's idle time is put down, instant by instant, to
+the innermost host annotation that covers it (the goodput spans open a
 ``jax.profiler.TraceAnnotation``, so ``data_wait``, ``ckpt_save``,
-``snapshot`` are on the capture's clock).
+``snapshot`` are on the capture's clock; the serving engine's
+``engine.<part>`` annotations nest inside its ``prefill`` / ``decode``
+spans, and the innermost is the part of the tick the host was in).
 
 Everything emits ``kind="profile"`` records through the shared
 MetricRouter schema; ``python -m apex_tpu.monitor.xray.timeline`` is
@@ -440,6 +442,40 @@ def self_times(
     return [(e, max(t, 0.0)) for e, t in out]
 
 
+def idle_by_innermost(gaps: Sequence[Interval],
+                      host: Sequence[TraceEvent]) -> Dict[str, float]:
+    """Each instant of ``gaps`` (sorted, disjoint) to the innermost of the
+    ``host`` annotations that cover it: of those, the one that began last
+    (of two that began together, the one that ends first), named by its
+    name, or ``step`` for a step marker; NO_ANNOTATION where none covers
+    it. One sweep over the annotations' edges."""
+    host = [h for h in host if h.end > h.ts]
+    edges = sorted([(h.ts, 1, i) for i, h in enumerate(host)]
+                   + [(h.end, 0, i) for i, h in enumerate(host)])
+    covering: Dict[int, TraceEvent] = {}
+    idle: Dict[str, float] = collections.Counter()
+    k = 0
+    for gs, ge in gaps:
+        t = gs
+        while t < ge:
+            while k < len(edges) and edges[k][0] <= t:
+                _, begins, i = edges[k]
+                if begins:
+                    covering[i] = host[i]
+                else:
+                    covering.pop(i, None)
+                k += 1
+            nxt = min(ge, edges[k][0]) if k < len(edges) else ge
+            if covering:
+                h = max(covering.values(), key=lambda h: (h.ts, -h.end))
+                name = h.name if h.step_num is None else "step"
+            else:
+                name = NO_ANNOTATION
+            idle[name] += nxt - t
+            t = nxt
+    return idle
+
+
 def _scope_of_event(e: TraceEvent) -> Optional[OpScope]:
     """The scope an op event names itself: a TPU event carries its
     instruction's ``op_name`` path as ``args["tf_op"]`` (with a trailing
@@ -534,15 +570,8 @@ def attribute_scopes(
             and (e.name in wanted or e.step_num is not None)]
     lo = min([e.ts for e in ops] + [h.ts for h in host])
     hi = max([e.end for e in ops] + [h.end for h in host])
-    idle: Dict[str, float] = collections.Counter()
-    for gs, ge in subtract_intervals([(lo, hi)], busy[devices[0]]):
-        best, best_ov = NO_ANNOTATION, 0.0
-        for h in host:
-            ov = min(ge, h.end) - max(gs, h.ts)
-            if ov > best_ov:
-                best = h.name if h.step_num is None else "step"
-                best_ov = ov
-        idle[best] += ge - gs
+    idle = idle_by_innermost(
+        subtract_intervals([(lo, hi)], busy[devices[0]]), host)
     return ScopeBreakdown(
         n_steps=n_steps,
         n_devices=n_dev,

@@ -40,6 +40,19 @@ table and length (``ops.paged_decode_attention``), so per-request
 positions diverge freely, nothing is gathered into a contiguous window,
 and a lane costs what it holds (``stats()["decode_keys_read_share"]``).
 
+The host's own account of every tick: its wall time booked into five
+parts that add up to it exactly (``TICK_PARTS``: admission, each
+prefill through its first token, the decode call's dispatch, the wait
+for a device value of the decode path, and the bookkeeping that is the
+rest), with the tick's start, the lanes stepped, the prefills run and
+whether the step went ahead, kept in a ring of the newest
+``TICK_LOG_TICKS`` ticks (:meth:`ServingEngine.tick_log`,
+``stats()["host_tick_ms"]``). Each part also opens a
+``jax.profiler.TraceAnnotation`` named ``engine.<part>`` while a
+profiler session records (one flag test a tick otherwise), nested in
+the goodput ``prefill`` / ``decode`` spans, so a capture puts the
+device's idle gaps down to the part of the tick that kept it waiting.
+
 Zero steady-state recompiles: prefill shapes are BUCKETED (block-size
 multiples, doubling up to ``max_seq_len``) and every bucket plus the
 decode step is AOT-compiled (``jit(...).lower(...).compile()``) in
@@ -92,7 +105,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from apex_tpu.monitor.goodput.spans import span
+from apex_tpu.monitor.goodput.spans import open_annotation, profiling, span
 from apex_tpu.serving.kvcache import BlockAllocator, CacheSpec, blocks_needed
 from apex_tpu.serving.lifecycle import (
     ADMITTED,
@@ -112,7 +125,17 @@ from apex_tpu.serving.trace.emit import TraceEmitter
 
 logger = logging.getLogger("apex_tpu.serving")
 
-__all__ = ["ServingConfig", "ServingEngine"]
+__all__ = ["ServingConfig", "ServingEngine", "TICK_PARTS", "TICK_LOG_TICKS"]
+
+#: the parts of a tick's host time (module docstring), in the order a tick
+#: runs them; ``book`` is the tick less the other four
+TICK_PARTS = ("admit", "prefill", "dispatch", "wait", "book")
+#: ticks the account keeps, the newest (a 50 s chat window runs 6-7,000)
+TICK_LOG_TICKS = 65536
+_TICK_RECORD = np.dtype(
+    [("start_ns", np.int64)] + [(p + "_ns", np.int64) for p in TICK_PARTS]
+    + [("lanes", np.int32), ("prefills", np.int32), ("ahead", np.bool_)])
+_ADMIT, _PREFILL, _DISPATCH, _WAIT, _BOOK = range(len(TICK_PARTS))
 
 
 def _ema(old: Optional[float], x: float, alpha: float = 0.5) -> float:
@@ -127,6 +150,97 @@ class _Step:
     logits: Any                  # (lanes, vocab) rows, or None
     lanes: Dict[int, Request]    # the requests it advances, by lane
     at: Tuple[float, float]      # (dispatch time, prefill seconds by then)
+
+
+class _Part:
+    """One timed part of the tick (prefill, dispatch, wait) as a context:
+    adds its nanoseconds to the account's, under its annotation while a
+    profiler session records."""
+
+    __slots__ = ("acct", "index", "name", "t0", "annotation")
+
+    def __init__(self, acct: "_TickAccount", index: int):
+        self.acct, self.index = acct, index
+        self.name = "engine." + TICK_PARTS[index]
+
+    def __enter__(self) -> None:
+        prof = self.acct.profiler
+        self.annotation = (None if prof is None
+                           else open_annotation(self.name, prof))
+        self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc) -> None:
+        self.acct.ns[self.index] += time.perf_counter_ns() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+
+
+class _TickAccount:
+    """The host's time a tick by part (module docstring), and the ring of
+    the ticks booked. ``admit`` runs from the tick's start to the end of
+    admission, less the prefills in it; ``book`` is the rest of the tick
+    less dispatch and wait, so the five add up to the tick exactly. A wait
+    outside a tick (``extract``, ``drain`` reading the step in flight) is
+    dropped by the next tick's start."""
+
+    def __init__(self, size: int):
+        self.ring = np.zeros(size, _TICK_RECORD)
+        self.booked = 0           # ticks booked, all time
+        self.ns = [0] * len(TICK_PARTS)
+        self.profiler = None      # jax.profiler while a session records
+        self.lanes = self.prefills = 0
+        self.ahead = False
+        self.prefill, self.dispatch, self.wait = (
+            _Part(self, i) for i in (_PREFILL, _DISPATCH, _WAIT))
+        self._start = self._admitted = 0
+        self._annotation = None   # the open engine.admit / engine.book
+
+    def _open(self, part: int) -> None:
+        if self.profiler is not None:
+            self._annotation = open_annotation(
+                "engine." + TICK_PARTS[part], self.profiler)
+
+    def close(self) -> None:
+        """Close the open ``engine.admit`` / ``engine.book`` annotation."""
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+
+    def begin(self) -> None:
+        self._start = time.perf_counter_ns()
+        self.ns = [0] * len(TICK_PARTS)
+        self.lanes = 0
+        self.ahead = False
+        self.profiler = profiling()
+        self._open(_ADMIT)
+
+    def admitted(self, prefills: int) -> None:
+        """Admission is over, with ``prefills`` run: the rest of the tick
+        is decode and book."""
+        self.close()
+        self._admitted = time.perf_counter_ns()
+        self.prefills = prefills
+
+    def book(self) -> None:
+        """Open ``engine.book`` over bookkeeping to come (time is booked
+        by difference; this is the annotation alone)."""
+        self._open(_BOOK)
+
+    def end(self) -> None:
+        self.close()
+        end = time.perf_counter_ns()
+        ns, start = self.ns, self._start
+        ns[_ADMIT] = self._admitted - start - ns[_PREFILL]
+        ns[_BOOK] = end - self._admitted - ns[_DISPATCH] - ns[_WAIT]
+        self.ring[self.booked % len(self.ring)] = (
+            start, *ns, self.lanes, self.prefills, self.ahead)
+        self.booked += 1
+
+    def log(self) -> Dict[str, np.ndarray]:
+        size = len(self.ring)
+        kept = np.arange(max(0, self.booked - size), self.booked) % size
+        rows = self.ring[kept]
+        return {name: rows[name].copy() for name in _TICK_RECORD.names}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,6 +383,7 @@ class ServingEngine:
         self._decode_ticks = 0
         self._decode_keys = 0  # keys the lanes' lengths covered, all ticks
         self._decode_ahead = 0  # steps dispatched with the last one unread
+        self._acct = _TickAccount(TICK_LOG_TICKS)
         self._compile_watch = None
         self._spec: Optional[CacheSpec] = None
         self._pool = None
@@ -689,6 +804,8 @@ class ServingEngine:
         """One scheduler iteration (module docstring); returns the tick
         number just executed."""
         self._ensure_started()
+        acct = self._acct
+        acct.begin()
         t = self._tick
         now = self.time_fn()
         self._expire(now)
@@ -714,8 +831,10 @@ class ServingEngine:
             emit_request_record(self.router, t, req, trace=self.trace)
             self._run_prefill(req, t)
             n_pref += 1
+        acct.admitted(n_pref)
         if self._active or self._inflight is not None:
             self._run_decode(t)
+        acct.book()
         if self.watchdog is not None:
             self.watchdog.beat(t)
         if self._compile_watch is not None:
@@ -744,6 +863,7 @@ class ServingEngine:
                 peak_used_blocks=self.allocator.peak_used_blocks,
             ))
         self._tick += 1
+        acct.end()
         return t
 
     def _run_prefill(self, req: Request, t: int) -> None:
@@ -759,7 +879,8 @@ class ServingEngine:
         block_ids[:k] = req.blocks[:k]
         t0 = time.perf_counter()
         try:
-            with span("prefill", router=self.router, step=t):
+            with span("prefill", router=self.router, step=t), \
+                    self._acct.prefill:
                 out = self._prefill_c[P](
                     self._pool, self.variables, tokens, np.int32(L),
                     block_ids,
@@ -811,15 +932,19 @@ class ServingEngine:
                     lanes[lane] = False
         try:
             with span("decode", router=self.router, step=t):
-                if self.fault_plan is not None:
-                    # injected INSIDE the span: the inflated tick is
-                    # exactly the span the stall warn flags
-                    self.fault_plan.maybe_slow_decode(t)
-                self._inflight = None
-                if lanes.any():
-                    self._dispatch_decode(lanes, ahead=prev is not None)
-                if prev is not None:
-                    self._read_decode(prev)
+                self._acct.book()   # dispatch and wait nest in it
+                try:
+                    if self.fault_plan is not None:
+                        # injected INSIDE the span: the inflated tick is
+                        # exactly the span the stall warn flags
+                        self.fault_plan.maybe_slow_decode(t)
+                    self._inflight = None
+                    if lanes.any():
+                        self._dispatch_decode(lanes, ahead=prev is not None)
+                    if prev is not None:
+                        self._read_decode(prev)
+                finally:
+                    self._acct.close()
         except Exception as e:
             logger.exception("decode tick %d failed", t)
             self._inflight = None
@@ -832,13 +957,15 @@ class ServingEngine:
         """Dispatch one decode step for ``lanes`` without waiting for it.
         ``ahead``: the last step's tokens are still unread, and stay so
         unless a fresh lane needs them merged on the host."""
+        acct = self._acct
         tokens = self._tok_src
         fresh = lanes & self._fresh
         if fresh.any():
             # a lane placed since the last step joins it: its first token
             # is on the host, so the others' are fetched to sit beside it
             # (the tick's prefill already waited for the last step)
-            tokens = np.array(tokens)
+            with acct.wait:
+                tokens = np.array(tokens)
             tokens[fresh] = self._last_tok[fresh]
             ahead = False
         self._fresh[lanes] = False
@@ -849,11 +976,11 @@ class ServingEngine:
         self._decode_keys += int(self._positions[lanes].sum() + lanes.sum())
         at = (time.perf_counter(), self._prefill_wall)
         # copies: the host advances its arrays while the step runs
-        out = self._decode_c(
-            self._pool, self.variables, self._tables.copy(),
-            self._positions.copy(), tokens, self._temps.copy(), self._keys,
-            lanes,
-        )
+        args = (self._pool, self.variables, self._tables.copy(),
+                self._positions.copy(), tokens, self._temps.copy(),
+                self._keys, lanes)
+        with acct.dispatch:
+            out = self._decode_c(*args)
         self._pool, self._tok_src, self._keys = out[:3]
         self._positions[lanes] += 1
         self._inflight = _Step(
@@ -863,14 +990,16 @@ class ServingEngine:
                    if lanes[lane]},
             at=at,
         )
+        acct.lanes, acct.ahead = len(self._inflight.lanes), ahead
 
     def _read_decode(self, step: _Step) -> None:
         """Read a dispatched step's tokens into their requests and release
         the requests they complete. A request that left its lane while the
         step was in flight (cancel, deadline, extract) gets no token."""
-        nxts = np.asarray(step.tokens)
-        logits_rows = (np.asarray(step.logits)
-                       if step.logits is not None else None)
+        with self._acct.wait:
+            nxts = np.asarray(step.tokens)
+            logits_rows = (np.asarray(step.logits)
+                           if step.logits is not None else None)
         now = time.perf_counter()
         # the tick's period since the later of this step's dispatch and
         # the last read, less the prefills run in it
@@ -1141,6 +1270,16 @@ class ServingEngine:
     def requests(self) -> List[Request]:
         return list(self._requests.values())
 
+    def tick_log(self) -> Dict[str, np.ndarray]:
+        """The host's account of the ticks kept (module docstring), oldest
+        first, one entry a tick in each array: ``start_ns`` (the tick's
+        start, ``time.perf_counter_ns``), ``<part>_ns`` for each of
+        ``TICK_PARTS`` (they add up to the tick's wall time), ``lanes``
+        (the lanes its decode step advanced, 0 if it dispatched none),
+        ``prefills`` and ``ahead`` (its step was dispatched with the last
+        one's tokens unread). A copy."""
+        return self._acct.log()
+
     def stats(self) -> dict:
         """Aggregate serving outcome (docs/serving.md): per-terminal
         counts, shed reasons, TTFT percentiles over requests that got a
@@ -1149,9 +1288,12 @@ class ServingEngine:
         the lanes' lengths covered over ``lanes * max_seq_len``, i.e. the
         share of a fixed full window per lane that decode attention, which
         stops at each lane's length, had to read (None before the first
-        decode tick), and ``decode_dispatched_ahead_share``: the decode
-        steps dispatched while the previous step's tokens were still
-        unread, over all decode steps (None before the first)."""
+        decode tick), ``decode_dispatched_ahead_share``: the decode steps dispatched
+        while the previous step's tokens were still unread, over all
+        decode steps (None before the first), and ``host_tick_ms``: the
+        median milliseconds of each of ``TICK_PARTS`` and of the whole
+        ``tick`` over the ticks :meth:`tick_log` keeps (None before the
+        first tick)."""
         from apex_tpu.serving.loadgen import percentile
 
         counts: Dict[str, int] = {}
@@ -1190,4 +1332,13 @@ class ServingEngine:
             "decode_dispatched_ahead_share": (
                 self._decode_ahead / self._decode_ticks
                 if self._decode_ticks else None),
+            "host_tick_ms": self._host_tick_ms(),
         }
+
+    def _host_tick_ms(self) -> Optional[Dict[str, float]]:
+        log = self._acct.log()
+        if not len(log["start_ns"]):
+            return None
+        parts = {p: log[p + "_ns"] for p in TICK_PARTS}
+        parts["tick"] = sum(parts.values())
+        return {p: float(np.median(ns)) / 1e6 for p, ns in parts.items()}

@@ -27,6 +27,7 @@ from apex_tpu.analysis.hlo.parser import parse_hlo_module, parse_instruction
 from apex_tpu.monitor import goodput
 from apex_tpu.monitor.goodput import scopes
 from apex_tpu.monitor.xray import timeline
+from apex_tpu.monitor.xray.timeline import analyzer
 from apex_tpu.monitor.xray.timeline import hlo_scopes as sm
 from apex_tpu.ops import _dispatch
 
@@ -547,14 +548,37 @@ class TestTpuLayout:
         assert sc.by_how["no instruction"] == pytest.approx(20.0)
         assert sc.attributed_fraction == pytest.approx(1200.0 / 1220.0)
         # the window runs to the end of the snapshot annotation: the gaps
-        # between and after the runs go to the spans that cover them
+        # between and after the runs go to the spans that cover them,
+        # instant by instant (the first gap's last 20 us lie under none;
+        # snapshot, begun later, is innermost where data_wait overlaps it)
         assert sc.idle_by_annotation == pytest.approx(
-            {"data_wait": 400.0, "snapshot": 500.0})
+            {"data_wait": 380.0, "snapshot": 500.0,
+             analyzer.NO_ANNOTATION: 20.0})
         text = rep.summary()
         assert "by Pallas kernel" in text and "flash_bwd_dq" in text
         assert "1 calls a step  tiles 1024x512" in text
         kinds = [r for r in rep.to_records() if "part" in r or "kernel" in r]
         assert {r.get("part") for r in kinds} >= {"forward", "optimizer"}
+
+    def test_idle_goes_to_the_innermost_annotation(self):
+        """A serving tick's parts nest in the goodput ``decode`` span: an
+        idle gap across them is split between the parts, the span keeping
+        only what no part covers, and what no annotation covers is
+        (no annotation)."""
+        def host(name, ts, dur):
+            return timeline.TraceEvent(name, 7, 1, ts, dur)
+
+        spans = [host("decode", 100.0, 100.0),
+                 host("engine.book", 110.0, 80.0),
+                 host("engine.dispatch", 120.0, 30.0),
+                 host("engine.wait", 160.0, 20.0),
+                 host("engine.book", 200.0, 10.0)]
+        got = analyzer.idle_by_innermost([(90.0, 170.0), (185.0, 230.0)],
+                                         spans)
+        assert got == pytest.approx({
+            analyzer.NO_ANNOTATION: 10.0 + 20.0, "decode": 10.0 + 10.0,
+            "engine.book": 10.0 + 10.0 + 5.0 + 10.0,
+            "engine.dispatch": 30.0, "engine.wait": 10.0})
 
     def test_trace_event_export_form_long_name_and_tf_op(self, snippet,
                                                          op_texts):

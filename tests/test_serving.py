@@ -951,3 +951,196 @@ def test_decode_dispatched_ahead_share(tiny_gpt):
     # not ahead
     assert eng.stats()["decode_dispatched_ahead_share"] == pytest.approx(
         8 / 11)
+
+
+# -- the host's account of a tick (engine.py) --------------------------------
+
+
+def _ticks(eng, limit=200):
+    """Tick to idle; each tick's (start, end) by ``perf_counter_ns``, the
+    clock the account stamps its parts with."""
+    spans = []
+    while not eng.idle:
+        before = time.perf_counter_ns()
+        eng.tick()
+        spans.append((before, time.perf_counter_ns()))
+        assert len(spans) < limit
+    return spans
+
+
+def _parts(log):
+    from apex_tpu.serving.engine import TICK_PARTS
+
+    return np.stack([log[p + "_ns"] for p in TICK_PARTS], axis=1)
+
+
+def test_a_ticks_parts_add_up_to_its_wall_time(tiny_gpt):
+    """Every logged tick's five parts are its wall time, stamped inside the
+    caller's own stamps around ``tick()``; the lanes, prefills and steps
+    dispatched ahead are the tick's."""
+    pa, pb = _prompts(5, 11)
+    eng = _engine(tiny_gpt)
+    eng.submit(pa, max_new_tokens=6)
+    eng.submit(pb, max_new_tokens=4)
+    spans = _ticks(eng)
+    log = eng.tick_log()
+    parts = _parts(log)
+    assert len(parts) == len(spans) == eng.stats()["ticks"]
+    assert (parts >= 0).all()
+    for (before, after), start, total in zip(spans, log["start_ns"],
+                                             parts.sum(axis=1)):
+        assert before <= start and start + total <= after
+        assert total > 0
+    # two admissions, one a tick; B joins A's step at tick 1, then both
+    # step ahead until B's last token (step 3) and A's (step 4); tick 5
+    # only reads
+    assert log["prefills"].tolist() == [1, 1, 0, 0, 0, 0]
+    assert log["lanes"].tolist() == [1, 2, 2, 2, 1, 0]
+    assert log["ahead"].tolist() == [False, False, True, True, True, False]
+
+
+class _SlowTokens:
+    """A decode step's tokens whose fetch takes ``delay`` seconds: a step
+    the device is still running when the host asks for it."""
+
+    def __init__(self, real, delay):
+        self.real, self.delay = real, delay
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.delay)
+        return np.asarray(self.real, dtype)
+
+
+def test_a_slow_device_step_lands_in_wait_not_dispatch(tiny_gpt, greedy):
+    """A stub compiled step whose token fetch sleeps: the sleep is booked
+    where the host blocks on the step (reading its tokens, or merging a
+    fresh lane's), never in the call that dispatched it."""
+    delay = 0.05
+    pa, pb = _prompts(6, 9)
+    eng = _engine(tiny_gpt)
+    real = eng._decode_c
+
+    def slow(pool, variables, tables, positions, tokens, *rest):
+        if isinstance(tokens, _SlowTokens):
+            tokens = tokens.real
+        out = real(pool, variables, tables, positions, tokens, *rest)
+        return (out[0], _SlowTokens(out[1], delay)) + tuple(out[2:])
+
+    eng._decode_c = slow
+    a = eng.submit(pa, max_new_tokens=5)
+    eng.tick()
+    b = eng.submit(pb, max_new_tokens=3)   # merged with A's step in flight
+    _ticks(eng)
+    assert a.tokens_out == greedy(pa, 5) and b.tokens_out == greedy(pb, 3)
+    parts = _parts(eng.tick_log())
+    from apex_tpu.serving.engine import TICK_PARTS
+
+    wait, dispatch = (parts[:, TICK_PARTS.index(p)]
+                      for p in ("wait", "dispatch"))
+    # every tick after the first reads (or merges) a step in flight
+    assert (wait[1:] >= delay * 1e9).all() and wait[0] < delay * 1e9
+    assert (dispatch < delay * 1e9).all()
+
+
+def test_a_tick_that_admits_books_its_prefill(tiny_gpt):
+    """The prefill call through its first token is ``prefill``, not
+    ``admit``: a prefill slowed by a sleep shows there alone."""
+    delay = 0.05
+    (pa,) = _prompts(5)
+    eng = _engine(tiny_gpt)
+    real = eng._prefill_c[8]
+
+    def slow(*args):
+        time.sleep(delay)
+        return real(*args)
+
+    eng._prefill_c[8] = slow
+    a = eng.submit(pa, max_new_tokens=3)
+    _ticks(eng)
+    assert a.state == "completed"
+    log = eng.tick_log()
+    assert log["prefills"].tolist() == [1, 0, 0]
+    assert log["prefill_ns"][0] >= delay * 1e9
+    assert log["admit_ns"][0] < delay * 1e9
+    assert (log["prefill_ns"][1:] == 0).all()
+
+
+def test_the_tick_log_keeps_the_newest_ticks_when_it_wraps(tiny_gpt,
+                                                          monkeypatch):
+    from apex_tpu.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "TICK_LOG_TICKS", 4)
+    (pa,) = _prompts(7)
+    eng = _engine(tiny_gpt)
+    eng.submit(pa, max_new_tokens=9)
+    spans = _ticks(eng)
+    assert len(spans) == 9   # a prefill and 8 steps, the last read alone
+    log = eng.tick_log()
+    assert {len(v) for v in log.values()} == {4}
+    for (before, after), start in zip(spans[-4:], log["start_ns"]):
+        assert before <= start <= after
+    assert log["lanes"].tolist() == [1, 1, 1, 0]
+
+
+def test_host_tick_ms_is_none_before_the_first_tick(tiny_gpt):
+    """``stats()["host_tick_ms"]``: nothing before a tick, then the median
+    of every part and of the whole tick over the ring."""
+    from apex_tpu.serving.engine import TICK_PARTS
+
+    (pa,) = _prompts(5)
+    eng = _engine(tiny_gpt)
+    assert eng.stats()["host_tick_ms"] is None
+    assert {len(v) for v in eng.tick_log().values()} == {0}
+    eng.submit(pa, max_new_tokens=4)
+    _ticks(eng)
+    got = eng.stats()["host_tick_ms"]
+    assert set(got) == set(TICK_PARTS) | {"tick"}
+    ms = _parts(eng.tick_log()) / 1e6
+    assert got["tick"] == pytest.approx(float(np.median(ms.sum(axis=1))))
+    assert got["dispatch"] == pytest.approx(
+        float(np.median(ms[:, TICK_PARTS.index("dispatch")])))
+
+
+def test_each_part_opens_its_annotation_while_a_session_records(
+        tiny_gpt, monkeypatch):
+    """While a profiler session records, each part is an ``engine.<part>``
+    annotation, nested so that the innermost one at any instant is the
+    part the time is booked to; with none recording, none is made."""
+    from apex_tpu.serving import engine as engine_mod
+
+    events = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append("+" + self.name)
+
+        def __exit__(self, *exc):
+            events.append("-" + self.name)
+
+    recording = type("Profiler", (), {"TraceAnnotation": Annotation})
+    pa, pb = _prompts(5, 9)
+    eng = _engine(tiny_gpt)
+    eng.submit(pa, max_new_tokens=6)
+    eng.tick()
+    eng.tick()
+    assert events == []
+    monkeypatch.setattr(engine_mod, "profiling", lambda: recording)
+    eng.tick()                          # steady: dispatch ahead, then read
+    assert events == [
+        "+engine.admit", "-engine.admit",
+        "+engine.book", "+engine.dispatch", "-engine.dispatch",
+        "+engine.wait", "-engine.wait", "-engine.book",
+        "+engine.book", "-engine.book"]
+    del events[:]
+    eng.submit(pb, max_new_tokens=2)
+    eng.tick()                          # admits: B's prefill, merged step
+    assert events == [
+        "+engine.admit", "+engine.prefill", "-engine.prefill",
+        "-engine.admit",
+        "+engine.book", "+engine.wait", "-engine.wait",
+        "+engine.dispatch", "-engine.dispatch",
+        "+engine.wait", "-engine.wait", "-engine.book",
+        "+engine.book", "-engine.book"]
